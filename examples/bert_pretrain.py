@@ -1,7 +1,7 @@
 """BERT pretraining on the TPU throughput path: ONE fused
 forward+backward+update XLA computation (jit.TrainStep) with AMP bf16
 and optional dp x mp mesh sharding — the configuration bench.py scores
-(101k tok/s / 30.3% MFU on a single v5e chip at B=32 S=512).
+and chip_smoke.py drives at B=32 S=512 (speed: not measured at HEAD).
 
 CPU toy scale by default. On a TPU host: set TOY=False; for multi-chip
 set MESH to e.g. {"dp": 4, "mp": 2} — parameters shard over mp, the

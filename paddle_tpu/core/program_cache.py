@@ -1,10 +1,11 @@
 """Persistent AOT program cache: disk-backed trace + compile reuse.
 
 Kills the retrace+recompile cold start the reference pays per process
-(PERF_NOTES: ~3.3 s trace + ~21 s XLA compile for the 12-layer
-BERT-shaped train step, again in EVERY interpreter). Two disk layers
+(the 12-layer BERT-shaped train step is traced and compiled again in
+EVERY interpreter; seconds not measured at HEAD). Two disk layers
 share one directory (FLAGS_program_cache_dir, default
-~/.cache/paddle_tpu/aot, env override PADDLE_TPU_PROGRAM_CACHE_DIR):
+<checkout>/.paddle_tpu_cache/aot, env override
+PADDLE_TPU_PROGRAM_CACHE_DIR):
 
   <dir>/trace/<fingerprint>.stablehlo
       jax.export bytes of the fully-lowered Executor step, keyed by
@@ -82,12 +83,11 @@ class _timed:
 
 
 def default_dir() -> str:
-    """The auto cache location: env override, else the home cache."""
-    env = os.environ.get("PADDLE_TPU_PROGRAM_CACHE_DIR")
-    if env is not None:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "paddle_tpu", "aot")
+    """The auto cache location: env PADDLE_TPU_PROGRAM_CACHE_DIR, else
+    the fixed <checkout>/.paddle_tpu_cache/aot — one rule, kept with
+    the framework-free serving core."""
+    from ..serving_core import default_cache_dir
+    return default_cache_dir()
 
 
 def resolve_dir(override: Optional[str] = None) -> Optional[str]:
@@ -103,58 +103,63 @@ def resolve_dir(override: Optional[str] = None) -> Optional[str]:
     return d or None
 
 
+def source_tree_token(root: str) -> str:
+    """sha256 over the (relative path, contents) of every .py file under
+    `root`. Contents, not mtimes: a fresh copy of the same tree (a new
+    checkout, a machine image) must hit what the old copy wrote."""
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames.sort()
+        for fn in sorted(filenames):
+            if not fn.endswith(".py"):
+                continue
+            p = os.path.join(dirpath, fn)
+            with open(p, "rb") as f:
+                body = f.read()
+            h.update(("%s:%d;" % (os.path.relpath(p, root),
+                                  len(body))).encode())
+            h.update(body)
+    return h.hexdigest()
+
+
 def framework_token() -> str:
-    """Hash over the paddle_tpu source tree's (path, mtime, size) — the
-    op-lowering code IS part of the traced computation, so a source
-    change must invalidate disk entries (same pyc-style heuristic as
-    CPython's import system). Memoized per process."""
+    """source_tree_token of the paddle_tpu package — the op-lowering
+    code IS part of the traced computation, so a source change must
+    invalidate disk entries. Memoized per process."""
     global _framework_token
     if _framework_token is None:
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        h = hashlib.sha256()
-        for dirpath, dirnames, filenames in sorted(os.walk(root)):
-            dirnames.sort()
-            for fn in sorted(filenames):
-                if not fn.endswith(".py"):
-                    continue
-                p = os.path.join(dirpath, fn)
-                try:
-                    st = os.stat(p)
-                except OSError:
-                    continue
-                h.update(("%s:%d:%d;" % (os.path.relpath(p, root),
-                                         st.st_mtime_ns,
-                                         st.st_size)).encode())
-        _framework_token = h.hexdigest()
+        _framework_token = source_tree_token(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     return _framework_token
 
 
 def ensure_xla_cache(cache_dir: str) -> None:
     """Point jax's persistent compilation cache at <cache_dir>/xla with
     a zero min-compile-time threshold (small CPU test programs must
-    cache too). Never overrides a dir the user configured themselves."""
+    cache too). Never overrides a dir the user configured themselves:
+    where JAX_COMPILATION_CACHE_DIR (or jax.config) names a directory,
+    that one is used and no other is set here. A failure to turn the
+    cache on raises — a cache that silently never engaged reads as a
+    slow program, not as an error."""
     global _xla_cache_dir_set
-    try:
-        import jax
-        current = jax.config.jax_compilation_cache_dir
-        if current and current != _xla_cache_dir_set:
-            return  # user-configured; leave it alone
-        xla_dir = os.path.join(cache_dir, "xla")
-        if current == xla_dir:
-            return
-        os.makedirs(xla_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _xla_cache_dir_set = xla_dir
-        # jax latches its cache state at the process's FIRST compile
-        # (_initialize_cache runs "at most once"), and the Executor has
-        # usually jitted something (PRNG fold-in, state prep) before we
-        # get here — un-latch so the next compile picks up the new dir
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # config knob skew across jax versions: cache is
-        pass           # an optimization, never a hard dependency
+    import jax
+    current = jax.config.jax_compilation_cache_dir
+    if current and current != _xla_cache_dir_set:
+        return  # user-configured; leave it alone
+    xla_dir = os.path.join(cache_dir, "xla")
+    if current == xla_dir:
+        return
+    os.makedirs(xla_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", xla_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    _xla_cache_dir_set = xla_dir
+    # jax latches its cache state at the process's FIRST compile
+    # (_initialize_cache runs "at most once"), and the Executor has
+    # usually jitted something (PRNG fold-in, state prep) before we
+    # get here — un-latch so the next compile picks up the new dir
+    from jax._src import compilation_cache as _cc
+    _cc.reset_cache()
 
 
 def _trace_path(cache_dir: str, fingerprint: str) -> str:

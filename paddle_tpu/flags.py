@@ -34,24 +34,19 @@ _DEFS: Dict[str, Any] = {
     # False so a broken kernel can never silently ship — the round-2
     # bench measured the fallback without anyone noticing.
     "FLAGS_flash_attention_fallback": False,
-    # in-kernel hardware-PRNG flash dropout: validated on v5e hardware
-    # round 5 (scripts/inkernel_parity.py — determinism, fwd/bwd mask
-    # agreement by finite differences, bias+dropout combination) and
-    # 1.5x faster than flash+HBM-mask at the scored S=512 config
-    # (8.54ms vs 12.71ms f+b, tpu_experiments.py 2b). The ADVICE-r4
-    # caveat (no interpret-mode oracle) is discharged by that on-chip
-    # parity gate, which the run sheet re-runs every session — and
-    # enforced at runtime by the parity-freshness stamp the parity run
-    # writes (kernel-source-hash marker; flash_attention falls back to
-    # the HBM-mask path with a one-time warning when it is missing or
-    # stale — ADVICE r5).
+    # in-kernel hardware-PRNG flash dropout: no [B,H,Sq,Sk] keep-mask
+    # in HBM. Interpret mode cannot reproduce the hardware PRNG stream,
+    # so the oracle is the on-chip check in scripts/inkernel_parity.py
+    # (determinism, fwd/bwd mask agreement by finite differences,
+    # bias+dropout combination), which chip_smoke.py runs in its train
+    # phase on every run. Speed against the HBM-mask path: not measured
+    # at HEAD.
     "FLAGS_flash_inkernel_dropout": True,
     # dropout backward-residual strategy: "xla" leaves storage to XLA's
     # cost model (observed: 4 bytes/element u32 buffers), "u8" pins a
     # uint8 mask residual via custom_vjp (4x less mask HBM), "seed"
     # stores only the PRNG key and regenerates the mask in backward
-    # (zero mask bytes; rbg re-run in bwd). Measured on-chip before
-    # defaulting — see PERF_NOTES round 5.
+    # (zero mask bytes; rbg re-run in bwd). Not measured at HEAD.
     "FLAGS_dropout_storage": "xla",
     # embedding dW strategy: True = chunked one-hot MXU matmuls instead
     # of XLA scatter-add. Decided by the round-5 end-to-end B=32 BERT
@@ -64,8 +59,9 @@ _DEFS: Dict[str, Any] = {
     "FLAGS_fuse_parameter_groups_size": 3,
     "FLAGS_sync_nccl_allreduce": True,
     # persistent AOT program cache (core/program_cache.py). None = auto:
-    # $PADDLE_TPU_PROGRAM_CACHE_DIR if set, else ~/.cache/paddle_tpu/aot;
-    # "" disables the disk cache entirely.
+    # $PADDLE_TPU_PROGRAM_CACHE_DIR if set, else the fixed directory
+    # program_cache.default_dir() inside the checkout; "" disables the
+    # disk cache entirely.
     "FLAGS_program_cache_dir": None,
     # in-memory Executor cache bound (entries, LRU eviction)
     "FLAGS_executor_cache_capacity": 64,

@@ -281,7 +281,7 @@ class DGCMomentumOptimizer(MetaOptimizerBase):
                 # rampup: plain momentum on the dense pmean; v unused
                 u_d = momentum * u + jax.lax.pmean(g, axis)
                 zeros = jnp.zeros_like(v)
-                if axis not in getattr(_typeof(zeros), "vma", (axis,)):
+                if axis not in _typeof(zeros).vma:
                     zeros = _pcast(zeros, (axis,), to="varying")
                 # u_d is replicated in VALUE (identical pmean'ed grads ->
                 # identical momentum) but typed varying via u; pcast-by-

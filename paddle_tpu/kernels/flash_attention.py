@@ -30,12 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU too (used for interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+
+from . import gspmd_will_partition
 
 NEG_INF = -1e30
 
@@ -44,100 +41,23 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# ---------------------------------------------------------------------------
-# in-kernel dropout parity-freshness stamp (ADVICE round 5)
-#
-# FLAGS_flash_inkernel_dropout defaults on, but its only oracle runs on
-# real TPU hardware (scripts/inkernel_parity.py — interpret mode cannot
-# reproduce the hardware PRNG stream). The freshness stamp closes that
-# gap: the parity run writes a marker stamped with a hash of THIS
-# kernel source, and the flag only engages while the marker matches —
-# edit the kernel without re-running the parity check and the runtime
-# quietly (one warning) falls back to the HBM-mask reference path
-# instead of shipping an unvalidated PRNG pattern.
-# ---------------------------------------------------------------------------
-
-_parity_memo: Optional[bool] = None  # per-process; reset for tests
+# trace-time record of which attention-probs dropout path lowered:
+# "inkernel" (hardware PRNG inside the kernel, no mask in HBM) or
+# "mask" (a [B,H,Sq,Sk] keep-mask read from HBM). Same contract as
+# nn.transformer's attention path log: a check reads what was traced,
+# never what the configuration implies. The in-kernel path has no CPU
+# oracle (interpret mode cannot reproduce the hardware PRNG stream);
+# scripts/inkernel_parity.py checks it on the chip and chip_smoke.py
+# runs that check on every run.
+_DROPOUT_PATH_LOG = []
 
 
-def kernel_parity_hash() -> str:
-    """sha256 of this module's source — the identity the on-hardware
-    parity run certifies. Any edit to the kernel changes it."""
-    import hashlib
-    with open(__file__, "rb") as f:
-        return hashlib.sha256(f.read()).hexdigest()
+def reset_dropout_path_log():
+    del _DROPOUT_PATH_LOG[:]
 
 
-def parity_stamp_path() -> str:
-    """Stamp location: $PADDLE_TPU_PARITY_STAMP overrides; default
-    lives next to the AOT program cache in the user cache dir."""
-    import os
-    env = os.environ.get("PADDLE_TPU_PARITY_STAMP")
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "paddle_tpu", "inkernel_parity.json")
-
-
-def write_parity_stamp(path: Optional[str] = None) -> str:
-    """Record that scripts/inkernel_parity.py just PASSED on hardware:
-    stamp the current kernel hash (atomic replace, like the program
-    cache). Returns the path written."""
-    import json
-    import os
-    import tempfile
-    import time
-    p = path or parity_stamp_path()
-    os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
-    blob = json.dumps({
-        "kernel_hash": kernel_parity_hash(),
-        "backend": jax.default_backend(),
-        "jax": jax.__version__,
-        "time": time.time(),
-    }, sort_keys=True).encode()
-    fd, tmp = tempfile.mkstemp(dir=os.path.dirname(p) or ".",
-                               prefix=".tmp_parity")
-    try:
-        with os.fdopen(fd, "wb") as f:
-            f.write(blob)
-        os.replace(tmp, p)
-    except BaseException:
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        raise
-    global _parity_memo
-    _parity_memo = None  # re-read on next check
-    return p
-
-
-def _inkernel_parity_ok() -> bool:
-    """True while the parity stamp exists and certifies the CURRENT
-    kernel source. Memoized per process; on the first False a single
-    warning explains the silent fallback to the HBM-mask path."""
-    global _parity_memo
-    if _parity_memo is not None:
-        return _parity_memo
-    import json
-    ok = False
-    try:
-        with open(parity_stamp_path(), "rb") as f:
-            stamp = json.load(f)
-        ok = stamp.get("kernel_hash") == kernel_parity_hash()
-    except (OSError, ValueError):
-        ok = False
-    if not ok:
-        import warnings
-        warnings.warn(
-            "FLAGS_flash_inkernel_dropout is on but the parity stamp "
-            "(%s) is missing or stale for this kernel source — using "
-            "the HBM-mask dropout path. Re-run "
-            "scripts/inkernel_parity.py on TPU hardware to restore "
-            "the in-kernel path." % parity_stamp_path(),
-            RuntimeWarning, stacklevel=2)
-    _parity_memo = ok
-    return ok
+def dropout_paths_taken():
+    return list(_DROPOUT_PATH_LOG)
 
 
 def _drop_keep_tile(seed_ref, qi, ki, shape, keep_prob):
@@ -707,7 +627,8 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     attn_dropout in multihead_matmul / transformer layers) is applied
     inside the kernel from a precomputed keep-mask when dropout_rate>0
     and dropout_rng is given. Falls back to the composed XLA path for
-    unsupported shapes.
+    unsupported shapes, and where GSPMD will partition the step
+    (kernels.gspmd_will_partition).
 
     bias_needs_grad=False declares the bias non-differentiable (padding
     masks derived from input ids): the dbias recompute is skipped, and
@@ -727,7 +648,8 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
         block_q //= 2
     while block_k > 128 and sk % min(block_k, sk):
         block_k //= 2
-    if not _supported(q, k, sq, sk, d, block_q, block_k):
+    if (not _supported(q, k, sq, sk, d, block_q, block_k)
+            or gspmd_will_partition()):
         keep = dropout_keep_mask(dropout_rng, dropout_rate,
                                  (batch, heads, sq, sk), jnp.float32) \
             if want_drop else None
@@ -749,25 +671,24 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     if want_drop:
         from ..flags import get_flag
         if ((bias is None or not bias_needs_grad)
-                and not _use_interpret() and _HAS_PLTPU
-                and get_flag("FLAGS_flash_inkernel_dropout")
-                and _inkernel_parity_ok()):
+                and not _use_interpret()
+                and get_flag("FLAGS_flash_inkernel_dropout")):
             # in-kernel hardware-PRNG dropout: no [B,H,Sq,Sk] mask in
             # HBM at all. Needs a non-differentiable bias (or none)
             # because the dbias blockwise-recompute path (plain XLA,
             # outside Pallas) cannot regenerate the in-kernel pattern.
-            # Default-on since the round-5 on-chip parity run
-            # (scripts/inkernel_parity.py; the run sheet re-gates every
-            # session), and additionally gated on the parity-freshness
-            # stamp (_inkernel_parity_ok, checked LAST so CPU runs
-            # never warn) — the flag remains the kill switch.
+            # The path taken is a function of the code, the flag and
+            # the call alone; parity with the mask path is checked on
+            # the chip (scripts/inkernel_parity.py, chip_smoke.py).
             import numpy as _np
             drop_seed = jax.random.randint(
                 dropout_rng, (1, 1), 0, _np.iinfo(_np.int32).max,
                 dtype=jnp.int32)
+            _DROPOUT_PATH_LOG.append("inkernel")
         else:
             drop_mask = dropout_keep_mask(
                 dropout_rng, dropout_rate, (batch, heads, sq, sk), q.dtype)
+            _DROPOUT_PATH_LOG.append("mask")
     return _flash(q, k, v, bias, drop_mask, drop_seed, causal, sm_scale,
                   block_q, block_k, _use_interpret(), keep_prob,
                   bias_needs_grad)

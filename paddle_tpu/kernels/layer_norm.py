@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import gspmd_will_partition
+
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -145,10 +147,11 @@ _layer_norm.defvjp(_layer_norm_fwd, _layer_norm_bwd)
 
 def layer_norm(x, gamma, beta, eps: float = 1e-5):
     """Fused layer norm over the trailing dim. Falls back to the composed
-    XLA path when the feature dim is not lane-aligned."""
+    XLA path when the feature dim is not lane-aligned, or when GSPMD
+    will partition the step (kernels.gspmd_will_partition)."""
     f = x.shape[-1]
     rows = x.size // f
-    if f % 128 != 0 or rows % 8 != 0:
+    if f % 128 != 0 or rows % 8 != 0 or gspmd_will_partition():
         return layer_norm_reference(x, gamma, beta, eps)
     return _layer_norm(x, gamma, beta, eps, _use_interpret())
 
@@ -180,7 +183,7 @@ def layer_norm_with_stats(x, gamma, beta, eps: float = 1e-5):
     (layer_norm_op.cc). Stats come out of the same kernel pass; no extra
     reductions over x. Gradient flows only through y."""
     f = x.shape[-1]
-    if f % 128 != 0 or (x.size // f) % 8 != 0:
+    if f % 128 != 0 or (x.size // f) % 8 != 0 or gspmd_will_partition():
         xf = x.astype(jnp.float32)
         mean = xf.mean(-1)
         var = ((xf - mean[..., None]) ** 2).mean(-1)

@@ -64,12 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu imports on CPU too (interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 # finite "minus infinity", matching kernels/flash_attention.py: after
 # the running-max subtraction exp(NEG_INF - m) underflows to exactly
@@ -468,7 +463,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     (quantized pools, paddle_tpu/quant) flow to the dequant-fused
     forms of both paths; None = the untouched fp32 path."""
     mode = resolved_form()
-    if mode == "pallas" and _HAS_PLTPU:
+    if mode == "pallas":
         return paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                       ctx_lens, sm_scale,
                                       k_scales=k_scales,
@@ -488,7 +483,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
     seam (+ kernel_form override) as the decode entry; k_scales /
     v_scales select the quantized-KV dequant-fused forms."""
     mode = resolved_form()
-    if mode == "pallas" and _HAS_PLTPU:
+    if mode == "pallas":
         return ragged_paged_attention_pallas(
             q, k_pool, v_pool, block_tables, q_lens, ctx_lens, sm_scale,
             k_scales=k_scales, v_scales=v_scales)

@@ -796,7 +796,7 @@ class GangSupervisor:
         env["PADDLE_LAUNCH_DIGEST"] = "1" if self._digest_on else "0"
         # Workers run `python <script>`, so sys.path[0] is the script's
         # directory, not the supervisor's cwd. Propagate the cwd on
-        # PYTHONPATH (append, never overwrite: accelerator site dirs
+        # PYTHONPATH (append, never overwrite: the user's own entries
         # also ride this variable) so `import paddle_tpu` resolves the
         # same way for workers as it did for the launcher.
         cwd = os.getcwd()

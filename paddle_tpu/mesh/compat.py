@@ -1,11 +1,10 @@
-"""jax version-compatibility shims for the SPMD runtime.
+"""The jax surface the SPMD runtime is written against, in one place.
 
-The repo targets the jax.shard_map surface (top-level ``jax.shard_map``
-with ``check_vma=``), but the pinned container runs jax 0.4.37 where the
-API lives at ``jax.experimental.shard_map.shard_map`` with ``check_rep=``
-and ``jax.lax.axis_size`` does not exist yet. Every shard_map call site
-in paddle_tpu goes through :func:`shard_map` / :func:`axis_size` /
-:func:`in_named_axis` so a single module owns the version split.
+Written for the installed jax (0.9): top-level ``jax.shard_map`` with
+``check_vma=``, ``jax.lax.axis_size``, ``jax.lax.pcast`` and
+``jax.typeof`` for varying-manual-axes (VMA) typing. Every shard_map
+call site in paddle_tpu imports these names from here, so the next jax
+upgrade has one module to read.
 """
 from __future__ import annotations
 
@@ -13,78 +12,30 @@ from typing import Any, Optional
 
 import jax
 
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _OLD_SHARD_MAP
-else:  # pragma: no cover - newer jax than the pinned container
-    _OLD_SHARD_MAP = None
+axis_size = jax.lax.axis_size
+pcast = jax.lax.pcast
+typeof = jax.typeof
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: Optional[bool] = None,
               **kwargs: Any):
-    """``jax.shard_map`` across jax versions.
-
-    ``check_vma`` is the new-jax name for the per-output replication
-    check (old jax: ``check_rep``). ``None`` keeps each version's own
-    default — on old jax that default (True) is also load-bearing: the
-    shard_map TRANSPOSE rule only inserts the replicated-input
-    cotangent psum when rep-tracking is on, so grad-through-shard_map
-    paths (pipeline training) break under check_rep=False."""
-    if _NEW_SHARD_MAP is not None:  # pragma: no cover - newer jax
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kwargs)
+    """``jax.shard_map`` with the mesh and specs as plain arguments.
+    ``check_vma=None`` keeps jax's default (True). That default is
+    load-bearing under differentiation: the transpose of a replicated
+    input's cast to device-varying is the psum of its cotangent, and
+    only VMA typing inserts it."""
     if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return _OLD_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-
-def axis_size(axis: str):
-    """Size of a bound mesh axis, inside shard_map/pmap bodies.
-
-    jax guarantees ``psum(1, axis)`` constant-folds to the axis size, so
-    it is usable in shape arithmetic on any version; prefer the real
-    ``jax.lax.axis_size`` when it exists.
-    """
-    impl = getattr(jax.lax, "axis_size", None)
-    if impl is not None:  # pragma: no cover - newer jax
-        return impl(axis)
-    return jax.lax.psum(1, axis)
-
-
-def pcast(x, axes, to: str = "varying"):
-    """``jax.lax.pcast`` (new-jax varying-manual-axes retyping) — a
-    no-op on old jax, which has no VMA tracking (the compat shard_map
-    runs with check_rep=False there, so nothing needs retyping)."""
-    impl = getattr(jax.lax, "pcast", None)
-    if impl is not None:  # pragma: no cover - newer jax
-        return impl(x, axes, to=to)
-    return x
-
-
-class _NoVMA:
-    """Stand-in aval for old jax: no vma attribute, so
-    ``getattr(typeof(x), "vma", default)`` idioms take their default
-    (harmless either way — pcast is a no-op there)."""
-    __slots__ = ()
-
-
-def typeof(x):
-    """``jax.typeof`` (new-jax aval accessor, used for VMA queries)."""
-    impl = getattr(jax, "typeof", None)
-    if impl is not None:  # pragma: no cover - newer jax
-        return impl(x)
-    return _NoVMA()
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def in_named_axis(axis: str) -> bool:
     """True when ``axis`` is bound (we are tracing inside a shard_map /
-    pmap body mapped over it). Probes with ``axis_index`` — unbound
-    axes raise NameError (old jax) / KeyError-family errors (new)."""
+    pmap body mapped over it). Probes with ``axis_index``, which raises
+    NameError for an unbound axis."""
     try:
         jax.lax.axis_index(axis)
         return True
-    except (NameError, KeyError, ValueError, TypeError, AttributeError):
+    except NameError:
         return False

@@ -63,9 +63,11 @@ def routes_to_flash(seq_len: int, head_dim: int,
     once the composed path must materialize a [B,H,S,S] keep-mask,
     flash wins from shorter sequences (round-5 measurement above)."""
     import jax
+    from ..kernels import gspmd_will_partition
     min_seq = _FLASH_MIN_SEQ_DROPOUT if dropout_active else _FLASH_MIN_SEQ
     return (_USE_FLASH and jax.default_backend() == "tpu"
-            and seq_len >= min_seq and head_dim in (64, 128, 256))
+            and seq_len >= min_seq and head_dim in (64, 128, 256)
+            and not gspmd_will_partition())
 
 
 def _attention_core(q, k, v, attn_mask, dropout_p, training, is_causal=False):

@@ -283,6 +283,11 @@ def lower_pipeline_train(lowerer, op, env: Dict[str, Any]) -> None:
     f_total = max([1] + [lo.f_size for lo in layouts])
     i_total = max([1] + [lo.i_size for lo in layouts])
 
+    def to_vary(x):
+        if axis in _typeof(x).vma:
+            return x  # already device-varying on this axis
+        return _pcast(x, (axis,), to="varying")
+
     # --- per-stage branch functions for lax.switch --------------------
     def make_branch(s):
         def branch(fbuf, ibuf, feeds_mb, params, extras, key):
@@ -305,11 +310,7 @@ def lower_pipeline_train(lowerer, op, env: Dict[str, Any]) -> None:
             # type for lax.switch: a stage whose outputs are fresh zeros
             # (unvarying) must match one whose outputs came through the
             # device-varying buffers
-            def vary(x):
-                if axis in getattr(_typeof(x), "vma", ()):
-                    return x  # already device-varying on this axis
-                return _pcast(x, (axis,), to="varying")
-            return vary(fb), vary(ib), vary(loss)
+            return to_vary(fb), to_vary(ib), to_vary(loss)
         return branch
 
     branches = [make_branch(s) for s in range(n_stages)]
@@ -319,10 +320,6 @@ def lower_pipeline_train(lowerer, op, env: Dict[str, Any]) -> None:
 
     def shard_body(feeds_all, params, extras, key):
         stage = jax.lax.axis_index(axis)
-        # params arrive stage-tiled (leading pp dim of 1 per shard, see
-        # pipe_loss below); drop the tile dim
-        params = jax.tree.map(lambda p: p[0], params)
-        to_vary = lambda x: _pcast(x, (axis,), to="varying")
         # cast ALL inputs to device-varying before the scan: a branch
         # closing over a replicated (unvarying) value would get a psum
         # inserted inside the switch when transposed for the backward
@@ -377,32 +374,14 @@ def lower_pipeline_train(lowerer, op, env: Dict[str, Any]) -> None:
         return jax.lax.psum(loss_acc, axis) / n_mb
 
     from jax.sharding import PartitionSpec as P
-    # Differentiated params enter TILED over the pp axis (one identical
-    # slice per stage — per-device memory is unchanged vs replicated)
-    # so their in_spec mentions the axis: with the rep-checker off
-    # (which old jax's lax.switch typing forces, and new jax's vma
-    # pcasts make redundant) an unmentioned differentiated input has no
-    # transpose rule to psum its cotangent, while the tile's own
-    # transpose sums the per-stage partial grads for free.
+    # inputs enter replicated; shard_body casts them to device-varying,
+    # and the transpose of that cast psums the per-stage partial grads
     sharded = _shard_map(
         shard_body, mesh=mesh,
-        in_specs=(P(), P(axis), P(), P()), out_specs=P(),
-        check_vma=False)
-
-    # remat the whole sharded region: under partial eval (the executor
-    # traces this inside jit) old jax names dim 0 of every shard_map
-    # residual, so a RANK-0 residual (the scalar loss carry) cannot
-    # cross the forward/backward split — recomputing from the (all
-    # rank>=1) inputs sidesteps it, and a pipeline recomputes its
-    # stages under remat anyway
-    sharded = jax.checkpoint(
-        sharded, policy=jax.checkpoint_policies.nothing_saveable)
+        in_specs=(P(), P(), P(), P()), out_specs=P())
 
     def pipe_loss(params):
-        tiled = jax.tree.map(
-            lambda p: jnp.tile(p[None], (n_stages,) + (1,) * p.ndim),
-            params)
-        return sharded(feeds_stacked, tiled, extras_env, key0)
+        return sharded(feeds_stacked, params, extras_env, key0)
 
     loss_val, grads = jax.value_and_grad(pipe_loss)(params_env)
     env[loss_name] = loss_val

@@ -112,10 +112,6 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = SP_AXIS,
         body, mesh=mesh,
         in_specs=(P(None, None, axis, None),) * 3,
         out_specs=P(None, None, axis, None),
-        # old-jax rep-checker can't type the cond/scan ring (jax says:
-        # workaround check_rep=False); every in_spec mentions the axis,
-        # so the transpose needs no replication rewrite either
-        check_vma=False,
     )(q, k, v)
 
 
@@ -155,8 +151,4 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = SP_AXIS,
         body, mesh=mesh,
         in_specs=(P(None, None, axis, None),) * 3,
         out_specs=P(None, None, axis, None),
-        # old-jax rep-checker can't type the cond/scan ring (jax says:
-        # workaround check_rep=False); every in_spec mentions the axis,
-        # so the transpose needs no replication rewrite either
-        check_vma=False,
     )(q, k, v)

@@ -96,34 +96,45 @@ def _np_dtype(code: int):
     return np.dtype(name)
 
 
-def _maybe_enable_compile_cache():
-    """Point jax's persistent compilation cache at the shared AOT cache
-    dir (env PADDLE_TPU_PROGRAM_CACHE_DIR, default ~/.cache/paddle_tpu/
-    aot; empty string disables) so a serving process restart skips the
-    XLA binary compile of the deserialized StableHLO. Framework-free on
-    purpose — this file ships inside the artifact."""
+def default_cache_dir() -> str:
+    """The shared AOT cache dir: env PADDLE_TPU_PROGRAM_CACHE_DIR (empty
+    string disables), else ONE fixed, git-ignored directory inside the
+    checkout that holds this file's package. Fixed because the path is
+    part of jax's compilation-cache key: a directory named after a pid,
+    a time or a temporary name never hits. Not the home directory: a
+    fresh machine has none worth finding, and a checkout must not write
+    around itself. Lives here, not in core/program_cache.py (which
+    calls it), because this file is framework-free and ships inside the
+    artifact."""
     d = os.environ.get("PADDLE_TPU_PROGRAM_CACHE_DIR")
     if d is None:
-        d = os.path.join(os.path.expanduser("~"), ".cache",
-                         "paddle_tpu", "aot")
+        d = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".paddle_tpu_cache", "aot")
+    return d
+
+
+def _maybe_enable_compile_cache():
+    """Point jax's persistent compilation cache at default_cache_dir()/xla so a
+    serving process restart skips the XLA binary compile of the
+    deserialized StableHLO. Where JAX_COMPILATION_CACHE_DIR (or
+    jax.config) already names a directory that one is used and none is
+    set here."""
+    d = default_cache_dir()
     if not d:
         return
-    try:
-        import jax
-        if jax.config.jax_compilation_cache_dir:
-            return  # respect an explicit user setting
-        xla_dir = os.path.join(d, "xla")
-        os.makedirs(xla_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # jax latches cache state at the first compile of the process;
-        # un-latch so the new dir takes effect even if something jitted
-        # before this call
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass  # cache is an optimization; serving must not depend on it
+    import jax
+    if jax.config.jax_compilation_cache_dir:
+        return  # respect an explicit user setting
+    xla_dir = os.path.join(d, "xla")
+    os.makedirs(xla_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", xla_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches cache state at the first compile of the process;
+    # un-latch so the new dir takes effect even if something jitted
+    # before this call
+    from jax._src import compilation_cache as _cc
+    _cc.reset_cache()
 
 
 class SerializedCore:

@@ -17,7 +17,7 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-import _bootstrap  # noqa: F401  (repo-root sys.path + PT_FORCE_CPU)
+import _bootstrap  # noqa: F401  (repo-root sys.path)
 import numpy as np
 import jax
 import jax.numpy as jnp

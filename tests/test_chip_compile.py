@@ -1,0 +1,137 @@
+"""Compile the main paths' Pallas kernels for a described TPU v5e.
+
+No chip is attached here: the installed TPU compiler lowers for a
+device that is only described (`v5e:2x2`), which catches what
+interpret mode cannot — a block the Mosaic tiling refuses, too much
+VMEM, an op with no TPU lowering. Shapes are the real widths of
+chip_smoke.py: BERT-base attention and layer norm at B=32 S=512, and
+the GPT-2-small paged pool of the generation engine.
+
+The kernels' own `_use_interpret()` sees the CPU under pytest, so each
+test calls the inner function with `interpret=False` itself.
+
+All of these live in ONE file and describe the topology inside a
+module-scoped fixture: only one process may load the TPU library, so
+the call must not run at import or collection time, and a second file
+could land on another xdist worker.
+"""
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip("no v5e:2x2 topology can be described here: %r" % (e,))
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip; keep it off here
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *avals):
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+            for a in avals]
+    txt = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in txt
+    return txt
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# BERT-base attention at the train phase's batch: [B, H, S, D] bf16 with
+# the [B, 1, 1, S] padding bias MultiHeadAttention passes
+_QKV = _sds((32, 12, 512, 64), jnp.bfloat16)
+_BIAS = _sds((32, 1, 1, 512), jnp.float32)
+_SEED = _sds((1, 1), jnp.int32)
+
+
+def _flash_call(with_seed):
+    from paddle_tpu.kernels.flash_attention import _flash
+    keep = 0.9 if with_seed else 1.0
+
+    def fwd(q, k, v, bias, *seed):
+        return _flash(q, k, v, bias, None, seed[0] if seed else None,
+                      False, 1.0 / math.sqrt(64), 512, 512, False, keep,
+                      False)
+    return fwd
+
+
+@pytest.mark.parametrize("with_seed", [True, False],
+                         ids=["inkernel_dropout", "no_dropout"])
+def test_flash_forward_compiles_for_v5e(one_chip, with_seed):
+    avals = (_QKV, _QKV, _QKV, _BIAS) + ((_SEED,) if with_seed else ())
+    _compile(_flash_call(with_seed), one_chip, *avals)
+
+
+@pytest.mark.parametrize("with_seed", [True, False],
+                         ids=["inkernel_dropout", "no_dropout"])
+def test_flash_backward_compiles_for_v5e(one_chip, with_seed):
+    fwd = _flash_call(with_seed)
+
+    def loss(q, k, v, *rest):
+        return jnp.sum(fwd(q, k, v, *rest).astype(jnp.float32))
+    avals = (_QKV, _QKV, _QKV, _BIAS) + ((_SEED,) if with_seed else ())
+    txt = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *avals)
+    # forward + dQ + dK/dV kernels
+    assert txt.count("tpu_custom_call") >= 3
+
+
+def test_layer_norm_compiles_for_v5e(one_chip):
+    from paddle_tpu.kernels.layer_norm import _layer_norm
+
+    def loss(x, g, b):
+        return jnp.sum(_layer_norm(x, g, b, 1e-5, False)
+                       .astype(jnp.float32))
+    x = _sds((16384, 768), jnp.bfloat16)
+    gb = _sds((768,), jnp.float32)
+    txt = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, x, gb, gb)
+    assert txt.count("tpu_custom_call") >= 2  # forward + backward
+
+
+# GPT-2-small heads over the generate phase's pool. q [8, 16, ...] is
+# the ragged chunk form; [16, 1, ...] is what the engine's mixed step
+# traces (one slot per token of its default 16-slot budget)
+@pytest.mark.parametrize("q_shape", [(8, 16, 12, 64), (16, 1, 12, 64)],
+                         ids=["chunk16", "mixed_step_slots"])
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.int8],
+                         ids=["fp32_pool", "int8_pool"])
+def test_ragged_paged_attention_compiles_for_v5e(one_chip, pool_dtype,
+                                                 q_shape):
+    from paddle_tpu.kernels.paged_attention import \
+        ragged_paged_attention_pallas
+    b = q_shape[0]
+    pool = _sds((1024, 16, 12, 64), pool_dtype)
+    avals = [_sds(q_shape, jnp.float32), pool, pool,
+             _sds((b, 64), jnp.int32), _sds((b,), jnp.int32),
+             _sds((b,), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        scales = _sds((1024, 16, 12), jnp.float32)
+
+        def fn(q, kp, vp, tables, q_lens, ctx_lens, ks, vs):
+            return ragged_paged_attention_pallas(
+                q, kp, vp, tables, q_lens, ctx_lens, interpret=False,
+                k_scales=ks, v_scales=vs)
+        avals += [scales, scales]
+    else:
+        fn = functools.partial(ragged_paged_attention_pallas,
+                               interpret=False)
+    _compile(fn, one_chip, *avals)

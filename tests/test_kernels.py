@@ -254,10 +254,10 @@ def test_flash_block_divisor_fallback():
                     reason="in-kernel PRNG dropout needs the real TPU "
                            "(pltpu.prng has no interpret-mode impl)")
 def test_flash_inkernel_dropout_tpu():
-    """Delegates to the standalone parity script so the run sheet can
-    execute the SAME check outside pytest (tests/conftest.py forces the
-    CPU backend for every pytest session, so on hardware this runs via
-    `python scripts/inkernel_parity.py`)."""
+    """Delegates to the standalone parity script, the SAME check
+    chip_smoke.py runs on the chip (tests/conftest.py holds every
+    pytest session to the CPU backend, so this test only runs where
+    that is lifted)."""
     import importlib.util
     import os
     spec = importlib.util.spec_from_file_location(
@@ -322,76 +322,50 @@ def test_attention_core_mask_is_stop_gradiented():
 
 
 # ---------------------------------------------------------------------------
-# in-kernel dropout parity-freshness stamp (ADVICE r5)
+# Mosaic kernels under a GSPMD mesh (found by the 4-chip compile, PR 22)
 # ---------------------------------------------------------------------------
 
-@pytest.fixture
-def _stamp_env(tmp_path, monkeypatch):
-    """Point the stamp at a throwaway path and reset the per-process
-    memo around each test."""
-    from paddle_tpu.kernels import flash_attention as fa
-    p = tmp_path / "inkernel_parity.json"
-    monkeypatch.setenv("PADDLE_TPU_PARITY_STAMP", str(p))
-    fa._parity_memo = None
-    yield str(p)
-    fa._parity_memo = None
+def test_mosaic_kernels_yield_to_a_gspmd_mesh(monkeypatch):
+    """On a TPU, jax refuses to partition a Mosaic kernel automatically,
+    so under an ambient multi-device mesh the kernels' public entries
+    trace their composed path; inside a shard_map body (axes bound) and
+    with no mesh they keep the kernel. The CPU sees none of this, so the
+    test steers the backend query itself and only TRACES."""
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.kernels import gspmd_will_partition
+    from paddle_tpu.mesh.compat import shard_map
+    from paddle_tpu.nn.transformer import routes_to_flash
+    from paddle_tpu.parallel import env
+    x = jnp.zeros((16, 128), jnp.float32)
+    g = jnp.ones((128,), jnp.float32)
+    qkv = jnp.zeros((2, 2, 512, 64), jnp.float32)
 
+    def traced(fn, *args):
+        # a fresh callable each time: jax caches traces per function
+        return "pallas_call" in str(jax.make_jaxpr(
+            lambda *a: fn(*a))(*args))
 
-def test_parity_stamp_fresh_engages(_stamp_env):
-    from paddle_tpu.kernels import flash_attention as fa
-    written = fa.write_parity_stamp()
-    assert written == _stamp_env
-    import json
-    with open(written) as f:
-        stamp = json.load(f)
-    assert stamp["kernel_hash"] == fa.kernel_parity_hash()
-    import warnings
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # any warning fails the test
-        assert fa._inkernel_parity_ok() is True
-
-
-def test_parity_stamp_missing_warns_once_and_falls_back(_stamp_env):
-    from paddle_tpu.kernels import flash_attention as fa
-    with pytest.warns(RuntimeWarning, match="parity stamp"):
-        assert fa._inkernel_parity_ok() is False
-    # memoized: the second call neither warns nor re-reads
-    import warnings
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert fa._inkernel_parity_ok() is False
-
-
-def test_parity_stamp_stale_hash_rejected(_stamp_env):
-    from paddle_tpu.kernels import flash_attention as fa
-    fa.write_parity_stamp()
-    import json
-    with open(_stamp_env) as f:
-        stamp = json.load(f)
-    stamp["kernel_hash"] = "0" * 64  # kernel edited since the run
-    with open(_stamp_env, "w") as f:
-        json.dump(stamp, f)
-    fa._parity_memo = None
-    with pytest.warns(RuntimeWarning, match="missing or stale"):
-        assert fa._inkernel_parity_ok() is False
-
-
-def test_parity_stamp_corrupt_json_rejected(_stamp_env):
-    from paddle_tpu.kernels import flash_attention as fa
-    with open(_stamp_env, "w") as f:
-        f.write("{not json")
-    with pytest.warns(RuntimeWarning):
-        assert fa._inkernel_parity_ok() is False
-
-
-def test_write_parity_stamp_resets_memo(_stamp_env):
-    """The parity run un-sticks a previously-failed memo: after a pass
-    writes a fresh stamp, the gate re-opens without a process restart."""
-    from paddle_tpu.kernels import flash_attention as fa
-    with pytest.warns(RuntimeWarning):
-        assert fa._inkernel_parity_ok() is False
-    fa.write_parity_stamp()
-    assert fa._inkernel_parity_ok() is True
+    prev = env._env.mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        env._env.mesh = None
+        assert not gspmd_will_partition()
+        assert traced(layer_norm, x, g, g)
+        assert traced(flash_attention, qkv, qkv, qkv)
+        assert routes_to_flash(1024, 64)
+        mesh = env.init_parallel_env({"dp": 2, "mp": 2},
+                                     devices=jax.devices()[:4]).mesh
+        assert gspmd_will_partition()
+        assert not traced(layer_norm, x, g, g)
+        assert not traced(flash_attention, qkv, qkv, qkv)
+        assert not routes_to_flash(1024, 64)
+        per_shard = shard_map(
+            lambda a: layer_norm(a, g, g), mesh,
+            in_specs=P(("dp", "mp")), out_specs=P(("dp", "mp")),
+            check_vma=False)
+        assert traced(per_shard, jnp.zeros((64, 128), jnp.float32))
+    finally:
+        env._env.mesh = prev
 
 
 # ---------------------------------------------------------------------------

@@ -389,6 +389,74 @@ def test_cross_process_reuse(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# where the caches live (PR 22)
+# ---------------------------------------------------------------------------
+_CACHE_RULE = """
+import json, os, sys
+import jax
+from paddle_tpu import serving_core
+from paddle_tpu.core import program_cache
+before = jax.config.jax_compilation_cache_dir
+program_cache.ensure_xla_cache(sys.argv[1])
+serving_core._maybe_enable_compile_cache()
+print(json.dumps({"before": before,
+                  "after": jax.config.jax_compilation_cache_dir,
+                  "made": os.path.isdir(os.path.join(sys.argv[1], "xla"))}))
+"""
+
+
+def test_jax_compilation_cache_dir_env_is_left_alone(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the program uses that
+    directory and sets no other in code — neither the Executor's
+    ensure_xla_cache nor the serving core's own switch."""
+    theirs, ours = str(tmp_path / "theirs"), str(tmp_path / "ours")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=theirs,
+               PADDLE_TPU_PROGRAM_CACHE_DIR=ours)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    r = subprocess.run([sys.executable, "-c", _CACHE_RULE, ours],
+                       capture_output=True, text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    assert got == {"before": theirs, "after": theirs, "made": False}
+
+
+def test_default_cache_dir_is_fixed_inside_the_checkout(monkeypatch):
+    """Without the env override the cache is at ONE fixed path inside
+    the checkout — no home directory, pid, time or temporary name — and
+    the framework-free serving core spells the same path."""
+    from paddle_tpu import serving_core
+    monkeypatch.delenv("PADDLE_TPU_PROGRAM_CACHE_DIR")
+    want = os.path.join(REPO, ".paddle_tpu_cache", "aot")
+    assert program_cache.default_dir() == want
+    assert program_cache.resolve_dir() == want
+    assert serving_core.default_cache_dir() == want
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".paddle_tpu_cache/" in f.read().split()
+
+
+def test_framework_token_hashes_contents_not_mtimes(tmp_path):
+    """Two copies of one tree with different mtimes share a token (a
+    fresh checkout must hit what the old one wrote); an edit changes
+    it."""
+    import shutil
+    a, b = tmp_path / "a", tmp_path / "b"
+    (a / "sub").mkdir(parents=True)
+    (a / "x.py").write_text("x = 1\n")
+    (a / "sub" / "y.py").write_text("y = 2\n")
+    (a / "notes.txt").write_text("not source\n")
+    shutil.copytree(a, b)
+    for dirpath, _, files in os.walk(b):
+        for fn in files:
+            os.utime(os.path.join(dirpath, fn), (1, 1))
+    tok = program_cache.source_tree_token(str(a))
+    assert program_cache.source_tree_token(str(b)) == tok
+    (b / "notes.txt").write_text("still not source\n")
+    assert program_cache.source_tree_token(str(b)) == tok
+    (b / "sub" / "y.py").write_text("y = 3\n")
+    assert program_cache.source_tree_token(str(b)) != tok
+
+
+# ---------------------------------------------------------------------------
 # Predictor wiring
 # ---------------------------------------------------------------------------
 def test_predictor_program_cache(cache_root, tmp_path):
