@@ -214,6 +214,10 @@ def record(compiled, *, tag: str, key: str = "",
     rec = ProgramRecord(tag, key, meta)
     rec.compile_seconds = compile_seconds
     _fill_record(rec, compiled)
+    # the device trace's readers tell operations by the program's
+    # scopes through this table (telemetry.py)
+    from .. import telemetry
+    telemetry.note_device_program(compiled)
     with _LOCK:
         old = _PROGRAMS.pop(tag, None)
         if old is not None:

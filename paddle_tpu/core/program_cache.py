@@ -459,9 +459,21 @@ def exported_entry(cache_dir: str, fingerprint: str, fn, avals,
             _stat_add("STAT_program_cache_unexportable")
             return None
         store_trace(cache_dir, fingerprint, data)
-    entry = jax.jit(exported.call)
+    # The jitted entry is NAMED by tag and fingerprint, for two reasons.
+    # jax's persistent compile cache leaves metadata out of its key, so
+    # an executable compiled before a change of jax.named_scope names
+    # alone would come back WITHOUT the new names, and the device
+    # trace's readers would read nothing; the module's name is part of
+    # that key, and the fingerprint holds the framework's source token,
+    # so the two are told apart. And the trace's `XLA Modules` line then
+    # shows `jit_generation_mixed_<fingerprint>`, not `jit_call`.
+    def entry_fn(*args):
+        return exported.call(*args)
+    from . import program_accounting
+    entry_fn.__name__ = "%s_%s" % (
+        program_accounting.safe_tag(tag or "exported"), fingerprint[:12])
+    entry = jax.jit(entry_fn)
     if tag is not None:
-        from . import program_accounting
         entry = program_accounting.accounted(
             entry, avals, tag=program_accounting.safe_tag(tag),
             key=fingerprint[:12], meta=meta)

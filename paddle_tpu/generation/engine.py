@@ -550,8 +550,9 @@ class GenerationEngine:
                     logits, kp2, vp2, ks2, vs2 = forward_paged(
                         cfg, params, kp, vp, tables, positions, tokens,
                         k_scale_pools=ks, v_scale_pools=vs)
-                    nxt = sample_tokens(logits[sample_slots], temps,
-                                        tks, tps, seeds, steps)
+                    with jax.named_scope("sampler"):
+                        nxt = sample_tokens(logits[sample_slots], temps,
+                                            tks, tps, seeds, steps)
                     return nxt, kp2, vp2, ks2, vs2
                 pool_avals = (_sds(self.k_pools), _sds(self.v_pools),
                               _sds(self.k_scales), _sds(self.v_scales))
@@ -560,8 +561,9 @@ class GenerationEngine:
                         sample_slots, temps, tks, tps, seeds, steps):
                     logits, kp2, vp2 = forward_paged(
                         cfg, params, kp, vp, tables, positions, tokens)
-                    nxt = sample_tokens(logits[sample_slots], temps,
-                                        tks, tps, seeds, steps)
+                    with jax.named_scope("sampler"):
+                        nxt = sample_tokens(logits[sample_slots], temps,
+                                            tks, tps, seeds, steps)
                     return nxt, kp2, vp2
                 pool_avals = (_sds(self.k_pools), _sds(self.v_pools))
             m = self.max_blocks_per_seq
@@ -589,18 +591,20 @@ class GenerationEngine:
             # executable (draft pools are always fp32).
             if kind == "cow" and self.k_scales is not None:
                 def raw(kp, vp, ks, vs, src, dst):
-                    return (kp.at[:, dst].set(kp[:, src]),
-                            vp.at[:, dst].set(vp[:, src]),
-                            ks.at[:, dst].set(ks[:, src]),
-                            vs.at[:, dst].set(vs[:, src]))
+                    with jax.named_scope("kv_copy_on_write"):
+                        return (kp.at[:, dst].set(kp[:, src]),
+                                vp.at[:, dst].set(vp[:, src]),
+                                ks.at[:, dst].set(ks[:, src]),
+                                vs.at[:, dst].set(vs[:, src]))
                 avals = (_sds(self.k_pools), _sds(self.v_pools),
                          _sds(self.k_scales), _sds(self.v_scales),
                          jax.ShapeDtypeStruct((), jnp.int32),
                          jax.ShapeDtypeStruct((), jnp.int32))
             else:
                 def raw(kp, vp, src, dst):
-                    return (kp.at[:, dst].set(kp[:, src]),
-                            vp.at[:, dst].set(vp[:, src]))
+                    with jax.named_scope("kv_copy_on_write"):
+                        return (kp.at[:, dst].set(kp[:, src]),
+                                vp.at[:, dst].set(vp[:, src]))
                 kp0 = self.k_pools if kind == "cow" else self.dk_pools
                 vp0 = self.v_pools if kind == "cow" else self.dv_pools
                 avals = (_sds(kp0), _sds(vp0),
@@ -616,7 +620,8 @@ class GenerationEngine:
             def raw(params, kp, vp, tables, positions, tokens):
                 logits, kp2, vp2 = forward_paged(
                     dcfg, params, kp, vp, tables, positions, tokens)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sampler"):
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return nxt, kp2, vp2
             m = self.max_blocks_per_seq
             t = self.token_budget
@@ -667,6 +672,9 @@ class GenerationEngine:
                     kvq=self.kv_dtype,
                     kern=self.kernel,
                     policy=(self._policy_entry or {}).get("label", ""))
+        # the device trace's `XLA Modules` line shows the step by name
+        # (`jit_generation_mixed...`), with the cache as without
+        raw.__name__ = tag
         cache_dir = program_cache.resolve_dir(self._program_cache_dir)
         if cache_dir is not None:
             fp = program_cache.fn_fingerprint("generation_step", meta)
@@ -840,12 +848,16 @@ class GenerationEngine:
         advance every active lane (one mixed or decode batch), retire
         finished sequences. Returns the finished results (possibly
         empty)."""
-        self._admit()
-        if self.active_count == 0:
-            return []
-        if self.prefill_chunk:
-            return self._mixed_once()
-        return self._decode_once()
+        # host spans on the profiler's clock (telemetry.py): the five
+        # children cover the step, what is left is its self time
+        with _tm.span("pt/engine/step", track="generation"):
+            with _tm.span("pt/engine/admit", track="generation"):
+                self._admit()
+            if self.active_count == 0:
+                return []
+            if self.prefill_chunk:
+                return self._mixed_once()
+            return self._decode_once()
 
     def _admit(self) -> None:
         """Admit pending requests into free lanes, oldest first (the
@@ -986,7 +998,7 @@ class GenerationEngine:
                                  pad_stat="STAT_generation_pad_tokens")
         t0 = time.perf_counter()
         with _tm.trace_scope(tr.trace_id), \
-                _tm.span("generation/prefill", track="generation"):
+                _tm.span("pt/engine/prefill", track="generation"):
             fn = self._get_fn("prefill", bucket)
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :n] = prompt
@@ -1067,215 +1079,218 @@ class GenerationEngine:
         call step() again and the batch resumes exactly where it was —
         no token duplication, the basis of the mid-prompt fault
         recovery test."""
-        failpoint("generation.decode")
-        finished: List[GenerationResult] = []
-        # retire sequences whose PREVIOUS token already terminated them
-        for lane, seq in enumerate(self._lane_seq):
-            if seq is None:
-                continue
-            done = self._finish_reason(seq)
-            if done is not None:
-                finished.append(self._retire(lane, done))
-        t = self.token_budget
-        m = self.max_blocks_per_seq
-        # per-lane draft budget this step (0 with speculation off)
-        s_cap = self._spec_caps()
-        # provision every lane's write horizon: block extension plus
-        # copy-on-write of shared blocks in a write range. Pool
-        # exhaustion evicts cold cached prefixes LRU-first; only a dry
-        # cache preempts the youngest sequence. Re-running _provision
-        # after either is idempotent (already-extended / already-COWed
-        # lanes are no-ops).
-        while True:
-            try:
-                self._provision(s_cap)
-                break
-            except BlockPoolExhausted:
-                if self.prefix_cache is not None and \
-                        self.prefix_cache.evict_for(1):
+        with _tm.span("pt/engine/plan", track="generation"):
+            failpoint("generation.decode")
+            finished: List[GenerationResult] = []
+            # retire sequences whose PREVIOUS token already terminated them
+            for lane, seq in enumerate(self._lane_seq):
+                if seq is None:
                     continue
-                if not self._preempt_youngest():
-                    raise
-        decode_lanes = []
-        prefill_lanes = []
-        for ln, s in enumerate(self._lane_seq):
-            if s is None:
-                continue
-            if s.prefilled >= len(s.req.prompt):
-                decode_lanes.append(ln)
-            else:
-                prefill_lanes.append(ln)
-        if not decode_lanes and not prefill_lanes:
-            gauge_set("GAUGE_generation_active_seqs", 0)
-            return finished
-        # chunk plan BEFORE drafting, using the conservative s_cap slot
-        # layout: the model drafter's call 0 ingests these chunk tokens
-        # into the draft pools, so the plan must be fixed first. If the
-        # drafter then proposes fewer tokens the slack slots just pad.
-        slot = len(decode_lanes) + sum(s_cap.get(ln, 0)
-                                       for ln in decode_lanes)
-        chunk_plan = []              # (lane, seq, start, take)
-        for ln in prefill_lanes:
-            seq = self._lane_seq[ln]
-            n = len(seq.req.prompt)
-            take = min(self.prefill_chunk, n - seq.prefilled, t - slot)
-            if take <= 0:
-                continue
-            chunk_plan.append((ln, seq, seq.prefilled, take))
-            slot += take
-        drafts = self._propose(decode_lanes, s_cap, chunk_plan)
-        tables = np.full((t, m), TRASH_BLOCK, np.int32)
-        positions = np.zeros((t,), np.int32)
-        tokens = np.zeros((t,), np.int32)
-        # sampler arrays are [sample_width]: each LANE owns 1 + k
-        # consecutive rows (rows ln*(1+k) .. ln*(1+k)+k); a decode lane
-        # uses rows 0..len(drafts) for its verify chain, a prefill lane
-        # uses row 0 for its chunk's last slot. Unused rows gather the
-        # trash slot's logits (greedy, discarded on the host).
-        sw = self.sample_width
-        rpl = 1 + self.spec_tokens          # sampler rows per lane
-        sample_slots = np.zeros((sw,), np.int32)
-        temps = np.zeros((sw,), np.float32)
-        tks = np.zeros((sw,), np.int32)
-        tps = np.ones((sw,), np.float32)
-        seeds = np.zeros((sw,), np.int32)
-        steps = np.zeros((sw,), np.int32)
-        slot = 0
-        # (lane, seq, first sampler row, drafts riding this step)
-        decode_plan = []
-        for ln in decode_lanes:
-            seq = self._lane_seq[ln]
-            d = drafts.get(ln, [])[:s_cap.get(ln, 0)]
-            feed = [seq.generated[-1]] + d
-            base = len(seq.generated)
-            row0 = ln * rpl
-            for j in range(len(feed)):
-                tables[slot] = self._tables[ln]
-                positions[slot] = seq.ctx + j
-                tokens[slot] = feed[j]
-                sample_slots[row0 + j] = slot
-                temps[row0 + j] = self._temps[ln]
-                tks[row0 + j] = self._top_ks[ln]
-                tps[row0 + j] = self._top_ps[ln]
-                seeds[row0 + j] = self._seeds[ln]
-                # the fold_in step IS the absolute token index — row j
-                # samples exactly what plain decode would at that index
-                steps[row0 + j] = base + j
-                slot += 1
-            decode_plan.append((ln, seq, row0, d))
-        for ln, seq, start, take in chunk_plan:
-            if seq.prefilled:
-                # between chunks of one prompt — before any token-state
-                # mutation, so a caught raise resumes exactly
-                failpoint("generation.prefill_chunk")
-            sp = seq.req.sampling
-            for j in range(take):
-                tables[slot] = self._tables[ln]
-                positions[slot] = start + j
-                tokens[slot] = seq.req.prompt[start + j]
-                slot += 1
-            # only the chunk's LAST slot's sample matters (step 0, the
-            # first generated token) and only when the chunk completes
-            # the prompt — otherwise discarded on the host
-            row0 = ln * rpl
-            sample_slots[row0] = slot - 1
-            temps[row0] = sp.temperature
-            tks[row0] = sp.top_k
-            tps[row0] = sp.top_p
-            seeds[row0] = sp.seed
-            steps[row0] = 0
-        stat_add("STAT_generation_pad_tokens", t - slot)
-        if self.k_scales is not None:
-            # this step's fresh K/V rows quantize inside the compiled
-            # call — the failpoint models a fault in that stage, and it
-            # sits BEFORE any state mutation so a caught InjectedFault
-            # retries the step cleanly (tests/test_failpoints.py)
-            failpoint("generation.kv_quant")
-            bs_q = self.kv.block_size
-            written = {int(tables[i][positions[i] // bs_q])
-                       for i in range(slot)}
-            written.discard(TRASH_BLOCK)
-            stat_add("STAT_generation_kv_quant_blocks", len(written))
+                done = self._finish_reason(seq)
+                if done is not None:
+                    finished.append(self._retire(lane, done))
+            t = self.token_budget
+            m = self.max_blocks_per_seq
+            # per-lane draft budget this step (0 with speculation off)
+            s_cap = self._spec_caps()
+            # provision every lane's write horizon: block extension plus
+            # copy-on-write of shared blocks in a write range. Pool
+            # exhaustion evicts cold cached prefixes LRU-first; only a dry
+            # cache preempts the youngest sequence. Re-running _provision
+            # after either is idempotent (already-extended / already-COWed
+            # lanes are no-ops).
+            while True:
+                try:
+                    self._provision(s_cap)
+                    break
+                except BlockPoolExhausted:
+                    if self.prefix_cache is not None and \
+                            self.prefix_cache.evict_for(1):
+                        continue
+                    if not self._preempt_youngest():
+                        raise
+            decode_lanes = []
+            prefill_lanes = []
+            for ln, s in enumerate(self._lane_seq):
+                if s is None:
+                    continue
+                if s.prefilled >= len(s.req.prompt):
+                    decode_lanes.append(ln)
+                else:
+                    prefill_lanes.append(ln)
+            if not decode_lanes and not prefill_lanes:
+                gauge_set("GAUGE_generation_active_seqs", 0)
+                return finished
+            # chunk plan BEFORE drafting, using the conservative s_cap slot
+            # layout: the model drafter's call 0 ingests these chunk tokens
+            # into the draft pools, so the plan must be fixed first. If the
+            # drafter then proposes fewer tokens the slack slots just pad.
+            slot = len(decode_lanes) + sum(s_cap.get(ln, 0)
+                                           for ln in decode_lanes)
+            chunk_plan = []              # (lane, seq, start, take)
+            for ln in prefill_lanes:
+                seq = self._lane_seq[ln]
+                n = len(seq.req.prompt)
+                take = min(self.prefill_chunk, n - seq.prefilled, t - slot)
+                if take <= 0:
+                    continue
+                chunk_plan.append((ln, seq, seq.prefilled, take))
+                slot += take
+            drafts = self._propose(decode_lanes, s_cap, chunk_plan)
+            tables = np.full((t, m), TRASH_BLOCK, np.int32)
+            positions = np.zeros((t,), np.int32)
+            tokens = np.zeros((t,), np.int32)
+            # sampler arrays are [sample_width]: each LANE owns 1 + k
+            # consecutive rows (rows ln*(1+k) .. ln*(1+k)+k); a decode lane
+            # uses rows 0..len(drafts) for its verify chain, a prefill lane
+            # uses row 0 for its chunk's last slot. Unused rows gather the
+            # trash slot's logits (greedy, discarded on the host).
+            sw = self.sample_width
+            rpl = 1 + self.spec_tokens          # sampler rows per lane
+            sample_slots = np.zeros((sw,), np.int32)
+            temps = np.zeros((sw,), np.float32)
+            tks = np.zeros((sw,), np.int32)
+            tps = np.ones((sw,), np.float32)
+            seeds = np.zeros((sw,), np.int32)
+            steps = np.zeros((sw,), np.int32)
+            slot = 0
+            # (lane, seq, first sampler row, drafts riding this step)
+            decode_plan = []
+            for ln in decode_lanes:
+                seq = self._lane_seq[ln]
+                d = drafts.get(ln, [])[:s_cap.get(ln, 0)]
+                feed = [seq.generated[-1]] + d
+                base = len(seq.generated)
+                row0 = ln * rpl
+                for j in range(len(feed)):
+                    tables[slot] = self._tables[ln]
+                    positions[slot] = seq.ctx + j
+                    tokens[slot] = feed[j]
+                    sample_slots[row0 + j] = slot
+                    temps[row0 + j] = self._temps[ln]
+                    tks[row0 + j] = self._top_ks[ln]
+                    tps[row0 + j] = self._top_ps[ln]
+                    seeds[row0 + j] = self._seeds[ln]
+                    # the fold_in step IS the absolute token index — row j
+                    # samples exactly what plain decode would at that index
+                    steps[row0 + j] = base + j
+                    slot += 1
+                decode_plan.append((ln, seq, row0, d))
+            for ln, seq, start, take in chunk_plan:
+                if seq.prefilled:
+                    # between chunks of one prompt — before any token-state
+                    # mutation, so a caught raise resumes exactly
+                    failpoint("generation.prefill_chunk")
+                sp = seq.req.sampling
+                for j in range(take):
+                    tables[slot] = self._tables[ln]
+                    positions[slot] = start + j
+                    tokens[slot] = seq.req.prompt[start + j]
+                    slot += 1
+                # only the chunk's LAST slot's sample matters (step 0, the
+                # first generated token) and only when the chunk completes
+                # the prompt — otherwise discarded on the host
+                row0 = ln * rpl
+                sample_slots[row0] = slot - 1
+                temps[row0] = sp.temperature
+                tks[row0] = sp.top_k
+                tps[row0] = sp.top_p
+                seeds[row0] = sp.seed
+                steps[row0] = 0
+            stat_add("STAT_generation_pad_tokens", t - slot)
+            if self.k_scales is not None:
+                # this step's fresh K/V rows quantize inside the compiled
+                # call — the failpoint models a fault in that stage, and it
+                # sits BEFORE any state mutation so a caught InjectedFault
+                # retries the step cleanly (tests/test_failpoints.py)
+                failpoint("generation.kv_quant")
+                bs_q = self.kv.block_size
+                written = {int(tables[i][positions[i] // bs_q])
+                           for i in range(slot)}
+                written.discard(TRASH_BLOCK)
+                stat_add("STAT_generation_kv_quant_blocks", len(written))
         t0 = time.perf_counter()
         riders = decode_lanes + [c[0] for c in chunk_plan]
         tids = ",".join(
             tid for tid in (self._lane_seq[ln].req.trace.trace_id
                             for ln in riders) if tid) \
             if _tm.enabled() else None
-        with _tm.trace_scope(tids), \
-                _tm.span("generation/mixed_step", track="generation"):
-            fn = self._get_fn("mixed")
-            rest = (jnp.asarray(tables), jnp.asarray(positions),
-                    jnp.asarray(tokens), jnp.asarray(sample_slots),
-                    jnp.asarray(temps), jnp.asarray(tks),
-                    jnp.asarray(tps), jnp.asarray(seeds),
-                    jnp.asarray(steps))
-            if self.k_scales is not None:
-                (nxt, self.k_pools, self.v_pools, self.k_scales,
-                 self.v_scales) = fn(self.params, self.k_pools,
-                                     self.v_pools, self.k_scales,
-                                     self.v_scales, *rest)
-            else:
-                nxt, self.k_pools, self.v_pools = fn(
-                    self.params, self.k_pools, self.v_pools, *rest)
-            nxt = np.asarray(nxt)
+        with _tm.trace_scope(tids):
+            with _tm.span("pt/engine/dispatch", track="generation"):
+                fn = self._get_fn("mixed")
+                rest = (jnp.asarray(tables), jnp.asarray(positions),
+                        jnp.asarray(tokens), jnp.asarray(sample_slots),
+                        jnp.asarray(temps), jnp.asarray(tks),
+                        jnp.asarray(tps), jnp.asarray(seeds),
+                        jnp.asarray(steps))
+                if self.k_scales is not None:
+                    (nxt, self.k_pools, self.v_pools, self.k_scales,
+                     self.v_scales) = fn(self.params, self.k_pools,
+                                         self.v_pools, self.k_scales,
+                                         self.v_scales, *rest)
+                else:
+                    nxt, self.k_pools, self.v_pools = fn(
+                        self.params, self.k_pools, self.v_pools, *rest)
+            with _tm.span("pt/engine/fetch", track="generation"):
+                nxt = np.asarray(nxt)
         dt_us = (time.perf_counter() - t0) * 1e6
-        timer_observe("TIMER_generation_mixed_step_us", dt_us)
-        # the mixed step IS the decode step of this engine — keep the
-        # historic SLO timer (and its bench regression gate) alive
-        timer_observe("TIMER_generation_decode_step_us", dt_us)
-        now = time.perf_counter()
-        for ln, seq, row0, d in decode_plan:
-            s = len(d)
-            if s:
-                stat_add("STAT_generation_spec_proposed", s)
-            acc = 0
-            # row j's sample is valid iff every draft before it
-            # matched (its logits are conditioned on them); emit until
-            # the first mismatch. Rejected drafts' K/V writes sit past
-            # the new ctx — masked until next step's feed overwrites.
-            for j in range(s + 1):
-                tok = int(nxt[row0 + j])
-                seq.ctx += 1
-                self._ctx[ln] = seq.ctx
-                seq.generated.append(tok)
-                seq.req.trace.token()
-                timer_observe("TIMER_generation_inter_token_us",
-                              (now - seq.t_last_token) * 1e6)
-                seq.t_last_token = now
-                stat_add("STAT_generation_tokens")
-                done = self._finish_reason(seq)
-                if done is not None:
-                    finished.append(self._retire(ln, done))
-                    break
-                if j < s:
-                    if d[j] != tok:
+        with _tm.span("pt/engine/emit", track="generation"):
+            timer_observe("TIMER_generation_mixed_step_us", dt_us)
+            # the mixed step IS the decode step of this engine — keep the
+            # historic SLO timer (and its bench regression gate) alive
+            timer_observe("TIMER_generation_decode_step_us", dt_us)
+            now = time.perf_counter()
+            for ln, seq, row0, d in decode_plan:
+                s = len(d)
+                if s:
+                    stat_add("STAT_generation_spec_proposed", s)
+                acc = 0
+                # row j's sample is valid iff every draft before it
+                # matched (its logits are conditioned on them); emit until
+                # the first mismatch. Rejected drafts' K/V writes sit past
+                # the new ctx — masked until next step's feed overwrites.
+                for j in range(s + 1):
+                    tok = int(nxt[row0 + j])
+                    seq.ctx += 1
+                    self._ctx[ln] = seq.ctx
+                    seq.generated.append(tok)
+                    seq.req.trace.token()
+                    timer_observe("TIMER_generation_inter_token_us",
+                                  (now - seq.t_last_token) * 1e6)
+                    seq.t_last_token = now
+                    stat_add("STAT_generation_tokens")
+                    done = self._finish_reason(seq)
+                    if done is not None:
+                        finished.append(self._retire(ln, done))
                         break
-                    acc += 1
-            if s:
-                stat_add("STAT_generation_spec_accepted", acc)
-        for ln, seq, start, take in chunk_plan:
-            seq.prefilled = start + take
-            seq.ctx = seq.prefilled
-            self._ctx[ln] = seq.ctx
-            seq.req.trace.event("prefill_chunk", start=start,
-                                width=take)
-            self._publish_prefix(seq)
-            if seq.prefilled == len(seq.req.prompt):
-                # final chunk: its last slot's logits sampled the first
-                # generated token through the lane's sampler row 0
-                # (step 0 — identical fold_in to the two-phase prefill,
-                # so streams match bitwise).
-                seq.generated.append(int(nxt[ln * rpl]))
-                # TTFT lands at the TRUE first sampled token (first
-                # token() call only; replays re-observe TPOT)
-                seq.req.trace.token()
-                seq.t_last_token = now
-                stat_add("STAT_generation_tokens")
-                done = self._finish_reason(seq)
-                if done is not None:
-                    finished.append(self._retire(ln, done))
-        gauge_set("GAUGE_generation_active_seqs", self.active_count)
+                    if j < s:
+                        if d[j] != tok:
+                            break
+                        acc += 1
+                if s:
+                    stat_add("STAT_generation_spec_accepted", acc)
+            for ln, seq, start, take in chunk_plan:
+                seq.prefilled = start + take
+                seq.ctx = seq.prefilled
+                self._ctx[ln] = seq.ctx
+                seq.req.trace.event("prefill_chunk", start=start,
+                                    width=take)
+                self._publish_prefix(seq)
+                if seq.prefilled == len(seq.req.prompt):
+                    # final chunk: its last slot's logits sampled the first
+                    # generated token through the lane's sampler row 0
+                    # (step 0 — identical fold_in to the two-phase prefill,
+                    # so streams match bitwise).
+                    seq.generated.append(int(nxt[ln * rpl]))
+                    # TTFT lands at the TRUE first sampled token (first
+                    # token() call only; replays re-observe TPOT)
+                    seq.req.trace.token()
+                    seq.t_last_token = now
+                    stat_add("STAT_generation_tokens")
+                    done = self._finish_reason(seq)
+                    if done is not None:
+                        finished.append(self._retire(ln, done))
+            gauge_set("GAUGE_generation_active_seqs", self.active_count)
         return finished
 
     def _spec_caps(self) -> Dict[int, int]:
@@ -1464,34 +1479,35 @@ class GenerationEngine:
     def _decode_once(self) -> List[GenerationResult]:
         """Advance all active lanes one token (inactive lanes spin on
         the trash block)."""
-        # before the retire loop and any lane mutation: a caller that
-        # catches the InjectedFault can call step() again and the batch
-        # resumes exactly where it was (basis of the replay-under-fault
-        # determinism test)
-        failpoint("generation.decode")
-        finished: List[GenerationResult] = []
-        # retire sequences whose PREVIOUS token already terminated them
-        for lane, seq in enumerate(self._lane_seq):
-            if seq is None:
-                continue
-            done = self._finish_reason(seq)
-            if done is not None:
-                finished.append(self._retire(lane, done))
-        self._ensure_blocks()
-        w = self.decode_width
-        tokens = np.zeros((w,), np.int32)
-        steps = np.zeros((w,), np.int32)
-        active = [ln for ln, s in enumerate(self._lane_seq)
-                  if s is not None]
-        if not active:
-            gauge_set("GAUGE_generation_active_seqs", 0)
-            return finished
-        # idle lanes ride the fixed-width batch as padding
-        stat_add("STAT_generation_pad_tokens", w - len(active))
-        for ln in active:
-            seq = self._lane_seq[ln]
-            tokens[ln] = seq.generated[-1]
-            steps[ln] = len(seq.generated)
+        with _tm.span("pt/engine/plan", track="generation"):
+            # before the retire loop and any lane mutation: a caller that
+            # catches the InjectedFault can call step() again and the batch
+            # resumes exactly where it was (basis of the replay-under-fault
+            # determinism test)
+            failpoint("generation.decode")
+            finished: List[GenerationResult] = []
+            # retire sequences whose PREVIOUS token already terminated them
+            for lane, seq in enumerate(self._lane_seq):
+                if seq is None:
+                    continue
+                done = self._finish_reason(seq)
+                if done is not None:
+                    finished.append(self._retire(lane, done))
+            self._ensure_blocks()
+            w = self.decode_width
+            tokens = np.zeros((w,), np.int32)
+            steps = np.zeros((w,), np.int32)
+            active = [ln for ln, s in enumerate(self._lane_seq)
+                      if s is not None]
+            if not active:
+                gauge_set("GAUGE_generation_active_seqs", 0)
+                return finished
+            # idle lanes ride the fixed-width batch as padding
+            stat_add("STAT_generation_pad_tokens", w - len(active))
+            for ln in active:
+                seq = self._lane_seq[ln]
+                tokens[ln] = seq.generated[-1]
+                steps[ln] = len(seq.generated)
         t0 = time.perf_counter()
         # chrome-trace lanes carry which requests rode this step; the
         # join only matters (and only costs) when telemetry is on
@@ -1499,33 +1515,36 @@ class GenerationEngine:
             t for t in (self._lane_seq[ln].req.trace.trace_id
                         for ln in active) if t) \
             if _tm.enabled() else None
-        with _tm.trace_scope(tids), \
-                _tm.span("generation/decode_step", track="generation"):
-            fn = self._get_fn("decode")
-            nxt, self.k_pools, self.v_pools = fn(
-                self.params, self.k_pools, self.v_pools,
-                jnp.asarray(self._tables), jnp.asarray(self._ctx),
-                jnp.asarray(tokens), jnp.asarray(self._temps),
-                jnp.asarray(self._top_ks), jnp.asarray(self._top_ps),
-                jnp.asarray(self._seeds), jnp.asarray(steps))
-            nxt = np.asarray(nxt)
+        with _tm.trace_scope(tids):
+            with _tm.span("pt/engine/dispatch", track="generation"):
+                fn = self._get_fn("decode")
+                nxt, self.k_pools, self.v_pools = fn(
+                    self.params, self.k_pools, self.v_pools,
+                    jnp.asarray(self._tables), jnp.asarray(self._ctx),
+                    jnp.asarray(tokens), jnp.asarray(self._temps),
+                    jnp.asarray(self._top_ks),
+                    jnp.asarray(self._top_ps),
+                    jnp.asarray(self._seeds), jnp.asarray(steps))
+            with _tm.span("pt/engine/fetch", track="generation"):
+                nxt = np.asarray(nxt)
         timer_observe("TIMER_generation_decode_step_us",
                       (time.perf_counter() - t0) * 1e6)
-        now = time.perf_counter()
-        for ln in active:
-            seq = self._lane_seq[ln]
-            seq.ctx += 1
-            self._ctx[ln] = seq.ctx
-            seq.generated.append(int(nxt[ln]))
-            seq.req.trace.token()
-            timer_observe("TIMER_generation_inter_token_us",
-                          (now - seq.t_last_token) * 1e6)
-            seq.t_last_token = now
-            stat_add("STAT_generation_tokens")
-            done = self._finish_reason(seq)
-            if done is not None:
-                finished.append(self._retire(ln, done))
-        gauge_set("GAUGE_generation_active_seqs", self.active_count)
+        with _tm.span("pt/engine/emit", track="generation"):
+            now = time.perf_counter()
+            for ln in active:
+                seq = self._lane_seq[ln]
+                seq.ctx += 1
+                self._ctx[ln] = seq.ctx
+                seq.generated.append(int(nxt[ln]))
+                seq.req.trace.token()
+                timer_observe("TIMER_generation_inter_token_us",
+                              (now - seq.t_last_token) * 1e6)
+                seq.t_last_token = now
+                stat_add("STAT_generation_tokens")
+                done = self._finish_reason(seq)
+                if done is not None:
+                    finished.append(self._retire(ln, done))
+            gauge_set("GAUGE_generation_active_seqs", self.active_count)
         return finished
 
     def _finish_reason(self, seq: _Seq) -> Optional[str]:

@@ -211,43 +211,55 @@ def forward_paged(cfg: DecoderConfig, params: dict, k_pools, v_pools,
     v_scale_pools'); the fp32 call keeps the 3-tuple and the exact
     pre-quant expressions.
     """
+    # device-trace names of the step's phases (telemetry.py's
+    # convention): jax.named_scope is metadata of the compiled program
+    scope = jax.named_scope
     b = tokens.shape[0]
     bs = k_pools.shape[2]
-    x = _emb(params, "tok_emb", tokens) \
-        + _emb(params, "pos_emb", ctx_lens)                # [B,h]
+    with scope("embed"):
+        x = _emb(params, "tok_emb", tokens) \
+            + _emb(params, "pos_emb", ctx_lens)            # [B,h]
     sm_scale = 1.0 / math.sqrt(cfg.head_dim)
-    rows = jnp.arange(b)
-    blk = jnp.take_along_axis(
-        block_tables, (ctx_lens // bs)[:, None].astype(jnp.int32),
-        axis=1)[:, 0]                                      # [B]
-    off = ctx_lens % bs
+    with scope("kv_write"):
+        blk = jnp.take_along_axis(
+            block_tables, (ctx_lens // bs)[:, None].astype(jnp.int32),
+            axis=1)[:, 0]                                  # [B]
+        off = ctx_lens % bs
     quant_kv = k_scale_pools is not None
     new_k, new_v, new_ks, new_vs = [], [], [], []
     for i in range(cfg.layers):
-        xn = _ln(x, params["l%d_ln1_g" % i], params["l%d_ln1_b" % i])
-        q, k, v = _qkv(cfg, params, i, xn)                 # [B,H,D]
-        if quant_kv:
-            k, ksc = _quant.quantize_kv_rows(k, k_pools.dtype)
-            v, vsc = _quant.quantize_kv_rows(v, v_pools.dtype)
-            ksp = k_scale_pools[i].at[blk, off].set(ksc)
-            vsp = v_scale_pools[i].at[blk, off].set(vsc)
-            new_ks.append(ksp)
-            new_vs.append(vsp)
-        else:
-            ksp = vsp = None
-        kp = k_pools[i].at[blk, off].set(k)                # scatter new
-        vp = v_pools[i].at[blk, off].set(v)
-        new_k.append(kp)
-        new_v.append(vp)
+        with scope("qkv"):
+            xn = _ln(x, params["l%d_ln1_g" % i], params["l%d_ln1_b" % i])
+            q, k, v = _qkv(cfg, params, i, xn)             # [B,H,D]
+        with scope("kv_write"):
+            if quant_kv:
+                k, ksc = _quant.quantize_kv_rows(k, k_pools.dtype)
+                v, vsc = _quant.quantize_kv_rows(v, v_pools.dtype)
+                ksp = k_scale_pools[i].at[blk, off].set(ksc)
+                vsp = v_scale_pools[i].at[blk, off].set(vsc)
+                new_ks.append(ksp)
+                new_vs.append(vsp)
+            else:
+                ksp = vsp = None
+            kp = k_pools[i].at[blk, off].set(k)            # scatter new
+            vp = v_pools[i].at[blk, off].set(v)
+            new_k.append(kp)
+            new_v.append(vp)
+        # the kernel opens `paged_attention` itself, in either form
         o = paged_attention(q, kp, vp, block_tables, ctx_lens + 1,
                             sm_scale=sm_scale,
                             k_scales=ksp, v_scales=vsp)    # [B,H,D]
-        x = x + _mm(params, "l%d_wo" % i, o.reshape(b, cfg.hidden))
-        x = x + _mlp(params, i, _ln(x, params["l%d_ln2_g" % i],
-                                    params["l%d_ln2_b" % i]))
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    logits = _mm(params, "unembed", x)                     # [B, V]
-    if quant_kv:
-        return (logits, jnp.stack(new_k), jnp.stack(new_v),
-                jnp.stack(new_ks), jnp.stack(new_vs))
-    return logits, jnp.stack(new_k), jnp.stack(new_v)
+        with scope("attn_out"):
+            x = x + _mm(params, "l%d_wo" % i, o.reshape(b, cfg.hidden))
+        with scope("mlp"):
+            x = x + _mlp(params, i, _ln(x, params["l%d_ln2_g" % i],
+                                        params["l%d_ln2_b" % i]))
+    with scope("unembed"):
+        x = _ln(x, params["ln_f_g"], params["ln_f_b"])
+        logits = _mm(params, "unembed", x)                 # [B, V]
+    # putting the layers' pools back into one array is the update's too
+    with scope("kv_write"):
+        pools = (jnp.stack(new_k), jnp.stack(new_v))
+        if quant_kv:
+            pools += (jnp.stack(new_ks), jnp.stack(new_vs))
+    return (logits,) + pools

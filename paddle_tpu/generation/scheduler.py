@@ -32,11 +32,13 @@ Contracts, matching PredictorPool:
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from .. import telemetry as _tm
 from .. import tracing as _tr
 from ..flags import get_flag
 from ..monitor import gauge_set, stat_add
@@ -329,12 +331,18 @@ class GenerationPool:
     def _serve_loop(self) -> None:
         eng = self.engine
         while True:
-            with self._not_empty:
-                while not self._queue and eng.idle and not self._closed:
-                    self._not_empty.wait()
+            with contextlib.ExitStack() as held:
+                with _tm.span("pt/pool/wait", track="generation"):
+                    # taking the lock is part of the wait: every
+                    # submitter contends for it
+                    held.enter_context(self._not_empty)
+                    while not self._queue and eng.idle \
+                            and not self._closed:
+                        self._not_empty.wait()
                 if self._closed and not self._queue and eng.idle:
                     return
-                self._admit_locked()
+                with _tm.span("pt/pool/admit", track="generation"):
+                    self._admit_locked()
             # step OUTSIDE the lock: the decode executable can run
             # while submitters enqueue
             t0 = time.monotonic()
@@ -347,10 +355,11 @@ class GenerationPool:
                 raise _WorkerCrash(e)
             self._last_step_s = time.monotonic() - t0
             self._ok_since_restart = True
-            for res in finished:
-                fut = self._inflight.pop(res.request_id, None)
-                if fut is not None:
-                    fut._set(res)
+            with _tm.span("pt/pool/deliver", track="generation"):
+                for res in finished:
+                    fut = self._inflight.pop(res.request_id, None)
+                    if fut is not None:
+                        fut._set(res)
 
     def _reset_engine(self) -> None:
         """After a batch-level fault: rebuild the engine's sequence

@@ -260,8 +260,9 @@ class TrainStep:
         pgs = list(params.items())
         gs = [grads[n] for n, _ in pgs]
         if self.optimizer.grad_clip is not None:
-            clipped = self.optimizer.grad_clip.eager_apply(
-                list(zip([p for _, p in pgs], gs)))
+            with jax.named_scope("grad_clip"):
+                clipped = self.optimizer.grad_clip.eager_apply(
+                    list(zip([p for _, p in pgs], gs)))
             gs = [g for _, g in clipped]
         new_params, new_opt = {}, {}
         for (name, p), g in zip(pgs, gs):
@@ -291,6 +292,13 @@ class TrainStep:
         model, loss_fn = self.model, self.loss_fn
 
         def loss_of(p):
+            # `forward` on the device trace: model and loss. jax wraps
+            # the backward's operations in transpose(jvp(forward)), so
+            # no scope of the program's names them (telemetry.py)
+            with jax.named_scope("forward"):
+                return forward_and_loss(p)
+
+        def forward_and_loss(p):
             full = {**consts, **p}
             if self.amp_dtype is not None:
                 old_amp = tape._state.amp_dtype
@@ -370,11 +378,13 @@ class TrainStep:
                     losses.append(l)
                     acc = g if acc is None else jax.tree_util.tree_map(
                         jnp.add, acc, g)
-                grads = jax.tree_util.tree_map(
-                    lambda a: a * (1.0 / k), acc)
-                loss = jnp.mean(jnp.stack(losses))
-            new_params, new_opt = self._opt_update(params, grads, opt_state,
-                                                  lr_step)
+                with jax.named_scope("grad_accum"):
+                    grads = jax.tree_util.tree_map(
+                        lambda a: a * (1.0 / k), acc)
+                    loss = jnp.mean(jnp.stack(losses))
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = self._opt_update(
+                    params, grads, opt_state, lr_step)
             new_state = {**new_buf, **new_params}
             return loss, new_state, new_opt, lr_step + 1
 
@@ -567,11 +577,13 @@ class TrainStep:
                         # accumulated per microbatch so the fence stays
                         # pre-exchange even in fp32 mode, where the
                         # exchange runs inside this loop
-                        f = coll.phase_fence(g)
-                        fence = f if fence is None else fence + f
+                        with jax.named_scope("phase_fence"):
+                            f = coll.phase_fence(g)
+                            fence = f if fence is None else fence + f
                     if mode == "fp32":
                         # synchronous oracle: exchange EVERY microbatch
-                        g = coll.exchange_grads(g, cplan)
+                        with jax.named_scope("grad_exchange"):
+                            g = coll.exchange_grads(g, cplan)
                     acc = g if acc is None else jax.tree_util.tree_map(
                         jnp.add, acc, g)
                 grads = jax.tree_util.tree_map(
@@ -579,7 +591,8 @@ class TrainStep:
                 if mode != "fp32":
                     # int8: accumulate locally in fp32, quantize only
                     # the final cross-host exchange
-                    grads = coll.exchange_grads(grads, cplan)
+                    with jax.named_scope("grad_exchange"):
+                        grads = coll.exchange_grads(grads, cplan)
                 loss = jax.lax.pmean(jnp.mean(jnp.stack(losses)), dp_axis)
                 # float buffers (running stats) are computed per-shard;
                 # pmean makes the replicated out_spec well-defined
@@ -622,8 +635,9 @@ class TrainStep:
                 check_vma=False)
             res = synced(params, consts, rng, inputs, labels)
             loss, grads, new_buf = res[0], res[1], res[2]
-            new_params, new_opt = self._opt_update(params, grads,
-                                                   opt_state, lr_step)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = self._opt_update(
+                    params, grads, opt_state, lr_step)
             new_state = {**new_buf, **new_params}
             if phases:
                 return loss, new_state, new_opt, lr_step + 1, res[3]
@@ -687,13 +701,22 @@ class TrainStep:
         return opt_state
 
     def __call__(self, inputs, labels):
+        # host spans on the profiler's clock (telemetry.py): the whole
+        # call, with `stage` and `dispatch` (and the first call's
+        # `build`) as its children
+        from . import telemetry as _tm
+        with _tm.span("pt/trainstep/call", track="dispatch"):
+            return self._call(inputs, labels)
+
+    def _call(self, inputs, labels):
         from . import telemetry as _tm
         from .failpoints import failpoint
         # kill site for crash-injection tests: BEFORE the rng split and
         # any state mutation, so a caught crash leaves the step exactly
         # as it was after the last completed call
         failpoint("trainstep.step")
-        if self._step_fn is None:
+        built = self._step_fn is None
+        if built:
             plan = self.plan
             if plan is None and self.mesh is None and \
                     self.param_rules is None:
@@ -710,7 +733,7 @@ class TrainStep:
                     # annotate block below accepts both spellings
                     self.param_rules = \
                         lambda n, s, _p=plan: _p.param_sharding(n, s)
-            with _tm.span("trainstep/build", track="compile",
+            with _tm.span("pt/trainstep/build", track="compile",
                           timer="TIMER_trainstep_build_us"):
                 self._step_fn = self._build()
             self._state = state_of(self.model)
@@ -747,33 +770,34 @@ class TrainStep:
         from .flags import get_flag
         phases_on = bool(get_flag("FLAGS_step_phases"))
         t0 = time.perf_counter() if phases_on else 0.0
-        inputs = tuple(_unwrap(x) for x in (
-            inputs if isinstance(inputs, (tuple, list)) else (inputs,)))
-        labels = tuple(_unwrap(x) for x in (
-            labels if isinstance(labels, (tuple, list)) else (labels,)))
-        if self.plan is not None:
-            # plan-staged batches: the input rule decides (default
-            # shards dim 0 over the plan's data axis), and the
-            # STAT_mesh_* instruments see the traffic
-            def _stage(prefix, vals):
-                return tuple(
-                    None if x is None else self.plan.place(
-                        x, self.plan.input_sharding(
-                            "%s%d" % (prefix, i), np.shape(x)))
-                    for i, x in enumerate(vals))
-            inputs = _stage("input", inputs)
-            labels = _stage("label", labels)
-        elif self.mesh is not None:
-            # shard with THIS step's mesh — the global parallel-env mesh
-            # may be a different (even differently-sized) mesh
-            from .parallel.env import shard_batch
-            inputs = shard_batch(inputs, mesh=self.mesh)
-            labels = shard_batch(labels, mesh=self.mesh)
-        self._rng, sub = jax.random.split(self._rng)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            sub = jax.device_put(np.asarray(sub),
-                                 NamedSharding(self.mesh, P()))
+        with _tm.span("pt/trainstep/stage", track="dispatch"):
+            inputs = tuple(_unwrap(x) for x in (
+                inputs if isinstance(inputs, (tuple, list)) else (inputs,)))
+            labels = tuple(_unwrap(x) for x in (
+                labels if isinstance(labels, (tuple, list)) else (labels,)))
+            if self.plan is not None:
+                # plan-staged batches: the input rule decides (default
+                # shards dim 0 over the plan's data axis), and the
+                # STAT_mesh_* instruments see the traffic
+                def _stage(prefix, vals):
+                    return tuple(
+                        None if x is None else self.plan.place(
+                            x, self.plan.input_sharding(
+                                "%s%d" % (prefix, i), np.shape(x)))
+                        for i, x in enumerate(vals))
+                inputs = _stage("input", inputs)
+                labels = _stage("label", labels)
+            elif self.mesh is not None:
+                # shard with THIS step's mesh — the global parallel-env mesh
+                # may be a different (even differently-sized) mesh
+                from .parallel.env import shard_batch
+                inputs = shard_batch(inputs, mesh=self.mesh)
+                labels = shard_batch(labels, mesh=self.mesh)
+            self._rng, sub = jax.random.split(self._rng)
+            if self.mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                sub = jax.device_put(np.asarray(sub),
+                                     NamedSharding(self.mesh, P()))
         step_id = None
         if _tm.enabled():
             # inherit the loop's step scope (run_loop / hapi fit) or
@@ -795,11 +819,22 @@ class TrainStep:
             import contextlib
             plan_ctx = contextlib.nullcontext()
         t1 = time.perf_counter() if phases_on else 0.0
-        with _tm.span("trainstep/dispatch", step=step_id,
+        with _tm.span("pt/trainstep/dispatch", step=step_id,
                       track="dispatch",
                       timer="TIMER_trainstep_dispatch_us"), plan_ctx:
             res = self._step_fn(self._state, self._opt_state,
                                 self._lr_step, sub, (inputs, labels))
+        if built:
+            # once a build: the compiled step's instruction -> scope
+            # table for the device trace's readers (telemetry.py). The
+            # lowering and the executable are the ones the call above
+            # made (jax caches both), so nothing compiles twice
+            try:
+                _tm.note_device_program(self._step_fn.lower(
+                    res[1], res[2], res[3], sub,
+                    (inputs, labels)).compile())
+            except Exception:
+                pass
         if getattr(self, "_has_fence", False):
             loss, self._state, self._opt_state, self._lr_step, fence = res
         else:
@@ -872,7 +907,9 @@ class TrainStep:
             from . import profiler as _pf
             end_us = _tm.now_us()
             for ph, a, b in spans:
-                if ph == "total":
+                # stage and dispatch are on the trace already, as the
+                # pt/trainstep/stage and pt/trainstep/dispatch spans
+                if ph in ("total", "stage", "dispatch"):
                     continue
                 _pf.add_trace_event(
                     "phase/%s" % ph, end_us - (t5 - a) * 1e6,

@@ -262,18 +262,23 @@ def _fwd(q, k, v, bias, drop_mask, drop_seed, causal, sm_scale, block_q,
         pl.BlockSpec((1, 1, blk_q, d), lambda b, h, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, blk_q, 1), lambda b, h, i: (b, h, i, 0)),
     ]
-    o, lse = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-        cost_estimate=pl.CostEstimate(
-            flops=4 * batch * heads * sq * sk * d,
-            bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize * 2,
-            transcendentals=batch * heads * sq * sk),
-    )(*args)
+    # the device trace tells the kernels by these names (telemetry.py's
+    # convention): the scope for the readers, `name` for the custom call
+    with jax.named_scope("flash_attention"):
+        o, lse = pl.pallas_call(
+            kern,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            interpret=interpret,
+            name="flash_attention_fwd",
+            cost_estimate=pl.CostEstimate(
+                flops=4 * batch * heads * sq * sk * d,
+                bytes_accessed=(q.size + k.size + v.size)
+                * q.dtype.itemsize * 2,
+                transcendentals=batch * heads * sq * sk),
+        )(*args)
     return o, lse.reshape(batch, heads, sq)
 
 
@@ -444,14 +449,16 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, keep_prob,
                        block_k=blk_k, sk=sk, sq_total=sq,
                        keep_prob=keep_prob)
 
-    dq = pl.pallas_call(
-        dq_kern,
-        grid=(batch, heads, sq // blk_q),
-        in_specs=in_specs,
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_attention"):
+        dq = pl.pallas_call(
+            dq_kern,
+            grid=(batch, heads, sq // blk_q),
+            in_specs=in_specs,
+            out_specs=qspec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            interpret=interpret,
+            name="flash_attention_bwd_dq",
+        )(*args)
 
     # ---- dK/dV: grid over k blocks
     in_specs2 = [qfull, kspec, kspec, qfull, lse_full, lse_full]
@@ -487,15 +494,17 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, keep_prob,
                         sm_scale=sm_scale, causal=causal, block_q=blk_q,
                         sq=sq, sk_total=sk, keep_prob=keep_prob)
 
-    dk, dv = pl.pallas_call(
-        dkv_kern,
-        grid=(batch, heads, sk // blk_k),
-        in_specs=in_specs2,
-        out_specs=[kspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        interpret=interpret,
-    )(*args2)
+    with jax.named_scope("flash_attention"):
+        dk, dv = pl.pallas_call(
+            dkv_kern,
+            grid=(batch, heads, sk // blk_k),
+            in_specs=in_specs2,
+            out_specs=[kspec, kspec],
+            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            interpret=interpret,
+            name="flash_attention_bwd_dkv",
+        )(*args2)
 
     dbias = None
     if bias is not None and not bias_grad:

@@ -89,20 +89,23 @@ def _fwd(x, gamma, beta, eps, interpret):
     x2 = x.reshape(rows, f)
     blk = _pick_block(rows)
     grid = (rows // blk,)
-    o, mean, rstd = pl.pallas_call(
-        functools.partial(_fwd_kernel, eps=eps),
-        grid=grid,
-        in_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0)),
-                  pl.BlockSpec((f,), lambda i: (0,)),
-                  pl.BlockSpec((f,), lambda i: (0,))],
-        out_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0)),
-                   pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((blk, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rows, f), x.dtype),
-                   jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
-        interpret=interpret,
-    )(x2, gamma, beta)
+    # named for the device trace (telemetry.py's convention)
+    with jax.named_scope("layer_norm"):
+        o, mean, rstd = pl.pallas_call(
+            functools.partial(_fwd_kernel, eps=eps),
+            grid=grid,
+            in_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0)),
+                      pl.BlockSpec((f,), lambda i: (0,)),
+                      pl.BlockSpec((f,), lambda i: (0,))],
+            out_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0)),
+                       pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                       pl.BlockSpec((blk, 1), lambda i: (i, 0))],
+            out_shape=[jax.ShapeDtypeStruct((rows, f), x.dtype),
+                       jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+                       jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
+            interpret=interpret,
+            name="layer_norm_fwd",
+        )(x2, gamma, beta)
     return o.reshape(orig_shape), (x2, gamma, mean, rstd, orig_shape)
 
 
@@ -122,22 +125,24 @@ def _layer_norm_bwd(eps, interpret, res, g):
     rows = x2.shape[0]
     do2 = g.reshape(rows, f)
     blk = _pick_block(rows)
-    dx, dg, db = pl.pallas_call(
-        _bwd_kernel,
-        grid=(rows // blk,),
-        in_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0)),
-                  pl.BlockSpec((f,), lambda i: (0,)),
-                  pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, f), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0)),
-                   pl.BlockSpec((f,), lambda i: (0,)),
-                   pl.BlockSpec((f,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((rows, f), x2.dtype),
-                   jax.ShapeDtypeStruct((f,), jnp.float32),
-                   jax.ShapeDtypeStruct((f,), jnp.float32)],
-        interpret=interpret,
-    )(x2, gamma, mean, rstd, do2)
+    with jax.named_scope("layer_norm"):
+        dx, dg, db = pl.pallas_call(
+            _bwd_kernel,
+            grid=(rows // blk,),
+            in_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0)),
+                      pl.BlockSpec((f,), lambda i: (0,)),
+                      pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                      pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                      pl.BlockSpec((blk, f), lambda i: (i, 0))],
+            out_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0)),
+                       pl.BlockSpec((f,), lambda i: (0,)),
+                       pl.BlockSpec((f,), lambda i: (0,))],
+            out_shape=[jax.ShapeDtypeStruct((rows, f), x2.dtype),
+                       jax.ShapeDtypeStruct((f,), jnp.float32),
+                       jax.ShapeDtypeStruct((f,), jnp.float32)],
+            interpret=interpret,
+            name="layer_norm_bwd",
+        )(x2, gamma, mean, rstd, do2)
     return (dx.reshape(orig_shape), dg.astype(gamma.dtype),
             db.astype(gamma.dtype))
 

@@ -387,6 +387,7 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, cq, h, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables.astype(jnp.int32), q_lens.astype(jnp.int32),
       ctx_lens.astype(jnp.int32), *operands)
 
@@ -463,15 +464,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     (quantized pools, paddle_tpu/quant) flow to the dequant-fused
     forms of both paths; None = the untouched fp32 path."""
     mode = resolved_form()
-    if mode == "pallas":
-        return paged_attention_pallas(q, k_pool, v_pool, block_tables,
-                                      ctx_lens, sm_scale,
-                                      k_scales=k_scales,
-                                      v_scales=v_scales)
-    return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                     ctx_lens, sm_scale,
-                                     k_scales=k_scales,
-                                     v_scales=v_scales)
+    # ONE device-trace name for the gather and the attention over it,
+    # in either form: a kernel change is read by the same metric
+    with jax.named_scope("paged_attention"):
+        if mode == "pallas":
+            return paged_attention_pallas(q, k_pool, v_pool, block_tables,
+                                          ctx_lens, sm_scale,
+                                          k_scales=k_scales,
+                                          v_scales=v_scales)
+        return paged_attention_reference(q, k_pool, v_pool, block_tables,
+                                         ctx_lens, sm_scale,
+                                         k_scales=k_scales,
+                                         v_scales=v_scales)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
@@ -483,10 +487,11 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
     seam (+ kernel_form override) as the decode entry; k_scales /
     v_scales select the quantized-KV dequant-fused forms."""
     mode = resolved_form()
-    if mode == "pallas":
-        return ragged_paged_attention_pallas(
+    with jax.named_scope("paged_attention"):
+        if mode == "pallas":
+            return ragged_paged_attention_pallas(
+                q, k_pool, v_pool, block_tables, q_lens, ctx_lens,
+                sm_scale, k_scales=k_scales, v_scales=v_scales)
+        return ragged_paged_attention_reference(
             q, k_pool, v_pool, block_tables, q_lens, ctx_lens, sm_scale,
             k_scales=k_scales, v_scales=v_scales)
-    return ragged_paged_attention_reference(
-        q, k_pool, v_pool, block_tables, q_lens, ctx_lens, sm_scale,
-        k_scales=k_scales, v_scales=v_scales)

@@ -168,7 +168,10 @@ class Layer:
             res = hook(self, args)
             if res is not None:
                 args = res
-        out = self.forward(*args, **kwargs)
+        # device-trace name of everything this layer computes: metadata
+        # of the compiled program, free at run time (telemetry.py)
+        with jax.named_scope(type(self).__name__):
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_post_hooks:
             res = hook(self, args, out)
             if res is not None:

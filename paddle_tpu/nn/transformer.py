@@ -127,23 +127,26 @@ def _attention_core(q, k, v, attn_mask, dropout_p, training, is_causal=False):
                 "is active (FLAGS_flash_attention_fallback=True)",
                 exc_info=True)
     _PATH_LOG.append("composed")
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if attn_mask is not None:
-        scores = scores + attn_mask
-    if is_causal:
-        s = scores.shape[-1]
-        causal = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(causal, scores, jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    if want_dropout:
-        # the [B,H,Sq,Sk] keep decision is the composed path's biggest
-        # backward residual; apply_probs_dropout honors
-        # FLAGS_dropout_storage (u8 = 1 byte/elem, seed = key-only)
-        # through the same dispatch the dropout op uses
-        from ..ops.nn import apply_probs_dropout
-        probs = apply_probs_dropout(probs, 1.0 - dropout_p,
-                                    tape._state.next_key())
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(probs.dtype))
+    # `attention` on the device trace, where the Pallas kernel's calls
+    # read `flash_attention` (telemetry.py's convention)
+    with jax.named_scope("attention"):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        if attn_mask is not None:
+            scores = scores + attn_mask
+        if is_causal:
+            s = scores.shape[-1]
+            causal = jnp.tril(jnp.ones((s, s), bool))
+            scores = jnp.where(causal, scores, jnp.finfo(scores.dtype).min)
+        probs = jax.nn.softmax(scores, axis=-1)
+        if want_dropout:
+            # the [B,H,Sq,Sk] keep decision is the composed path's biggest
+            # backward residual; apply_probs_dropout honors
+            # FLAGS_dropout_storage (u8 = 1 byte/elem, seed = key-only)
+            # through the same dispatch the dropout op uses
+            from ..ops.nn import apply_probs_dropout
+            probs = apply_probs_dropout(probs, 1.0 - dropout_p,
+                                        tape._state.next_key())
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(probs.dtype))
 
 
 class MultiHeadAttention(Layer):

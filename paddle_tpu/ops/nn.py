@@ -417,29 +417,32 @@ def _mse_loss(ctx, ins, attrs):
 def _layer_norm(ctx, ins, attrs):
     # operators/layer_norm_op.cu: normalize over trailing dims from
     # begin_norm_axis; outputs saved mean/var over the leading dims.
-    x = ins["X"][0]
-    eps = attrs.get("epsilon", 1e-5)
-    axis = attrs.get("begin_norm_axis", 1)
-    if (axis == x.ndim - 1 and ins.get("Scale") and ins.get("Bias")
-            and jax.default_backend() == "tpu"):
-        from ..kernels.layer_norm import layer_norm_with_stats
-        y, mean, var = layer_norm_with_stats(
-            x, ins["Scale"][0], ins["Bias"][0], eps)
-        return {"Y": [y], "Mean": [mean], "Variance": [var]}
-    red = tuple(range(axis, x.ndim))
-    mean = jnp.mean(x, axis=red, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=red, keepdims=True)
-    y = (x - mean) * jax.lax.rsqrt(var + eps)
-    # Scale/Bias are stored flat [prod(norm_dims)] (layer_norm_op.cc
-    # contract); fold them back over the normalized region so a
-    # begin_norm_axis < ndim-1 (multi-dim region) broadcasts correctly
-    if ins.get("Scale"):
-        y = y * ins["Scale"][0].reshape(x.shape[axis:])
-    if ins.get("Bias"):
-        y = y + ins["Bias"][0].reshape(x.shape[axis:])
-    lead = int(np.prod(x.shape[:axis]))
-    return {"Y": [y], "Mean": [mean.reshape(lead)],
-            "Variance": [var.reshape(lead)]}
+    # `layer_norm` on the device trace, as the Pallas kernel names its
+    # own calls (telemetry.py's convention): one name for both forms
+    with jax.named_scope("layer_norm"):
+        x = ins["X"][0]
+        eps = attrs.get("epsilon", 1e-5)
+        axis = attrs.get("begin_norm_axis", 1)
+        if (axis == x.ndim - 1 and ins.get("Scale") and ins.get("Bias")
+                and jax.default_backend() == "tpu"):
+            from ..kernels.layer_norm import layer_norm_with_stats
+            y, mean, var = layer_norm_with_stats(
+                x, ins["Scale"][0], ins["Bias"][0], eps)
+            return {"Y": [y], "Mean": [mean], "Variance": [var]}
+        red = tuple(range(axis, x.ndim))
+        mean = jnp.mean(x, axis=red, keepdims=True)
+        var = jnp.mean(jnp.square(x - mean), axis=red, keepdims=True)
+        y = (x - mean) * jax.lax.rsqrt(var + eps)
+        # Scale/Bias are stored flat [prod(norm_dims)] (layer_norm_op.cc
+        # contract); fold them back over the normalized region so a
+        # begin_norm_axis < ndim-1 (multi-dim region) broadcasts correctly
+        if ins.get("Scale"):
+            y = y * ins["Scale"][0].reshape(x.shape[axis:])
+        if ins.get("Bias"):
+            y = y + ins["Bias"][0].reshape(x.shape[axis:])
+        lead = int(np.prod(x.shape[:axis]))
+        return {"Y": [y], "Mean": [mean.reshape(lead)],
+                "Variance": [var.reshape(lead)]}
 
 
 @register_op("batch_norm",

@@ -13,9 +13,35 @@ together behind ONE switch:
 - latencies land in monitor.py timer histograms (TIMER_* names),
 
 so one `FLAGS_telemetry=True` run yields both a timeline and
-aggregates. Everything here is OFF by default: the disabled fast path
-of `span()` is a single dict lookup returning a shared no-op context
-manager (bench.py's observability block pins the disabled overhead).
+aggregates. The chrome timeline, the timers and the flight recorder
+are OFF by default.
+
+On the device trace (no flag). Every `span()` also opens a
+`jax.profiler.TraceAnnotation` of the same name, so whenever a jax
+profiler session is open — whoever opened it: the benchmark's traced
+run, `profiler.start_device_trace`, an operator's
+`jax.profiler.start_trace` — the program's spans lie in the same
+`.xplane.pb` as the device's operations, on one clock. With
+FLAGS_telemetry off a span is that annotation and nothing else: one
+dict lookup and one small object, under a microsecond with no session
+open. Hence the budget: at most 20 span entries per engine step and 6
+per `TrainStep.__call__`, none inside a per-slot, per-token or
+per-lane loop (tests/test_device_trace_names.py counts them).
+
+ONE naming convention (docs/observability.md, "On the device trace"):
+- host spans are `pt/<module>/<phase>` (`pt/engine/plan`,
+  `pt/pool/wait`, `pt/trainstep/dispatch`); spans older than the
+  convention keep `<module>/<phase>`; the benchmark's own start
+  `bench/`;
+- program scopes on the device (`jax.named_scope`, metadata of the
+  compiled program, free at run time) are lower-case words for phases
+  (`forward`, `optimizer`, `embed`, `qkv`, `kv_write`,
+  `paged_attention`, `attn_out`, `mlp`, `unembed`, `sampler`,
+  `kv_copy_on_write`, `flash_attention`, `layer_norm`, `attention`)
+  and class names for layers
+  (`BertModel`, `TransformerEncoderLayer`, `Linear`: nn/layer.py opens
+  them). jax wraps the backward's operations in `transpose(jvp(...))`
+  around the forward's names; no scope of the program's names them.
 
 Step correlation: the executor (or any loop) enters `step_scope(n)`;
 every span and FetchHandle created under it inherits step id `n`, so a
@@ -30,16 +56,20 @@ into a reconstructable timeline.
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
-from collections import deque
+from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from . import monitor, profiler
 from .flags import get_flag
 
 __all__ = ["enabled", "span", "step_scope", "current_step",
            "trace_scope", "current_trace", "counter_sample",
+           "note_device_program", "device_op_names",
            "flight_begin", "flight_note", "flight_records",
            "flight_dump", "flight_reset", "attach_flight"]
 
@@ -140,7 +170,7 @@ _NOOP = _NoopSpan()
 
 class _Span:
     __slots__ = ("name", "step", "track", "cat", "timer", "trace",
-                 "tid", "args", "_t0")
+                 "tid", "args", "_t0", "_ann")
 
     def __init__(self, name, step, track, cat, timer, trace, tid, args):
         self.name = name
@@ -153,11 +183,21 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        # the same span on the profiler's clock, with the step and the
+        # riders' trace ids for an operator's view
+        kw = dict(self.args) if self.args else {}
+        if self.step is not None:
+            kw["step"] = self.step
+        if self.tid is not None:
+            kw["trace"] = self.tid
+        self._ann = TraceAnnotation(self.name, **kw)
+        self._ann.__enter__()
         self._t0 = now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = now_us()
+        self._ann.__exit__(*exc)
         dur = t1 - self._t0
         if self.trace:
             args = self.args
@@ -176,16 +216,20 @@ def span(name: str, *, step: Optional[int] = None,
          track: Optional[str] = None, cat: str = "telemetry",
          timer: Optional[str] = None, trace: bool = True,
          args: Optional[Dict[str, Any]] = None):
-    """Context manager timing one region. No-op (shared object, no
-    allocation) when telemetry is off. `step=None` inherits the
-    thread's step_scope; the thread's trace_scope ids (if any) land in
-    the event's args.trace, correlating chrome-trace lanes with
-    /tracez. `timer` additionally records the duration in the named
-    monitor histogram; `trace=False` keeps high-frequency timers out of
-    the chrome timeline (aggregate-only); `args` adds extra chrome-
-    trace event args."""
+    """Context manager around one region. Always a
+    `jax.profiler.TraceAnnotation` of the same name: it lands in any
+    open jax profiler session, beside the device's operations, and
+    costs under a microsecond when none is open. With telemetry off it
+    is that annotation and nothing else (no chrome event, no timer).
+    With it on: `step=None` inherits the thread's step_scope; the
+    thread's trace_scope ids (if any) land in the event's args.trace,
+    correlating chrome-trace lanes with /tracez. `timer` additionally
+    records the duration in the named monitor histogram; `trace=False`
+    keeps high-frequency timers out of the chrome timeline
+    (aggregate-only); `args` adds extra chrome-trace event args. Step,
+    trace ids and args ride the annotation as keyword arguments too."""
     if not enabled():
-        return _NOOP
+        return TraceAnnotation(name)
     if step is None:
         step = current_step()
     return _Span(name, step, track, cat, timer, trace,
@@ -200,6 +244,70 @@ def counter_sample(name: str, value: Optional[float] = None) -> None:
     if value is None:
         value = monitor.stat_get(name)
     profiler.add_counter_event(name, value)
+
+
+# ---------------------------------------------------------------------------
+# device-side names: HLO instruction -> scope path
+# ---------------------------------------------------------------------------
+# The profiler names a device operation by its HLO instruction
+# (`%fusion.35 = ...`). The scope path the program gave it
+# (`jit(step)/transpose(jvp(forward))/Linear/dot_general`) is in the
+# trace file too, but on the event's METADATA (stat `tf_op`), which
+# `jax.profiler.ProfileData` does not show (seen on the chip, PR 27). So
+# the program keeps, for the steps it compiles, the table from
+# instruction to path, read once from the compiled module's own text; a
+# trace reader joins the two by name. TrainStep and
+# core/program_accounting.py (the engine's steps, the Executor's) feed
+# it at compile time. Bounded: the newest _DEVICE_PROGRAMS modules.
+
+_DEVICE_PROGRAMS = 16
+_device_ops: "OrderedDict[str, Dict[str, str]]" = OrderedDict()
+_DEVICE_LOCK = threading.Lock()
+_HLO_MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([^\s=]+)\s=\s.*?\bop_name="([^"]*)"', re.M)
+
+
+def _module_text(compiled) -> str:
+    """The compiled module's text with names and metadata and little
+    else: a Mosaic kernel's body alone is 100 KB of base64 a call, and
+    BERT-base's step holds 88 of them."""
+    try:
+        from jax._src.lib import _jax
+        opts = _jax.HloPrintOptions()
+        opts.print_backend_config = False
+        opts.print_large_constants = False
+        opts.print_operand_shape = False
+        opts.print_result_shape = False
+        return compiled.runtime_executable().hlo_modules()[0].to_string(
+            opts)
+    except Exception:       # another jaxlib: the whole text, slower
+        return compiled.as_text()
+
+
+def note_device_program(compiled) -> None:
+    """Remember which scope path each instruction of one compiled
+    program (a `jax.stages.Compiled`) lies under. An observation, never
+    a dependency: any failure leaves the table as it was."""
+    try:
+        text = _module_text(compiled)
+        name = _HLO_MODULE.match(text).group(1)
+        table = {m.group(1): m.group(2)
+                 for m in _HLO_OP_NAME.finditer(text)}
+    except Exception:
+        return
+    with _DEVICE_LOCK:
+        _device_ops.pop(name, None)
+        _device_ops[name] = table
+        while len(_device_ops) > _DEVICE_PROGRAMS:
+            _device_ops.popitem(last=False)
+
+
+def device_op_names() -> Dict[str, Dict[str, str]]:
+    """{module name as the trace's `XLA Modules` line has it, less the
+    id in brackets: {HLO instruction name: scope path}}."""
+    with _DEVICE_LOCK:
+        return dict(_device_ops)
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,389 @@
+"""The program's names on the profiler's trace (docs/observability.md, "On
+the device trace"): host spans through `telemetry.span` as
+`jax.profiler.TraceAnnotation`s, `jax.named_scope` on every phase of the two
+compiled steps, and the table from HLO instruction to scope path that the
+trace's readers join by name.
+
+On XLA:CPU a profiler session has no device plane; what is checked here is
+the host side of the trace and the metadata of the lowered programs. The
+device side is read on the chip (`benchmark/run.py --trace 1`).
+"""
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import jit as pjit
+from paddle_tpu import monitor, profiler, telemetry
+from paddle_tpu.generation import (DecoderConfig, GenerationEngine,
+                                   GenerationRequest)
+from paddle_tpu.generation.model import init_params
+from paddle_tpu.models import bert
+
+ENGINE_CHILDREN = ("pt/engine/admit", "pt/engine/plan", "pt/engine/dispatch",
+                   "pt/engine/fetch", "pt/engine/emit")
+TRAIN_CHILDREN = ("pt/trainstep/stage", "pt/trainstep/dispatch")
+
+
+def _engine():
+    cfg = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=2,
+                        max_seq_len=64)
+    eng = GenerationEngine(cfg, init_params(cfg, 0), decode_width=4,
+                           num_blocks=32)
+    eng.warmup()
+    return eng
+
+
+def _drive(eng, n=3):
+    for i in range(n):
+        eng.submit(GenerationRequest(prompt=list(range(1, 12 + i)),
+                                     max_new_tokens=6))
+    steps = 0
+    while not eng.idle:
+        eng.step()
+        steps += 1
+    return steps
+
+
+def _train_step():
+    cfg = bert.BertConfig(vocab_size=128, hidden_size=32,
+                          num_hidden_layers=2, num_attention_heads=2,
+                          intermediate_size=64, max_position_embeddings=32)
+    pt.seed(1)
+    model = bert.BertForPretraining(cfg)
+    opt = pt.optimizer.Adam(1e-3, parameters=model.parameters())
+    step = pjit.TrainStep(model, bert.pretraining_loss, opt,
+                          amp_dtype="bfloat16")
+    rng = np.random.RandomState(0)
+    B, S, M = 4, 16, 3
+    ids = rng.randint(0, 128, (B, S)).astype(np.int32)
+    pos = np.stack([rng.choice(S, M, replace=False)
+                    for _ in range(B)]).astype(np.int32)
+    mlm = rng.randint(0, 128, (B, M)).astype(np.int32)
+    nsp = rng.randint(0, 2, (B,)).astype(np.int32)
+    return step, ((ids, None, None, pos), (mlm, nsp))
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One profiler session over a few engine steps and a few train steps,
+    FLAGS_telemetry off, read back as the benchmark reads its traces."""
+    from benchmark import trace_reduce
+    assert not telemetry.enabled()
+    eng = _engine()
+    step, batch = _train_step()
+    step(*batch)                          # build and compile outside
+    profiler.reset_profiler()
+    span_timers = ("TIMER_trainstep_dispatch_us", "TIMER_trainstep_build_us")
+    timers0 = [monitor.timer_get(t)["count"] for t in span_timers]
+    d = str(tmp_path_factory.mktemp("trace"))
+    opts = jax.profiler.ProfileOptions()      # as the benchmark's Tracer
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        n_engine = _drive(eng)
+        for _ in range(3):
+            loss = step(*batch)
+        loss.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    planes = trace_reduce.from_xplane(trace_reduce.find_xplane(d))
+    lines = [l["events"] for p in planes for l in p["lines"]
+             if any(e[0].startswith("pt/") for e in l["events"])]
+    timers1 = [monitor.timer_get(t)["count"] for t in span_timers]
+    return {"lines": lines, "engine_steps": n_engine,
+            "chrome_events": profiler.summary(),
+            "span_timers_grew": timers1 != timers0}
+
+
+def _children(line, parent):
+    """[(parent event, {child name: [child events]})] by containment."""
+    out = []
+    for name, s, d, _ in line:
+        if name != parent:
+            continue
+        kids = {}
+        for n, cs, cd, _ in line:
+            if n != parent and n.startswith("pt/") and s <= cs \
+                    and cs + cd <= s + d:
+                kids.setdefault(n, []).append((cs, cd))
+        out.append(((s, d), kids))
+    return out
+
+
+def test_engine_step_lands_in_the_trace_with_its_five_children(traced):
+    line = next(l for l in traced["lines"]
+                if any(e[0] == "pt/engine/step" for e in l))
+    steps = _children(line, "pt/engine/step")
+    assert len(steps) == traced["engine_steps"]
+    full = [k for _, k in steps if "pt/engine/dispatch" in k]
+    assert len(full) >= 3
+    for kids in full:
+        assert sorted(kids) == sorted(ENGINE_CHILDREN)
+        assert all(len(v) == 1 for v in kids.values())
+
+
+def test_the_children_cover_the_engine_step(traced):
+    line = next(l for l in traced["lines"]
+                if any(e[0] == "pt/engine/step" for e in l))
+    shares = []
+    for (s, d), kids in _children(line, "pt/engine/step"):
+        if "pt/engine/dispatch" not in kids:
+            continue
+        shares.append(sum(cd for v in kids.values() for _, cd in v) / d)
+    # what is left is the step's self time: a few statements between the
+    # children. A toy step on XLA:CPU lasts 2 ms, so that a scheduling hiccup
+    # of 0.1 ms shows; the middle step is held to the 95 %, each to 90 %
+    assert sorted(shares)[len(shares) // 2] >= 0.95, shares
+    assert min(shares) >= 0.90, shares
+
+
+def test_trainstep_call_lands_in_the_trace_with_its_two_children(traced):
+    line = next(l for l in traced["lines"]
+                if any(e[0] == "pt/trainstep/call" for e in l))
+    calls = _children(line, "pt/trainstep/call")
+    assert len(calls) == 3
+    for _, kids in calls:
+        assert sorted(kids) == sorted(TRAIN_CHILDREN)
+    # the build was outside the session: it is a span of the first call only
+    assert not any(e[0] == "pt/trainstep/build" for e in line)
+
+
+def test_with_telemetry_off_a_span_adds_no_chrome_event_and_no_timer(traced):
+    # the timers the engine observes itself (TIMER_generation_*) are not a
+    # span's; the ones a span feeds (`timer=`) stay as they were
+    assert traced["chrome_events"] == []
+    assert not traced["span_timers_grew"]
+
+
+def _scopes_in(text):
+    """Every name-stack component in a lowered module's debug locations."""
+    found = set()
+    for path in re.findall(r'"((?:jit|pjit)\([^"]*)"', text):
+        found.update(re.findall(r"[A-Za-z_][\w.]*", path))
+    return found
+
+
+@pytest.fixture(scope="module")
+def train_text():
+    step, batch = _train_step()
+    step(*batch)
+    lowered = step._step_fn.lower(step._state, step._opt_state,
+                                  step._lr_step, step._rng, batch)
+    return lowered.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [
+    "forward", "optimizer", "attention", "layer_norm", "BertForPretraining",
+    "BertModel", "BertEmbeddings", "TransformerEncoderLayer",
+    "MultiHeadAttention", "Linear", "LayerNorm", "BertLMHead"])
+def test_train_step_lowering_holds_the_scope(train_text, name):
+    # `attention` is the dense path's name: at these sizes the router does
+    # not take the Pallas kernel, whose calls read `flash_attention`
+    assert name in _scopes_in(train_text)
+
+
+def test_backward_is_told_by_jaxs_own_wrapper(train_text):
+    assert "transpose(jvp(forward))" in train_text
+    assert "jvp(forward)" in train_text
+
+
+@pytest.fixture(scope="module")
+def mixed_text():
+    eng = _engine()
+    got = {}
+
+    def capture(kind, bucket, raw, avals):
+        got.update(raw=raw, avals=avals)
+        return jax.jit(raw)
+    eng._aot_or_jit = capture
+    eng._build_fn("mixed", 0)
+    return jax.jit(got["raw"]).lower(*got["avals"]).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", ["embed", "qkv", "kv_write",
+                                  "paged_attention", "attn_out", "mlp",
+                                  "unembed", "sampler"])
+def test_mixed_step_lowering_holds_the_scope(mixed_text, name):
+    assert name in _scopes_in(mixed_text)
+
+
+@pytest.mark.parametrize("kernel,scope,names", [
+    ("flash", "flash_attention", ("flash_attention_fwd",
+                                  "flash_attention_bwd_dq",
+                                  "flash_attention_bwd_dkv")),
+    ("layer_norm", "layer_norm", ("layer_norm_fwd", "layer_norm_bwd")),
+    ("paged", "paged_attention", ("paged_attention",)),
+])
+def test_pallas_calls_carry_a_scope_and_a_name(kernel, scope, names):
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.kernels import layer_norm as ln
+    from paddle_tpu.kernels import paged_attention as pa
+    if kernel == "flash":
+        q = jnp.ones((1, 2, 128, 64), jnp.float32)
+
+        def f(q):
+            return fa._flash(q, q, q, None, None, None, False, 0.125, 128,
+                             128, True, 1.0, True).sum()
+        text = jax.jit(jax.grad(f)).lower(q).as_text(debug_info=True)
+    elif kernel == "layer_norm":
+        x = jnp.ones((8, 128), jnp.float32)
+        g = jnp.ones((128,), jnp.float32)
+
+        def f(x):
+            return ln._layer_norm(x, g, g, 1e-5, True).sum()
+        text = jax.jit(jax.grad(f)).lower(x).as_text(debug_info=True)
+    else:
+        q = jnp.ones((2, 2, 16), jnp.float32)
+        pool = jnp.ones((4, 8, 2, 16), jnp.float32)
+        tables = jnp.zeros((2, 2), jnp.int32)
+        ctx = jnp.ones((2,), jnp.int32)
+        with pa.kernel_form("pallas"):
+            text = jax.jit(lambda q: pa.paged_attention(
+                q, pool, pool, tables, ctx)).lower(q).as_text(
+                    debug_info=True)
+    assert scope in _scopes_in(text)
+    for n in names:
+        assert n in text
+
+
+def _count_spans(monkeypatch):
+    calls = []
+    real = telemetry.span
+
+    def counting(name, **kw):
+        calls.append(name)
+        return real(name, **kw)
+    monkeypatch.setattr(telemetry, "span", counting)
+    return calls
+
+
+def test_span_budget_of_an_engine_step(monkeypatch):
+    eng = _engine()
+    calls = _count_spans(monkeypatch)
+    steps = _drive(eng)
+    assert steps >= 3 and calls
+    assert len(calls) <= 20 * steps
+    per_step = len(calls) / steps
+    assert per_step <= 6, (per_step, sorted(set(calls)))
+    assert all(n.startswith("pt/engine/") for n in calls)
+
+
+def test_span_budget_of_a_pool_round(monkeypatch):
+    from paddle_tpu.generation import GenerationPool
+    eng = _engine()
+    calls = _count_spans(monkeypatch)
+    pool = GenerationPool(eng)
+    try:
+        futs = [pool.submit(GenerationRequest(prompt=[1, 2, 3, 4 + i],
+                                              max_new_tokens=4))
+                for i in range(3)]
+        for f in futs:
+            assert len(f.result(timeout=120).tokens) == 4
+    finally:
+        pool.close()
+    steps = calls.count("pt/engine/step")
+    assert steps >= 1
+    assert {"pt/pool/wait", "pt/pool/admit", "pt/pool/deliver"} <= set(calls)
+    # the pool's three and the engine's six, a round
+    assert len(calls) <= 20 * steps
+
+
+def test_span_budget_of_a_trainstep_call(monkeypatch):
+    step, batch = _train_step()
+    step(*batch)
+    calls = _count_spans(monkeypatch)
+    for _ in range(4):
+        step(*batch)
+    assert calls.count("pt/trainstep/call") == 4
+    assert len(calls) <= 6 * 4
+    assert set(calls) == {"pt/trainstep/call", "pt/trainstep/stage",
+                          "pt/trainstep/dispatch"}
+
+
+def test_trace_ids_ride_the_annotation_only_with_telemetry_on(monkeypatch):
+    from paddle_tpu.flags import get_flags
+    seen = []
+
+    class Spy(telemetry.TraceAnnotation):
+        def __init__(self, name, **kw):
+            seen.append((name, kw))
+            super().__init__(name, **kw)
+    monkeypatch.setattr(telemetry, "TraceAnnotation", Spy)
+    saved = get_flags(["FLAGS_telemetry"])
+    try:
+        pt.set_flags({"FLAGS_telemetry": True})
+        with telemetry.step_scope(7), telemetry.trace_scope("a1,b2"):
+            with telemetry.span("pt/engine/dispatch", track="generation"):
+                pass
+        pt.set_flags({"FLAGS_telemetry": False})
+        with telemetry.trace_scope("c3"):
+            with telemetry.span("pt/engine/fetch"):
+                pass
+    finally:
+        pt.set_flags(saved)
+        profiler.reset_profiler()
+    assert seen == [("pt/engine/dispatch", {"step": 7, "trace": "a1,b2"}),
+                    ("pt/engine/fetch", {})]
+
+
+def test_the_compiled_steps_feed_the_instruction_to_scope_table():
+    eng = _engine()
+    step, batch = _train_step()
+    step(*batch)
+    table = telemetry.device_op_names()
+    mixed = [m for m in table if m.startswith("jit_generation_mixed")]
+    assert mixed and "jit_step" in table
+    paths = set(table[mixed[-1]].values())
+    for scope in ("sampler", "paged_attention", "kv_write", "unembed"):
+        assert any("/%s/" % scope in p for p in paths), scope
+    train = set(table["jit_step"].values())
+    assert any("/optimizer/" in p for p in train)
+    assert any("transpose(jvp(forward))" in p for p in train)
+    del eng
+
+
+def test_the_table_is_bounded_and_never_raises():
+    telemetry.note_device_program(object())     # not a compiled program
+    for i in range(telemetry._DEVICE_PROGRAMS + 4):
+        def f(x):
+            with jax.named_scope("mlp"):
+                return x * 2.0
+        f.__name__ = "probe_%d" % i
+        telemetry.note_device_program(
+            jax.jit(f).lower(np.ones((2,), np.float32)).compile())
+    table = telemetry.device_op_names()
+    assert len(table) <= telemetry._DEVICE_PROGRAMS
+    newest = "jit_probe_%d" % (telemetry._DEVICE_PROGRAMS + 3)
+    assert newest in table and "jit_probe_0" not in table
+    assert any(p.endswith("/mlp/mul") for p in table[newest].values())
+
+
+def test_a_cached_entry_is_named_by_tag_and_fingerprint(tmp_path):
+    """jax's persistent compile cache leaves metadata out of its key: the
+    module's name, which is in it, tells a program apart from one compiled
+    before its scopes were named (core/program_cache.py)."""
+    import jax.numpy as jnp
+    from paddle_tpu.core import program_cache
+
+    def fn(x):
+        with jax.named_scope("mlp"):
+            return x + 1.0
+    avals = (jax.ShapeDtypeStruct((4,), jnp.float32),)
+    fps = [program_cache.fn_fingerprint("probe", {"v": v}) for v in (1, 2)]
+    names = []
+    for fp in fps:
+        entry = program_cache.exported_entry(str(tmp_path), fp, fn, avals,
+                                             tag="generation_probe")
+        jitted = getattr(entry, "_fallback", entry)
+        text = jitted.lower(*avals).as_text(debug_info=True)
+        names.append(re.search(r"module @(\S+)", text).group(1))
+        assert "mlp" in _scopes_in(text)    # the names survive jax.export
+    assert names[0] == "jit_generation_probe_%s" % fps[0][:12]
+    assert names[1] == "jit_generation_probe_%s" % fps[1][:12]
+    assert os.listdir(str(tmp_path))
