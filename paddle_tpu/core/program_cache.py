@@ -417,13 +417,16 @@ def fn_fingerprint(tag: str, meta: dict) -> str:
 
 
 def exported_entry(cache_dir: str, fingerprint: str, fn, avals,
-                   tag: Optional[str] = None, meta: Optional[dict] = None):
+                   tag: Optional[str] = None, meta: Optional[dict] = None,
+                   donate_argnums=()):
     """Generic disk-backed AOT entry: the Executor._aot_entry recipe
     (load -> deserialize -> aval check -> jit(exported.call); on miss
     export, round-trip the bytes, store) for any jit-able `fn` called
     as `fn(*avals)`. Returns the callable, or None when this function
     cannot be disk-cached (unexportable lowering, IO trouble) — the
-    caller falls back to plain jax.jit(fn).
+    caller falls back to plain jax.jit(fn). `donate_argnums` is the
+    jitted entry's: the exported module itself aliases nothing, the
+    entry around it donates (as Executor._aot_entry does its state).
 
     With `tag`, the entry is routed through the XLA program accounting
     registry (core/program_accounting.py): compiled at once from the
@@ -472,7 +475,7 @@ def exported_entry(cache_dir: str, fingerprint: str, fn, avals,
     from . import program_accounting
     entry_fn.__name__ = "%s_%s" % (
         program_accounting.safe_tag(tag or "exported"), fingerprint[:12])
-    entry = jax.jit(entry_fn)
+    entry = jax.jit(entry_fn, donate_argnums=donate_argnums)
     if tag is not None:
         entry = program_accounting.accounted(
             entry, avals, tag=program_accounting.safe_tag(tag),

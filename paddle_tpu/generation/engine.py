@@ -350,22 +350,10 @@ class GenerationEngine:
         # fixed attention lane count shared by prefill and decode —
         # the bitwise-parity requirement (model.forward_full docstring)
         self.attn_lanes = self.max_blocks_per_seq * bs
-        shape = (cfg.layers, nb, bs, cfg.heads, cfg.head_dim)
-        if self.kv_dtype == "fp32":
-            self.k_pools = jnp.zeros(shape, jnp.float32)
-            self.v_pools = jnp.zeros(shape, jnp.float32)
-            self.k_scales = self.v_scales = None
-        else:
-            # quantized pool + per-token-per-head fp32 absmax scale
-            # pool (quant.quantize_kv_rows). Scales init to ONE so a
-            # trash-block / never-written row dequantizes its zero
-            # payload to exact 0.0, same as the fp32 pools
-            dt = _quant.storage_dtype(self.kv_dtype)
-            self.k_pools = jnp.zeros(shape, dt)
-            self.v_pools = jnp.zeros(shape, dt)
-            sshape = (cfg.layers, nb, bs, cfg.heads)
-            self.k_scales = jnp.ones(sshape, jnp.float32)
-            self.v_scales = jnp.ones(sshape, jnp.float32)
+        # device pools: made below by _restore_pools, once the draft
+        # model's config is known (it makes whichever are missing)
+        self.k_pools = self.v_pools = None
+        self.k_scales = self.v_scales = None
         # cross-request prefix cache (chunked mode only: the chunk is
         # the hash unit)
         pc_on = bool(prefix_cache if prefix_cache is not None
@@ -393,13 +381,10 @@ class GenerationEngine:
                     "cover every verified position)"
                     % (draft_cfg.max_seq_len, cfg.max_seq_len))
             self.draft_params = jax.tree.map(jnp.asarray, draft_params)
-            dshape = (draft_cfg.layers, nb, bs, draft_cfg.heads,
-                      draft_cfg.head_dim)
-            self.dk_pools = jnp.zeros(dshape, jnp.float32)
-            self.dv_pools = jnp.zeros(dshape, jnp.float32)
         elif self.spec_tokens and self.draft_kind != "ngram":
             raise ValueError("unknown draft kind %r (ngram|model)"
                              % self.draft_kind)
+        self._restore_pools()
         # compiled-step registry: dict miss == an engine compilation
         # (STAT_generation_compile — the zero-steady-state-recompile
         # pin counts THIS, plus the fixed shapes make jax's own cache
@@ -423,6 +408,55 @@ class GenerationEngine:
         self._warmed = False
         self._publish_quant_gauges()
         self._publish_autotune_gauges()
+
+    # --- the device pools ----------------------------------------------
+
+    def _pool_specs(self) -> Dict[str, tuple]:
+        """attribute -> (shape, dtype, fill) of every device pool this
+        engine holds. A pool is `[layers, N, block_size, heads *
+        head_dim]`: the heads' two axes are kept FLAT, because the TPU's
+        default layout of a `[..., heads, 64]` array makes the block
+        axis N the minor one, and then neither the step's scatter nor
+        the block-table gather can use the array as it lies (each
+        copied the whole pool, PERF.md PR 28). Flat, a block is one
+        contiguous `[block_size, hidden]` tile."""
+        cfg, nb, bs = self.cfg, self.kv.num_blocks, self.kv.block_size
+        shape = (cfg.layers, nb, bs, cfg.hidden)
+        if self.kv_dtype == "fp32":
+            specs = {"k_pools": (shape, jnp.float32, 0),
+                     "v_pools": (shape, jnp.float32, 0)}
+        else:
+            # quantized pool + per-token-per-head fp32 absmax scale
+            # pool (quant.quantize_kv_rows). Scales init to ONE so a
+            # trash-block / never-written row dequantizes its zero
+            # payload to exact 0.0, same as the fp32 pools
+            dt = _quant.storage_dtype(self.kv_dtype)
+            sshape = (cfg.layers, nb, bs, cfg.heads)
+            specs = {"k_pools": (shape, dt, 0),
+                     "v_pools": (shape, dt, 0),
+                     "k_scales": (sshape, jnp.float32, 1),
+                     "v_scales": (sshape, jnp.float32, 1)}
+        if self.draft_params is not None:
+            dshape = (self.draft_cfg.layers, nb, bs,
+                      self.draft_cfg.hidden)
+            specs["dk_pools"] = (dshape, jnp.float32, 0)
+            specs["dv_pools"] = (dshape, jnp.float32, 0)
+        return specs
+
+    def _restore_pools(self) -> List[str]:
+        """Make every pool that is missing or dead, as a new engine
+        holds it (zeros; scale pools ones). The pools are DONATED to
+        every program that writes them, so a fault raised while such a
+        call runs can leave the engine holding deleted arrays:
+        GenerationPool._reset_engine and the draft step's fault path
+        call this. Returns the names it made."""
+        made = []
+        for name, (shape, dtype, fill) in self._pool_specs().items():
+            cur = getattr(self, name)
+            if cur is None or cur.is_deleted():
+                setattr(self, name, jnp.full(shape, fill, dtype))
+                made.append(name)
+        return made
 
     # --- quantized serving (ISSUE 15) ----------------------------------
 
@@ -588,28 +622,21 @@ class GenerationEngine:
             # before a write would mutate a shared block. Scalar
             # src/dst keep it ONE executable for any block pair. A
             # quantized target pool clones its scale rows in the same
-            # executable (draft pools are always fp32).
-            if kind == "cow" and self.k_scales is not None:
-                def raw(kp, vp, ks, vs, src, dst):
-                    with jax.named_scope("kv_copy_on_write"):
-                        return (kp.at[:, dst].set(kp[:, src]),
-                                vp.at[:, dst].set(vp[:, src]),
-                                ks.at[:, dst].set(ks[:, src]),
-                                vs.at[:, dst].set(vs[:, src]))
-                avals = (_sds(self.k_pools), _sds(self.v_pools),
-                         _sds(self.k_scales), _sds(self.v_scales),
-                         jax.ShapeDtypeStruct((), jnp.int32),
-                         jax.ShapeDtypeStruct((), jnp.int32))
-            else:
-                def raw(kp, vp, src, dst):
-                    with jax.named_scope("kv_copy_on_write"):
-                        return (kp.at[:, dst].set(kp[:, src]),
-                                vp.at[:, dst].set(vp[:, src]))
-                kp0 = self.k_pools if kind == "cow" else self.dk_pools
-                vp0 = self.v_pools if kind == "cow" else self.dv_pools
-                avals = (_sds(kp0), _sds(vp0),
-                         jax.ShapeDtypeStruct((), jnp.int32),
-                         jax.ShapeDtypeStruct((), jnp.int32))
+            # executable (draft pools are always fp32). The pools are
+            # donated: the program writes one block (590 KB at GPT-2
+            # widths) into the arrays it was given and returns them.
+            pools = tuple(getattr(self, n)
+                          for n in self._program_pools(kind))
+            n_pools = len(pools)
+
+            def raw(*args):
+                src, dst = args[n_pools:]
+                with jax.named_scope("kv_copy_on_write"):
+                    return tuple(p.at[:, dst].set(p[:, src])
+                                 for p in args[:n_pools])
+            avals = tuple(_sds(p) for p in pools) + (
+                jax.ShapeDtypeStruct((), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32))
         elif kind == "draft_mixed":
             # the draft model's step over the SAME slot layout and the
             # same block tables, writing its own pools. Greedy argmax:
@@ -637,12 +664,34 @@ class GenerationEngine:
             raise ValueError(kind)
         return self._aot_or_jit(kind, bucket, raw, avals)
 
+    def _program_pools(self, kind: str) -> tuple:
+        """Names of the pools `kind`'s program takes, writes and
+        returns, in the order of its arguments and results."""
+        if kind == "prefill":
+            return ()                     # returns fresh K/V, no pools
+        if kind.startswith("draft"):
+            return ("dk_pools", "dv_pools")
+        if self.k_scales is not None and kind != "decode":
+            return ("k_pools", "v_pools", "k_scales", "v_scales")
+        return ("k_pools", "v_pools")
+
+    def _pool_argnums(self, kind: str) -> tuple:
+        """Positions of those pools among the program's arguments
+        (_build_fn's signatures: first in `cow` and `draft_cow`, after
+        the weights elsewhere): what it donates."""
+        first = 0 if kind.endswith("cow") else 1
+        return tuple(range(first, first + len(self._program_pools(kind))))
+
     def _aot_or_jit(self, kind: str, bucket: int, raw, avals):
         """Route the step through the persistent AOT program cache
         (PR 1) when a cache dir resolves; plain jit otherwise. Both
         paths register with the XLA program accounting registry
         (core/program_accounting.py) so /programz shows every prefill
-        bucket and the decode step with compile-time flops/bytes."""
+        bucket and the decode step with compile-time flops/bytes.
+        Every program that returns new pools DONATES the old ones, on
+        both paths, so the update happens in the arrays the engine
+        holds: after a call the pool arrays passed in are dead and the
+        caller keeps the ones returned."""
         tag = ("generation_prefill_b%d" % bucket if kind == "prefill"
                else "generation_%s" % kind)
         base = (self.draft_cfg.meta() if kind.startswith("draft")
@@ -658,8 +707,10 @@ class GenerationEngine:
         # trace the tuned process exported. v=3 (ISSUE 15) added
         # qm/kvq so an fp32 cached program can never serve a quantized
         # checkpoint; samp rides along because two engines can share
-        # every other dimension yet differ in spec_tokens.
-        meta = dict(base, kind=kind, bucket=bucket, v=4,
+        # every other dimension yet differ in spec_tokens. v=5: the
+        # pools are donated and hold the heads' axes flat, so no entry
+        # stored before that is served.
+        meta = dict(base, kind=kind, bucket=bucket, v=5,
                     blocks=self.kv.num_blocks,
                     block_size=self.kv.block_size,
                     width=self.decode_width,
@@ -675,16 +726,19 @@ class GenerationEngine:
         # the device trace's `XLA Modules` line shows the step by name
         # (`jit_generation_mixed...`), with the cache as without
         raw.__name__ = tag
+        donate = self._pool_argnums(kind)
         cache_dir = program_cache.resolve_dir(self._program_cache_dir)
         if cache_dir is not None:
             fp = program_cache.fn_fingerprint("generation_step", meta)
             fn = program_cache.exported_entry(cache_dir, fp, raw, avals,
-                                              tag=tag, meta=meta)
+                                              tag=tag, meta=meta,
+                                              donate_argnums=donate)
             if fn is not None:
                 return fn
         from ..core import program_accounting
         return program_accounting.accounted(
-            jax.jit(raw), avals, tag=program_accounting.safe_tag(tag),
+            jax.jit(raw, donate_argnums=donate), avals,
+            tag=program_accounting.safe_tag(tag),
             key=program_accounting.key_token(sorted(meta.items())),
             meta=meta)
 
@@ -710,13 +764,12 @@ class GenerationEngine:
                 # shared block happens in steady state, and the
                 # zero-steady-state-recompile pin counts it
                 t0 = time.perf_counter()
-                self._warm_cow("cow", self.k_pools, self.v_pools)
+                self._warm_cow("cow")
                 report["cow"] = round(time.perf_counter() - t0, 4)
             if self.draft_params is not None:
                 t0 = time.perf_counter()
                 self._warm_draft()
-                self._warm_cow("draft_cow", self.dk_pools,
-                               self.dv_pools)
+                self._warm_cow("draft_cow")
                 report["draft"] = round(time.perf_counter() - t0, 4)
             self._warmed = True
             return report
@@ -740,55 +793,62 @@ class GenerationEngine:
         bs = self.kv.block_size
         blk = np.zeros(bucket, np.int32)  # TRASH_BLOCK
         off = (np.arange(bucket) % bs).astype(np.int32)
-        self.k_pools = self.k_pools.at[:, blk, off].set(kc[:, 0])
-        self.v_pools = self.v_pools.at[:, blk, off].set(vc[:, 0])
+        rows = (self.cfg.layers, bucket, self.cfg.hidden)
+        self.k_pools = self.k_pools.at[:, blk, off].set(
+            kc[:, 0].reshape(rows))
+        self.v_pools = self.v_pools.at[:, blk, off].set(
+            vc[:, 0].reshape(rows))
 
     def _warm_decode(self) -> None:
-        fn = self._get_fn("decode")
         w = self.decode_width
         z = jnp.zeros((w,), jnp.int32)
-        fn(self.params, self.k_pools, self.v_pools,
-           jnp.zeros((w, self.max_blocks_per_seq), jnp.int32), z, z,
-           jnp.zeros((w,), jnp.float32), z, jnp.ones((w,), jnp.float32),
-           z, z)
+        # every lane parked on the trash block
+        self._run("decode",
+                  jnp.zeros((w, self.max_blocks_per_seq), jnp.int32), z, z,
+                  jnp.zeros((w,), jnp.float32), z,
+                  jnp.ones((w,), jnp.float32), z, z)
 
     def _warm_mixed(self) -> None:
-        fn = self._get_fn("mixed")
         t, sw = self.token_budget, self.sample_width
         zt = jnp.zeros((t,), jnp.int32)
         zs = jnp.zeros((sw,), jnp.int32)
-        rest = (jnp.zeros((t, self.max_blocks_per_seq), jnp.int32),
-                zt, zt, zs, jnp.zeros((sw,), jnp.float32), zs,
-                jnp.ones((sw,), jnp.float32), zs, zs)
-        if self.k_scales is not None:
-            fn(self.params, self.k_pools, self.v_pools, self.k_scales,
-               self.v_scales, *rest)
-        else:
-            fn(self.params, self.k_pools, self.v_pools, *rest)
+        # every slot writes the trash block
+        self._run("mixed",
+                  jnp.zeros((t, self.max_blocks_per_seq), jnp.int32),
+                  zt, zt, zs, jnp.zeros((sw,), jnp.float32), zs,
+                  jnp.ones((sw,), jnp.float32), zs, zs)
 
-    def _warm_cow(self, kind: str, kp, vp) -> None:
+    def _warm_cow(self, kind: str) -> None:
         # trash-block self-copy: compiles the clone, mutates nothing
         # anyone reads
-        fn = self._get_fn(kind)
-        z = jnp.asarray(0, jnp.int32)
-        if kind == "cow" and self.k_scales is not None:
-            (self.k_pools, self.v_pools, self.k_scales,
-             self.v_scales) = fn(kp, vp, self.k_scales, self.v_scales,
-                                 z, z)
-            return
-        out = fn(kp, vp, z, z)
-        if kind == "cow":
-            self.k_pools, self.v_pools = out
+        z = jnp.asarray(TRASH_BLOCK, jnp.int32)
+        self._run(kind, z, z)
+
+    def _run(self, kind: str, *rest):
+        """Run `kind`'s compiled program on the pools it writes and
+        KEEP the pools it returns: the arrays passed in are donated
+        and dead after the call. `mixed`, `decode` and `draft_mixed`
+        take (weights, *pools, *rest) and return (result, *pools): the
+        result is handed back; `cow` and `draft_cow` take (*pools,
+        *rest) and return the pools alone."""
+        names = self._program_pools(kind)
+        args = tuple(getattr(self, n) for n in names) + rest
+        if kind.endswith("cow"):
+            out = (None,) + tuple(self._get_fn(kind)(*args))
         else:
-            self.dk_pools, self.dv_pools = out
+            out = self._get_fn(kind)(
+                self.draft_params if kind.startswith("draft")
+                else self.params, *args)
+        for n, a in zip(names, out[1:]):
+            setattr(self, n, a)
+        return out[0]
 
     def _warm_draft(self) -> None:
-        fn = self._get_fn("draft_mixed")
         t = self.token_budget
         zt = jnp.zeros((t,), jnp.int32)
-        _, self.dk_pools, self.dv_pools = fn(
-            self.draft_params, self.dk_pools, self.dv_pools,
-            jnp.zeros((t, self.max_blocks_per_seq), jnp.int32), zt, zt)
+        self._run("draft_mixed",
+                  jnp.zeros((t, self.max_blocks_per_seq), jnp.int32),
+                  zt, zt)
 
     # --- admission -----------------------------------------------------
 
@@ -1021,10 +1081,11 @@ class GenerationEngine:
             tbl = np.asarray(table, np.int32)
             blk = tbl[np.minimum(pos // bs, len(tbl) - 1)]
             off = (pos % bs).astype(np.int32)
+            rows = (self.cfg.layers, bucket, self.cfg.hidden)
             self.k_pools = self.k_pools.at[:, blk, off].set(
-                kc[:, 0, :bucket])
+                kc[:, 0, :bucket].reshape(rows))
             self.v_pools = self.v_pools.at[:, blk, off].set(
-                vc[:, 0, :bucket])
+                vc[:, 0, :bucket].reshape(rows))
         timer_observe("TIMER_generation_prefill_us",
                       (time.perf_counter() - t0) * 1e6)
         stat_add("STAT_generation_prefills")
@@ -1217,20 +1278,12 @@ class GenerationEngine:
             if _tm.enabled() else None
         with _tm.trace_scope(tids):
             with _tm.span("pt/engine/dispatch", track="generation"):
-                fn = self._get_fn("mixed")
-                rest = (jnp.asarray(tables), jnp.asarray(positions),
-                        jnp.asarray(tokens), jnp.asarray(sample_slots),
-                        jnp.asarray(temps), jnp.asarray(tks),
-                        jnp.asarray(tps), jnp.asarray(seeds),
-                        jnp.asarray(steps))
-                if self.k_scales is not None:
-                    (nxt, self.k_pools, self.v_pools, self.k_scales,
-                     self.v_scales) = fn(self.params, self.k_pools,
-                                         self.v_pools, self.k_scales,
-                                         self.v_scales, *rest)
-                else:
-                    nxt, self.k_pools, self.v_pools = fn(
-                        self.params, self.k_pools, self.v_pools, *rest)
+                nxt = self._run(
+                    "mixed", jnp.asarray(tables), jnp.asarray(positions),
+                    jnp.asarray(tokens), jnp.asarray(sample_slots),
+                    jnp.asarray(temps), jnp.asarray(tks),
+                    jnp.asarray(tps), jnp.asarray(seeds),
+                    jnp.asarray(steps))
             with _tm.span("pt/engine/fetch", track="generation"):
                 nxt = np.asarray(nxt)
         dt_us = (time.perf_counter() - t0) * 1e6
@@ -1359,20 +1412,11 @@ class GenerationEngine:
     def _copy_block(self, src: int, dst: int) -> None:
         """Clone one pool block's rows (all layers) src -> dst — the
         device half of copy-on-write."""
-        fn = self._get_fn("cow")
         s = jnp.asarray(src, jnp.int32)
         d = jnp.asarray(dst, jnp.int32)
-        if self.k_scales is not None:
-            (self.k_pools, self.v_pools, self.k_scales,
-             self.v_scales) = fn(self.k_pools, self.v_pools,
-                                 self.k_scales, self.v_scales, s, d)
-        else:
-            self.k_pools, self.v_pools = fn(self.k_pools,
-                                            self.v_pools, s, d)
+        self._run("cow", s, d)
         if self.draft_params is not None:
-            dfn = self._get_fn("draft_cow")
-            self.dk_pools, self.dv_pools = dfn(
-                self.dk_pools, self.dv_pools, s, d)
+            self._run("draft_cow", s, d)
 
     def _propose(self, decode_lanes: List[int],
                  s_cap: Dict[int, int],
@@ -1402,6 +1446,9 @@ class GenerationEngine:
             return out
         except Exception:
             stat_add("STAT_generation_draft_faults")
+            # a fault inside the draft step's donated call leaves dead
+            # draft pools; cold ones only cost acceptance
+            self._restore_pools()
             return {}
 
     def _propose_model(self, lanes: List[int], s_cap: Dict[int, int],
@@ -1417,7 +1464,6 @@ class GenerationEngine:
         cached region — acceptance suffers, correctness doesn't; the
         ngram drafter (default) has no such blind spot."""
         t, m = self.token_budget, self.max_blocks_per_seq
-        fn = self._get_fn("draft_mixed")
         max_s = max((s_cap[ln] for ln in lanes), default=0)
         feeds = {ln: self._lane_seq[ln].generated[-1] for ln in lanes}
         out: Dict[int, List[int]] = {ln: [] for ln in lanes}
@@ -1445,11 +1491,9 @@ class GenerationEngine:
                         positions[slot] = start + i
                         tokens[slot] = seq.req.prompt[start + i]
                         slot += 1
-            nxt, self.dk_pools, self.dv_pools = fn(
-                self.draft_params, self.dk_pools, self.dv_pools,
-                jnp.asarray(tables), jnp.asarray(positions),
-                jnp.asarray(tokens))
-            nxt = np.asarray(nxt)
+            nxt = np.asarray(self._run(
+                "draft_mixed", jnp.asarray(tables),
+                jnp.asarray(positions), jnp.asarray(tokens)))
             for ln, sl in slot_of.items():
                 if j < s_cap[ln]:
                     tok = int(nxt[sl])
@@ -1517,14 +1561,12 @@ class GenerationEngine:
             if _tm.enabled() else None
         with _tm.trace_scope(tids):
             with _tm.span("pt/engine/dispatch", track="generation"):
-                fn = self._get_fn("decode")
-                nxt, self.k_pools, self.v_pools = fn(
-                    self.params, self.k_pools, self.v_pools,
-                    jnp.asarray(self._tables), jnp.asarray(self._ctx),
-                    jnp.asarray(tokens), jnp.asarray(self._temps),
-                    jnp.asarray(self._top_ks),
-                    jnp.asarray(self._top_ps),
-                    jnp.asarray(self._seeds), jnp.asarray(steps))
+                nxt = self._run(
+                    "decode", jnp.asarray(self._tables),
+                    jnp.asarray(self._ctx), jnp.asarray(tokens),
+                    jnp.asarray(self._temps), jnp.asarray(self._top_ks),
+                    jnp.asarray(self._top_ps), jnp.asarray(self._seeds),
+                    jnp.asarray(steps))
             with _tm.span("pt/engine/fetch", track="generation"):
                 nxt = np.asarray(nxt)
         timer_observe("TIMER_generation_decode_step_us",

@@ -3,7 +3,9 @@
 The decode-side analog of the reference's contiguous per-request KV
 buffers: instead of one `[S_max]` allocation per sequence (worst-case
 memory, realloc on growth, a fresh XLA shape per length), every layer
-owns ONE preallocated pool `[num_blocks, block_size, heads, head_dim]`
+owns ONE preallocated pool of `num_blocks` blocks of `block_size` token
+rows (the engine stacks the layers' pools into one array,
+`[layers, num_blocks, block_size, heads * head_dim]`, updated in place)
 and a sequence holds an ordered list of pool block indices (its block
 table). Growth is "append one index", completion is "return the
 indices" — the device arrays never change shape, so every decode step

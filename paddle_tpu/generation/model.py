@@ -183,11 +183,24 @@ def forward_paged(cfg: DecoderConfig, params: dict, k_pools, v_pools,
                   block_tables, ctx_lens, tokens,
                   k_scale_pools=None, v_scale_pools=None):
     """One-token-per-slot paged step: tokens `[B]` (each slot's token
-    at position ctx_lens), pools `[layers, N, bs, H, D]`, block_tables
+    at position ctx_lens), pools `[layers, N, bs, H * D]` (the heads'
+    axes flat: a token row is one contiguous line; the `reference`
+    form reads a `[layers, N, bs, H, D]` pool the same), block_tables
     `[B, M]`, ctx_lens `[B]` int32 (tokens already in the cache).
     Writes each layer's new K/V into the pool at the flat slot
     `table[ctx // bs] * bs + ctx % bs`, attends over ctx+1 positions,
     returns (logits `[B, vocab]`, k_pools', v_pools').
+
+    The pools are STATE UPDATED IN PLACE: layer i's rows go into the
+    stacked array it was given with one scatter (`.at[i, blk, off]`),
+    the updated array is threaded to the next layer, and attention
+    reads layer i of it through `paged_attention(..., layer=i)` — no
+    layer's pool is ever sliced out, and nothing is stacked at the
+    end. A caller that jits this with the pools DONATED (the engine's
+    `mixed`, `decode` and `draft_mixed` programs) gets the arrays it
+    passed back, the step's rows written; a caller that does not
+    donate pays one copy of each pool at the program's edge, and
+    computes the same values.
 
     This is the engine's MIXED step, not just decode.  A batch row is a
     *slot*: either a decode lane's next token or one prompt token of a
@@ -226,7 +239,7 @@ def forward_paged(cfg: DecoderConfig, params: dict, k_pools, v_pools,
             axis=1)[:, 0]                                  # [B]
         off = ctx_lens % bs
     quant_kv = k_scale_pools is not None
-    new_k, new_v, new_ks, new_vs = [], [], [], []
+    row = (b,) + k_pools.shape[3:]          # a slot's K or V, as stored
     for i in range(cfg.layers):
         with scope("qkv"):
             xn = _ln(x, params["l%d_ln1_g" % i], params["l%d_ln1_b" % i])
@@ -235,20 +248,15 @@ def forward_paged(cfg: DecoderConfig, params: dict, k_pools, v_pools,
             if quant_kv:
                 k, ksc = _quant.quantize_kv_rows(k, k_pools.dtype)
                 v, vsc = _quant.quantize_kv_rows(v, v_pools.dtype)
-                ksp = k_scale_pools[i].at[blk, off].set(ksc)
-                vsp = v_scale_pools[i].at[blk, off].set(vsc)
-                new_ks.append(ksp)
-                new_vs.append(vsp)
-            else:
-                ksp = vsp = None
-            kp = k_pools[i].at[blk, off].set(k)            # scatter new
-            vp = v_pools[i].at[blk, off].set(v)
-            new_k.append(kp)
-            new_v.append(vp)
+                k_scale_pools = k_scale_pools.at[i, blk, off].set(ksc)
+                v_scale_pools = v_scale_pools.at[i, blk, off].set(vsc)
+            k_pools = k_pools.at[i, blk, off].set(k.reshape(row))
+            v_pools = v_pools.at[i, blk, off].set(v.reshape(row))
         # the kernel opens `paged_attention` itself, in either form
-        o = paged_attention(q, kp, vp, block_tables, ctx_lens + 1,
-                            sm_scale=sm_scale,
-                            k_scales=ksp, v_scales=vsp)    # [B,H,D]
+        o = paged_attention(q, k_pools, v_pools, block_tables,
+                            ctx_lens + 1, sm_scale=sm_scale,
+                            k_scales=k_scale_pools,
+                            v_scales=v_scale_pools, layer=i)  # [B,H,D]
         with scope("attn_out"):
             x = x + _mm(params, "l%d_wo" % i, o.reshape(b, cfg.hidden))
         with scope("mlp"):
@@ -257,9 +265,6 @@ def forward_paged(cfg: DecoderConfig, params: dict, k_pools, v_pools,
     with scope("unembed"):
         x = _ln(x, params["ln_f_g"], params["ln_f_b"])
         logits = _mm(params, "unembed", x)                 # [B, V]
-    # putting the layers' pools back into one array is the update's too
-    with scope("kv_write"):
-        pools = (jnp.stack(new_k), jnp.stack(new_v))
-        if quant_kv:
-            pools += (jnp.stack(new_ks), jnp.stack(new_vs))
-    return (logits,) + pools
+    if quant_kv:
+        return logits, k_pools, v_pools, k_scale_pools, v_scale_pools
+    return logits, k_pools, v_pools

@@ -364,8 +364,12 @@ class GenerationPool:
     def _reset_engine(self) -> None:
         """After a batch-level fault: rebuild the engine's sequence
         state (fresh KV ledger + lanes) reusing its compiled steps and
-        device pools — in-flight sequences are gone, their futures
-        already hold the error. EVERY generation occupancy gauge is
+        the device pools that are still alive — in-flight sequences are
+        gone, their futures already hold the error. The pools are
+        donated to every program that writes them, so a fault raised
+        while such a call ran may have left the engine holding deleted
+        arrays: those are made anew, zeros, as a new engine holds them
+        (engine._restore_pools). EVERY generation occupancy gauge is
         retracted here, not lazily at the next allocation: a monitoring
         scrape between the fault and the next request must see the
         true (empty) state, not the pre-fault occupancy (pinned by
@@ -380,6 +384,7 @@ class GenerationPool:
             # prefix gauges at zero.
             eng.prefix_cache = type(eng.prefix_cache)(
                 eng.kv, eng.prefill_chunk)
+        eng._restore_pools()
         eng._lane_seq = [None] * eng.decode_width
         eng._tables[:] = 0
         eng._ctx[:] = 0

@@ -29,7 +29,10 @@ Two execution paths, selected by FLAGS_paged_attention_kernel:
 Layouts: q `[B, H, D]` (one new token per sequence), pools
 `[N, block_size, H, D]`, block_tables `[B, max_blocks]` int32,
 ctx_lens `[B]` int32 (number of VISIBLE keys, i.e. the new token's
-position + 1). Returns `[B, H, D]`.
+position + 1). Returns `[B, H, D]`. The engine passes its whole
+stacked pools instead, `[layers, N, block_size, H * D]` with a static
+`layer=`: every entry point reads that layer's blocks where they lie,
+with no slice of the array the step updates in place.
 
 RAGGED entry (PR 10, chunked prefill): `ragged_paged_attention` takes
 q `[B, Cq, H, D]` where row b carries `q_lens[b]` real queries — 1 for
@@ -130,7 +133,8 @@ def attend_reference(q, k, v, mask, sm_scale):
 def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
                                      q_lens, ctx_lens,
                                      sm_scale: Optional[float] = None,
-                                     k_scales=None, v_scales=None):
+                                     k_scales=None, v_scales=None,
+                                     layer: Optional[int] = None):
     """Ragged gather-from-block-table attention in plain XLA.
 
     q `[B, Cq, H, D]`: row b holds `q_lens[b]` real queries at absolute
@@ -154,12 +158,37 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
     dequantizes (stored * scale / GRID) right at the softmax input, the
     XLA-fused analog of the in-loop dequant in the Pallas kernel below.
     `None` scales take the EXACT pre-quant expressions, keeping the
-    fp32 path bitwise-identical."""
+    fp32 path bitwise-identical.
+
+    STACKED POOLS: with `layer` (a static int) the pools are the
+    engine's whole arrays, `[layers, N, bs, H * D]` with the heads'
+    two axes FLAT (scale pools `[layers, N, bs, H]`), and the gather
+    indexes `(layer, block)` straight into them, so the caller never
+    slices a layer's pool out of the array it updates in place
+    (generation/model.py:forward_paged). The gathered `[B, L, H * D]`
+    view is then read one head at a time, as `D`-wide slices of its
+    minor axis, each through attend_reference with one head: the same
+    products and the same reductions, head for head, and no
+    `[.., H * D] -> [.., H, D]` reshape of the view, which on the TPU
+    is a relayout of the whole gathered array (twice over, K and V of
+    every layer: 28 of 39 ms a step, PERF.md PR 28)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     b, cq, h, d = q.shape
-    n, bs, _, _ = k_pool.shape
+    bs = k_pool.shape[1 if layer is None else 2]
     m = block_tables.shape[1]
+    pos = jnp.arange(m * bs, dtype=jnp.int32)
+    qi = jnp.arange(cq, dtype=jnp.int32)
+    # [B, Cq, L]: pool position visible to query j of row b
+    visible = pos[None, None, :] <= \
+        (ctx_lens[:, None] + qi[None, :])[:, :, None]
+    live = (qi[None, :] < q_lens[:, None])[:, :, None]
+    mask = (visible & live)[:, None, :, :]            # [B, 1, Cq, L]
+    qt = jnp.transpose(q, (0, 2, 1, 3))               # [B, H, Cq, D]
+    if layer is not None:
+        out = _attend_stacked(qt, k_pool, v_pool, k_scales, v_scales,
+                              layer, block_tables, mask, sm_scale)
+        return jnp.transpose(out, (0, 2, 1, 3))
     if k_scales is None:
         # [B, M, bs, H, D] -> [B, H, M*bs, D]
         k = jnp.transpose(k_pool[block_tables], (0, 3, 1, 2, 4)
@@ -174,21 +203,39 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
             * (v_scales[block_tables] * inv)[..., None]
         k = jnp.transpose(kg, (0, 3, 1, 2, 4)).reshape(b, h, m * bs, d)
         v = jnp.transpose(vg, (0, 3, 1, 2, 4)).reshape(b, h, m * bs, d)
-    pos = jnp.arange(m * bs, dtype=jnp.int32)
-    qi = jnp.arange(cq, dtype=jnp.int32)
-    # [B, Cq, L]: pool position visible to query j of row b
-    visible = pos[None, None, :] <= \
-        (ctx_lens[:, None] + qi[None, :])[:, :, None]
-    live = (qi[None, :] < q_lens[:, None])[:, :, None]
-    mask = (visible & live)[:, None, :, :]            # [B, 1, Cq, L]
-    out = attend_reference(jnp.transpose(q, (0, 2, 1, 3)), k, v, mask,
-                           sm_scale)
+    out = attend_reference(qt, k, v, mask, sm_scale)
     return jnp.transpose(out, (0, 2, 1, 3))
+
+
+def _attend_stacked(qt, k_pool, v_pool, k_scales, v_scales, layer,
+                    block_tables, mask, sm_scale):
+    """The reference attention over layer `layer` of the engine's
+    stacked flat pools, one head at a time (see
+    ragged_paged_attention_reference, STACKED POOLS): `[B, H, Cq, D]`."""
+    b, h, _, d = qt.shape
+
+    def view(pool):                                   # [B, L, width]
+        return pool[layer, block_tables].reshape(b, mask.shape[-1], -1)
+    kf, vf = view(k_pool), view(v_pool)
+    if k_scales is not None:
+        inv = _inv_grid(k_pool.dtype)
+        ksc, vsc = view(k_scales) * inv, view(v_scales) * inv
+    outs = []
+    for i in range(h):
+        k = kf[:, None, :, i * d:(i + 1) * d]         # [B, 1, L, D]
+        v = vf[:, None, :, i * d:(i + 1) * d]
+        if k_scales is not None:
+            k = k.astype(jnp.float32) * ksc[:, None, :, i, None]
+            v = v.astype(jnp.float32) * vsc[:, None, :, i, None]
+        outs.append(attend_reference(qt[:, i:i + 1], k, v, mask,
+                                     sm_scale))
+    return jnp.concatenate(outs, axis=1)
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
                               sm_scale: Optional[float] = None,
-                              k_scales=None, v_scales=None):
+                              k_scales=None, v_scales=None,
+                              layer: Optional[int] = None):
     """Single-token decode attention: the Cq == 1 specialization of the
     ragged path. ctx_lens here counts VISIBLE keys (position + 1), so
     the ragged call gets `ctx_lens - 1` keys-before-the-query and a
@@ -199,13 +246,28 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
     out = ragged_paged_attention_reference(
         q[:, None], k_pool, v_pool, block_tables,
         jnp.ones_like(ctx), ctx - 1, sm_scale,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, layer=layer)
     return out[:, 0]
 
 
 # ---------------------------------------------------------------------------
 # Pallas kernel: one pool block in VMEM per grid step
 # ---------------------------------------------------------------------------
+
+def _tile(ref, heads):
+    """The resident pool block as float32 `[bs, H, D]`. A block of the
+    engine's stacked pools arrives flat, `[1, bs, H * D]`
+    (ragged_paged_attention_pallas, `layer`), and is split into heads
+    here, in VMEM, by lane slices (Mosaic has no such reshape, and
+    none of an int8 tile: hence the cast first); a `[1, bs, H, D]`
+    block is taken as it is."""
+    t = ref[0].astype(jnp.float32)
+    if t.ndim == 3:
+        return t
+    d = t.shape[1] // heads
+    return jnp.stack([t[:, i * d:(i + 1) * d] for i in range(heads)],
+                     axis=1)
+
 
 def _ragged_kernel(tables_ref, qlens_ref, lens_ref, q_ref, k_ref, v_ref,
                    o_ref, acc_ref, m_ref, l_ref, *, block_size, sm_scale,
@@ -235,8 +297,8 @@ def _ragged_kernel(tables_ref, qlens_ref, lens_ref, q_ref, k_ref, v_ref,
     @pl.when(mi * block_size < ctx + qlen)
     def _body():
         q = q_ref[0].astype(jnp.float32) * sm_scale      # [Cq, H, D]
-        k = k_ref[0].astype(jnp.float32)                 # [bs, H, D]
-        v = v_ref[0].astype(jnp.float32)
+        k = _tile(k_ref, q.shape[1])                     # [bs, H, D]
+        v = _tile(v_ref, q.shape[1])
         # batch over heads, contract D: [H, Cq, bs]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((1,), (1,))),
@@ -291,9 +353,9 @@ def _ragged_kernel_quant(tables_ref, qlens_ref, lens_ref, q_ref, k_ref,
     def _body():
         q = q_ref[0].astype(jnp.float32) * sm_scale      # [Cq, H, D]
         # in-loop dequant: [bs, H, D] stored * [bs, H, 1] scale/GRID
-        k = k_ref[0].astype(jnp.float32) \
+        k = _tile(k_ref, q.shape[1]) \
             * (ks_ref[0].astype(jnp.float32) * inv_grid)[:, :, None]
-        v = v_ref[0].astype(jnp.float32) \
+        v = _tile(v_ref, q.shape[1]) \
             * (vs_ref[0].astype(jnp.float32) * inv_grid)[:, :, None]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((1,), (1,))),
@@ -326,42 +388,49 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                   q_lens, ctx_lens,
                                   sm_scale: Optional[float] = None,
                                   interpret: Optional[bool] = None,
-                                  k_scales=None, v_scales=None):
+                                  k_scales=None, v_scales=None,
+                                  layer: Optional[int] = None):
     """Blocked ragged kernel: same grid over (sequence, pool block) as
     the decode kernel, but each VMEM tile scores the whole Cq-wide
     chunk against one resident block, so prefill chunks and decode
     singles share one executable shape. Quantized pools (k_scales /
     v_scales given) route to the _ragged_kernel_quant twin — the fp32
-    kernel is untouched so the quant-off executable stays identical."""
+    kernel is untouched so the quant-off executable stays identical.
+    With `layer` the pools are the engine's stacked arrays, `[layers,
+    N, bs, H * D]` with the heads flat (scales `[layers, N, bs, H]`):
+    the index maps put `layer` in front of the table's block, the
+    layer axis is squeezed out of the tile, and the kernel bodies
+    split the flat `[1, bs, H * D]` block into heads (_tile)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = _use_interpret()
     b, cq, h, d = q.shape
-    _, bs, _, _ = k_pool.shape
+    bs = k_pool.shape[1 if layer is None else 2]
     m = block_tables.shape[1]
+
+    def pool_spec(*tail):
+        """One table-picked block of a pool: `(1, bs, *tail)` tiles."""
+        zeros = (0,) * (1 + len(tail))
+        if layer is None:
+            return pl.BlockSpec(
+                (1, bs) + tail,
+                lambda bi, mi, tbl, qls, lens: (tbl[bi, mi],) + zeros)
+        return pl.BlockSpec(
+            (None, 1, bs) + tail,
+            lambda bi, mi, tbl, qls, lens: (layer, tbl[bi, mi]) + zeros)
+    kv_spec = pool_spec(h, d) if layer is None else pool_spec(h * d)
     in_specs = [
         pl.BlockSpec((1, cq, h, d),
                      lambda bi, mi, tbl, qls, lens: (bi, 0, 0, 0)),
-        pl.BlockSpec(
-            (1, bs, h, d),
-            lambda bi, mi, tbl, qls, lens: (tbl[bi, mi], 0, 0, 0)),
-        pl.BlockSpec(
-            (1, bs, h, d),
-            lambda bi, mi, tbl, qls, lens: (tbl[bi, mi], 0, 0, 0)),
+        kv_spec,
+        kv_spec,
     ]
     operands = [q, k_pool, v_pool]
     if k_scales is not None:
         # scale rows ride the SAME block-table index map as their
         # payload tile, one [bs, H] row set per resident block
-        in_specs += [
-            pl.BlockSpec(
-                (1, bs, h),
-                lambda bi, mi, tbl, qls, lens: (tbl[bi, mi], 0, 0)),
-            pl.BlockSpec(
-                (1, bs, h),
-                lambda bi, mi, tbl, qls, lens: (tbl[bi, mi], 0, 0)),
-        ]
+        in_specs += [pool_spec(h), pool_spec(h)]
         operands += [k_scales, v_scales]
         kern = functools.partial(
             _ragged_kernel_quant, block_size=bs, sm_scale=sm_scale,
@@ -395,7 +464,8 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
 def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens,
                            sm_scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None,
+                           layer: Optional[int] = None):
     """Single-token decode kernel: Cq == 1 delegation to the ragged
     kernel (same visible-count ctx_lens convention as the reference
     specialization above)."""
@@ -403,7 +473,7 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens,
     out = ragged_paged_attention_pallas(
         q[:, None], k_pool, v_pool, block_tables,
         jnp.ones_like(ctx), ctx - 1, sm_scale, interpret,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, layer=layer)
     return out[:, 0]
 
 
@@ -455,14 +525,18 @@ def resolved_form() -> str:
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                     sm_scale: Optional[float] = None,
-                    k_scales=None, v_scales=None):
+                    k_scales=None, v_scales=None,
+                    layer: Optional[int] = None):
     """Decode-step attention over the paged KV pool. Routed by
     FLAGS_paged_attention_kernel (a lowering flag: it is baked into
     every generation compile key), subject to the kernel_form override
     above: "reference" is the bitwise parity path; "pallas" runs the
     blocked kernel (interpret mode off-TPU). k_scales/v_scales
     (quantized pools, paddle_tpu/quant) flow to the dequant-fused
-    forms of both paths; None = the untouched fp32 path."""
+    forms of both paths; None = the untouched fp32 path. `layer`
+    (static) says the pools are the stacked `[layers, N, bs, ...]`
+    arrays and picks the layer to read, in both forms without a
+    slice."""
     mode = resolved_form()
     # ONE device-trace name for the gather and the attention over it,
     # in either form: a kernel change is read by the same metric
@@ -471,16 +545,17 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
             return paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                           ctx_lens, sm_scale,
                                           k_scales=k_scales,
-                                          v_scales=v_scales)
+                                          v_scales=v_scales, layer=layer)
         return paged_attention_reference(q, k_pool, v_pool, block_tables,
                                          ctx_lens, sm_scale,
                                          k_scales=k_scales,
-                                         v_scales=v_scales)
+                                         v_scales=v_scales, layer=layer)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
                            ctx_lens, sm_scale: Optional[float] = None,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None,
+                           layer: Optional[int] = None):
     """Mixed prefill+decode attention over the paged KV pool: q
     `[B, Cq, H, D]` with per-row true query length (1 = decode, chunk
     width = prefill). Routed by the same FLAGS_paged_attention_kernel
@@ -491,7 +566,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
         if mode == "pallas":
             return ragged_paged_attention_pallas(
                 q, k_pool, v_pool, block_tables, q_lens, ctx_lens,
-                sm_scale, k_scales=k_scales, v_scales=v_scales)
+                sm_scale, k_scales=k_scales, v_scales=v_scales,
+                layer=layer)
         return ragged_paged_attention_reference(
             q, k_pool, v_pool, block_tables, q_lens, ctx_lens, sm_scale,
-            k_scales=k_scales, v_scales=v_scales)
+            k_scales=k_scales, v_scales=v_scales, layer=layer)

@@ -114,24 +114,31 @@ def test_layer_norm_compiles_for_v5e(one_chip):
                          ids=["chunk16", "mixed_step_slots"])
 @pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.int8],
                          ids=["fp32_pool", "int8_pool"])
+@pytest.mark.parametrize("layer", [None, 5],
+                         ids=["one_layers_pool", "stacked_flat_pools"])
 def test_ragged_paged_attention_compiles_for_v5e(one_chip, pool_dtype,
-                                                 q_shape):
+                                                 q_shape, layer):
+    """`stacked_flat_pools` is what the engine hands the kernel: its
+    whole `[layers, N, bs, H * D]` pools and the layer to read."""
     from paddle_tpu.kernels.paged_attention import \
         ragged_paged_attention_pallas
     b = q_shape[0]
-    pool = _sds((1024, 16, 12, 64), pool_dtype)
+    stacked = layer is not None
+    pool = _sds((12, 1024, 16, 768) if stacked else (1024, 16, 12, 64),
+                pool_dtype)
     avals = [_sds(q_shape, jnp.float32), pool, pool,
              _sds((b, 64), jnp.int32), _sds((b,), jnp.int32),
              _sds((b,), jnp.int32)]
     if pool_dtype == jnp.int8:
-        scales = _sds((1024, 16, 12), jnp.float32)
+        scales = _sds((12, 1024, 16, 12) if stacked else (1024, 16, 12),
+                      jnp.float32)
 
         def fn(q, kp, vp, tables, q_lens, ctx_lens, ks, vs):
             return ragged_paged_attention_pallas(
                 q, kp, vp, tables, q_lens, ctx_lens, interpret=False,
-                k_scales=ks, v_scales=vs)
+                k_scales=ks, v_scales=vs, layer=layer)
         avals += [scales, scales]
     else:
         fn = functools.partial(ragged_paged_attention_pallas,
-                               interpret=False)
+                               interpret=False, layer=layer)
     _compile(fn, one_chip, *avals)
