@@ -44,6 +44,12 @@ REAL = dict(
     static=dict(layers_n=12, H=768, FF=3072, HEADS=12, S=128, B=8),
     decoder=dict(vocab_size=50257, hidden=768, layers=12, heads=12,
                  max_seq_len=1024),  # GPT-2 small's published widths
+    # the looped family at the widths of the benchmark's `ouro_2_6b`, 4
+    # layers run 4 times; bfloat16 weights and KV pool
+    looped=dict(vocab_size=49152, hidden_size=2048, num_hidden_layers=4,
+                num_attention_heads=16, num_key_value_heads=16,
+                head_dim=128, intermediate_size=5632, total_ut_steps=4,
+                max_seq_len=1024),
     engine=dict(num_blocks=1024, decode_width=8),
     prompts=(64, 512), new_tokens=32,
     mesh_bert=dict(num_hidden_layers=2, hidden_dropout_prob=0.0,
@@ -57,6 +63,9 @@ TINY = dict(
     static=dict(layers_n=2, H=64, FF=128, HEADS=4, S=16, B=2),
     decoder=dict(vocab_size=128, hidden=64, layers=2, heads=4,
                  max_seq_len=64),
+    looped=dict(vocab_size=128, hidden_size=64, num_hidden_layers=2,
+                num_attention_heads=4, num_key_value_heads=4, head_dim=24,
+                intermediate_size=96, total_ut_steps=2, max_seq_len=64),
     engine=dict(num_blocks=64, decode_width=8),
     prompts=(4, 24), new_tokens=6,
     mesh_bert=dict(vocab_size=1000, hidden_size=128, num_hidden_layers=2,
@@ -318,16 +327,25 @@ def _explain_divergence(oracle_logits, max_len, prompt, ref, got, what):
           % (what, gap, LOGIT_TIE_TOL))
 
 
-def phase_generate(sizes, counter):
+def phase_generate(sizes, counter, family="gpt"):
+    """`family`: "gpt" (float32, generation/model.py) or "looped"
+    (bfloat16 weights and KV pool, generation/looped.py); the engine, the
+    pool, both kernel forms and the oracle are the same code."""
     import jax
+    import jax.numpy as jnp
     from paddle_tpu.generation import (DecoderConfig, GenerationEngine,
                                        GenerationPool, GenerationRequest,
-                                       NaiveGenerator, init_params)
-    from paddle_tpu.generation.model import forward_full
+                                       NaiveGenerator, init_params, looped)
     from paddle_tpu.monitor import stat_get
 
-    cfg = DecoderConfig(**sizes["decoder"])
-    params = init_params(cfg, seed=0)
+    engine_kw = dict(sizes["engine"])
+    if family == "gpt":
+        cfg = DecoderConfig(**sizes["decoder"])
+        params = init_params(cfg, seed=0)
+    else:
+        cfg = looped.LoopedDecoderConfig(**sizes["looped"])
+        params = looped.init_params(cfg, seed=0, dtype=jnp.bfloat16)
+        engine_kw["kv_dtype"] = "bf16"
     rng = np.random.default_rng(0)
     lo, hi = sizes["prompts"]
     new = sizes["new_tokens"]
@@ -335,8 +353,7 @@ def phase_generate(sizes, counter):
                for n in np.linspace(lo, hi, 8)]
 
     def serve(kernel):
-        engine = GenerationEngine(cfg, params, kernel=kernel,
-                                  **sizes["engine"])
+        engine = GenerationEngine(cfg, params, kernel=kernel, **engine_kw)
         t0 = time.perf_counter()
         engine.warmup()
         warm_s = time.perf_counter() - t0
@@ -369,8 +386,8 @@ def phase_generate(sizes, counter):
     oracle = naive.generate(GenerationRequest(
         prompt=prompts[0], max_new_tokens=new)).tokens
     # full-context logits of a padded prefix, for _explain_divergence
-    full = jax.jit(lambda p, toks, n: forward_full(
-        cfg, p, toks, n, attn_lanes=lanes)[0])
+    full = jax.jit(lambda p, toks, n: cfg.forward_full(
+        p, toks, n, attn_lanes=lanes)[0])
 
     def oracle_logits(toks, n):
         return full(naive.params, toks, n)
@@ -497,7 +514,9 @@ def main(argv=None):
     else:
         phases = [("train", lambda: phase_train(sizes, counter, on_chip)),
                   ("static", lambda: phase_static(sizes, counter)),
-                  ("generate", lambda: phase_generate(sizes, counter))]
+                  ("generate", lambda: phase_generate(sizes, counter)),
+                  ("generate_looped",
+                   lambda: phase_generate(sizes, counter, "looped"))]
     for name, run in phases:
         t1 = time.perf_counter()
         run()

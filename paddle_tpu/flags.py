@@ -253,7 +253,8 @@ _DEFS: Dict[str, Any] = {
     "FLAGS_quant_mode": "off",
     # quantized KV block pool (generation/engine.py): "auto" follows
     # FLAGS_quant_mode (int8 KV when quant is on, fp32 otherwise);
-    # "fp32" / "int8" / "fp8" pin the pool dtype. Quantized pools store
+    # "fp32" / "bf16" / "int8" / "fp8" pin the pool dtype ("bf16" is a
+    # plain narrower pool, no scales). Quantized pools store
     # per-token-per-head absmax scales alongside and dequantize inside
     # the online-softmax loop of kernels/paged_attention.py.
     "FLAGS_generation_kv_quant": "auto",

@@ -10,6 +10,9 @@ Three pillars on top of the serving stack:
   (kernels/paged_attention.py), greedy/top-k/top-p samplers with
   per-sequence PRNG. Fixed shapes end to end: steady state replays
   two compiled steps (prefill-at-bucket, decode) with zero recompiles.
+- model families: `DecoderConfig` (the GPT block, model.py) and
+  `LoopedDecoderConfig` (a stack run several times a token, looped.py)
+  reach the engine through the same seam on the config object.
 - continuous batching: `GenerationPool` admits requests into the
   in-flight decode batch every step (join at prefill, leave at
   EOS/max-len), `ServingQueueFull` backpressure, per-sequence error
@@ -18,6 +21,7 @@ Three pillars on top of the serving stack:
 from .engine import (GenerationEngine, GenerationRequest,
                      GenerationResult, NaiveGenerator)
 from .kv_cache import TRASH_BLOCK, BlockPoolExhausted, KVCacheManager
+from .looped import LoopedDecoderConfig
 from .model import DecoderConfig, forward_full, forward_paged, init_params
 from .sampling import SamplingParams, sample_tokens
 from .scheduler import GenerationPool
@@ -25,6 +29,7 @@ from .scheduler import GenerationPool
 __all__ = [
     "BlockPoolExhausted", "DecoderConfig", "GenerationEngine",
     "GenerationPool", "GenerationRequest", "GenerationResult",
-    "KVCacheManager", "NaiveGenerator", "SamplingParams", "TRASH_BLOCK",
+    "KVCacheManager", "LoopedDecoderConfig", "NaiveGenerator",
+    "SamplingParams", "TRASH_BLOCK",
     "forward_full", "forward_paged", "init_params", "sample_tokens",
 ]

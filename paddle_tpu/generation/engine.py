@@ -21,6 +21,18 @@ PR-5 two-phase scheme: bucketed whole-prompt prefill
 rung) followed by fixed-width fused decode. In chunked mode the ladder
 is a compat shim collapsed to [max_seq_len] — see MIGRATION.md.
 
+MODEL FAMILIES (PR 29). The engine reaches a model through its config
+object alone: `cfg.forward_full` / `cfg.forward_paged`, the cache's
+geometry (`cfg.kv_layers` cache layers of rows `cfg.kv_row`, read in
+one place, `_pool_specs`), `cfg.max_seq_len` (the context cap the
+block tables are sized by), `cfg.meta()` for the fingerprints. The GPT
+block (`model.DecoderConfig`) and the looped decoder
+(`looped.LoopedDecoderConfig`: a stack run several times a token, a
+cache layer for every pass) are served by the same programs below;
+nothing here names a family (docs/generation.md, "Model families").
+`kv_dtype="bf16"` keeps a plain bfloat16 pool; weights are served in
+the dtype they arrive in.
+
 Fixed shapes everywhere mean the steady state replays exactly the warm
 executables: STAT_generation_compile counts engine-level compilations
 (tests pin it at zero across a mixed-length continuous stream), and
@@ -108,7 +120,7 @@ from ..inference import bucket_for, bucket_or_exact, parse_bucket_ladder
 from ..monitor import gauge_set, stat_add, timer_observe
 from .kv_cache import (TRASH_BLOCK, BlockPoolExhausted, KVCacheManager,
                        PrefixCache)
-from .model import DecoderConfig, forward_full, forward_paged
+from .model import DecoderConfig
 from .sampling import SamplingParams, sample_tokens
 
 __all__ = ["GenerationEngine", "GenerationRequest", "GenerationResult",
@@ -147,7 +159,7 @@ class _Seq:
 
     __slots__ = ("req", "ctx", "generated", "lane", "admit_order",
                  "evictions", "t_last_token", "prefilled",
-                 "admit_failures", "pkeys", "published")
+                 "admit_failures", "pkeys", "published", "pending")
 
     def __init__(self, req: GenerationRequest, admit_order: int):
         self.req = req
@@ -161,6 +173,8 @@ class _Seq:
         self.admit_failures = 0    # consecutive transient re-admit fails
         self.pkeys = None          # [(boundary, hash)] — PrefixCache keys
         self.published = 0         # prompt tokens already cached
+        self.pending = 0           # tokens sampled on the device and
+        #                            not fetched yet (lookahead: 0 or 1)
 
 
 class GenerationEngine:
@@ -189,7 +203,8 @@ class GenerationEngine:
                  quant_mode: Optional[str] = None,
                  kv_dtype: Optional[str] = None,
                  kernel: Optional[str] = None,
-                 autotune: Optional[bool] = None):
+                 autotune: Optional[bool] = None,
+                 lookahead: Optional[int] = None):
         self.cfg = cfg
         self.params = jax.tree.map(jnp.asarray, params)
         nb = int(num_blocks if num_blocks is not None
@@ -222,6 +237,11 @@ class GenerationEngine:
             raise ValueError(
                 "quant_mode='fp8' needs float8_e4m3fn in this jax "
                 "build/backend (quant.supports_fp8()) — use 'int8'")
+        if self.quant_mode != "off" and not cfg.weight_quant:
+            raise ValueError(
+                "quant_mode=%r: post-training weight quantization does "
+                "not know the leaves of %s" % (self.quant_mode,
+                                               type(cfg).__name__))
         kvq = str(kv_dtype if kv_dtype is not None
                   else get_flag("FLAGS_generation_kv_quant"))
         if kvq == "auto":
@@ -229,8 +249,8 @@ class GenerationEngine:
             # HBM saving on the pools too; fp8 KV stays opt-in
             kvq = "int8" if self.quant_mode != "off" else "fp32"
         if kvq not in _quant.KV_DTYPES:
-            raise ValueError("unknown kv_dtype %r (auto|fp32|int8|fp8)"
-                             % kvq)
+            raise ValueError(
+                "unknown kv_dtype %r (auto|fp32|bf16|int8|fp8)" % kvq)
         if kvq == "fp8" and not _quant.supports_fp8():
             raise ValueError(
                 "kv_dtype='fp8' needs float8_e4m3fn in this jax "
@@ -308,9 +328,24 @@ class GenerationEngine:
                 "FLAGS_generation_prefill_chunk > 0")
         if self.kv_dtype != "fp32" and not self.prefill_chunk:
             raise ValueError(
-                "quantized KV rides the chunked mixed step — "
-                "FLAGS_generation_kv_quant needs "
-                "FLAGS_generation_prefill_chunk > 0")
+                "a KV pool narrower than float32 rides the chunked "
+                "mixed step — FLAGS_generation_kv_quant=%s needs "
+                "FLAGS_generation_prefill_chunk > 0" % self.kv_dtype)
+        # One step ahead of the host (_mixed_ahead) wherever the step
+        # allows it: the chunked mixed step without speculation (a
+        # draft is verified against tokens the host has not seen yet).
+        # No flag: the argument is for tests, which hold the streams
+        # with it to the streams without.
+        can_look = bool(self.prefill_chunk) and not self.spec_tokens
+        self.lookahead = int(can_look if lookahead is None else lookahead)
+        if self.lookahead not in (0, 1):
+            raise ValueError("lookahead must be 0 or 1")
+        if self.lookahead and not can_look:
+            raise ValueError(
+                "lookahead dispatches the chunked mixed step ahead of "
+                "the tokens it feeds on — it needs "
+                "FLAGS_generation_prefill_chunk > 0 and "
+                "FLAGS_generation_spec_tokens 0")
         if self.prefill_chunk:
             # chunked mode: prompts stream through the mixed step, so
             # the bucket ladder is a compat shim with one rung
@@ -401,6 +436,13 @@ class GenerationEngine:
         self._seeds = np.zeros((w,), np.int32)
         self._pending: List[_Seq] = []     # admitted, awaiting prefill
         self._admit_counter = 0
+        # lookahead: the mixed step that runs on the device while the
+        # host plans the next one — (tokens on the device, decode
+        # plan, chunk plan, time of dispatch); None when none is out
+        self._inflight = None
+        self._t_collected = 0.0
+        # what a step feeds on where the host gives every token itself
+        self._no_prev = jnp.zeros((self.sample_width,), jnp.int32)
         # per-request error sink: the scheduler points this at the
         # request's future; the bare engine re-raises
         self.on_request_error = None
@@ -413,32 +455,36 @@ class GenerationEngine:
 
     def _pool_specs(self) -> Dict[str, tuple]:
         """attribute -> (shape, dtype, fill) of every device pool this
-        engine holds. A pool is `[layers, N, block_size, heads *
-        head_dim]`: the heads' two axes are kept FLAT, because the TPU's
+        engine holds: the ONE place the cache's geometry is written
+        down, from what the model family's config says of it
+        (`kv_layers`, `kv_row`, `kv_heads`; a looped family holds
+        passes x layers of cache, a head need not be hidden / heads).
+        A pool is `[kv_layers, N, block_size, kv_heads * head_dim]`:
+        the heads' two axes are kept FLAT, because the TPU's
         default layout of a `[..., heads, 64]` array makes the block
         axis N the minor one, and then neither the step's scatter nor
         the block-table gather can use the array as it lies (each
         copied the whole pool, PERF.md PR 28). Flat, a block is one
         contiguous `[block_size, hidden]` tile."""
         cfg, nb, bs = self.cfg, self.kv.num_blocks, self.kv.block_size
-        shape = (cfg.layers, nb, bs, cfg.hidden)
-        if self.kv_dtype == "fp32":
-            specs = {"k_pools": (shape, jnp.float32, 0),
-                     "v_pools": (shape, jnp.float32, 0)}
+        shape = (cfg.kv_layers, nb, bs, cfg.kv_row)
+        if self.kv_dtype in _PLAIN_KV:
+            dt = _PLAIN_KV[self.kv_dtype]
+            specs = {"k_pools": (shape, dt, 0), "v_pools": (shape, dt, 0)}
         else:
             # quantized pool + per-token-per-head fp32 absmax scale
             # pool (quant.quantize_kv_rows). Scales init to ONE so a
             # trash-block / never-written row dequantizes its zero
             # payload to exact 0.0, same as the fp32 pools
             dt = _quant.storage_dtype(self.kv_dtype)
-            sshape = (cfg.layers, nb, bs, cfg.heads)
+            sshape = (cfg.kv_layers, nb, bs, cfg.kv_heads)
             specs = {"k_pools": (shape, dt, 0),
                      "v_pools": (shape, dt, 0),
                      "k_scales": (sshape, jnp.float32, 1),
                      "v_scales": (sshape, jnp.float32, 1)}
         if self.draft_params is not None:
-            dshape = (self.draft_cfg.layers, nb, bs,
-                      self.draft_cfg.hidden)
+            dshape = (self.draft_cfg.kv_layers, nb, bs,
+                      self.draft_cfg.kv_row)
             specs["dk_pools"] = (dshape, jnp.float32, 0)
             specs["dv_pools"] = (dshape, jnp.float32, 0)
         return specs
@@ -472,14 +518,14 @@ class GenerationEngine:
     def kv_bytes_per_seq(self) -> int:
         """Pool bytes one max-length sequence occupies (payload +
         scales over its max_blocks_per_seq table span) — the value
-        behind GAUGE_kv_bytes_per_seq."""
-        cfg = self.cfg
-        per_tok = 2 * cfg.layers * cfg.heads * cfg.head_dim \
-            * jnp.dtype(self.k_pools.dtype).itemsize
-        if self.k_scales is not None:
-            per_tok += 2 * cfg.layers * cfg.heads * 4
-        return int(per_tok * self.kv.block_size
-                   * self.max_blocks_per_seq)
+        behind GAUGE_kv_bytes_per_seq. Read off the pool spec: a
+        block's bytes in every pool the mixed step writes (the
+        drafter's are its own), times the table span."""
+        per_block = sum(
+            math.prod(shape) // shape[1] * jnp.dtype(dtype).itemsize
+            for name, (shape, dtype, _) in self._pool_specs().items()
+            if name in self._program_pools("mixed"))
+        return int(per_block * self.max_blocks_per_seq)
 
     def kv_capacity_seqs(self) -> int:
         """Concurrent max-length sequences the pool admits (block 0 is
@@ -493,6 +539,7 @@ class GenerationEngine:
         the scheduler's _reset_engine, so a post-fault rebuild retracts
         stale values (tests/test_failpoints.py pins this)."""
         gauge_set("GAUGE_kv_bytes_per_seq", self.kv_bytes_per_seq())
+        gauge_set("GAUGE_kv_layers", self.cfg.kv_layers)
         gauge_set("GAUGE_kv_capacity_seqs", self.kv_capacity_seqs())
         gauge_set("GAUGE_quant_weight_bytes_saved",
                   _quant.weight_bytes_saved(self.params))
@@ -527,8 +574,8 @@ class GenerationEngine:
             lanes = self.attn_lanes
 
             def raw(params, tokens, lengths):
-                return forward_full(cfg, params, tokens, lengths,
-                                    attn_lanes=lanes)
+                return cfg.forward_full(params, tokens, lengths,
+                                        attn_lanes=lanes)
             avals = (
                 jax.tree.map(_sds, self.params),
                 jax.ShapeDtypeStruct((1, bucket), jnp.int32),
@@ -537,8 +584,8 @@ class GenerationEngine:
         elif kind == "decode":
             def raw(params, kp, vp, tables, ctx, tokens, temps, tks,
                     tps, seeds, steps):
-                logits, kp2, vp2 = forward_paged(
-                    cfg, params, kp, vp, tables, ctx, tokens)
+                logits, kp2, vp2 = cfg.forward_paged(
+                    params, kp, vp, tables, ctx, tokens)
                 nxt = sample_tokens(logits, temps, tks, tps, seeds,
                                     steps)
                 return nxt, kp2, vp2
@@ -577,46 +624,44 @@ class GenerationEngine:
             # executable (5-tuple state) — the dequant runs inside the
             # attention kernel's online-softmax loop, not as a separate
             # pass, so the step count and shapes never change.
-            if self.k_scales is not None:
-                def raw(params, kp, vp, ks, vs, tables, positions,
-                        tokens, sample_slots, temps, tks, tps, seeds,
-                        steps):
-                    logits, kp2, vp2, ks2, vs2 = forward_paged(
-                        cfg, params, kp, vp, tables, positions, tokens,
-                        k_scale_pools=ks, v_scale_pools=vs)
-                    with jax.named_scope("sampler"):
-                        nxt = sample_tokens(logits[sample_slots], temps,
-                                            tks, tps, seeds, steps)
-                    return nxt, kp2, vp2, ks2, vs2
-                pool_avals = (_sds(self.k_pools), _sds(self.v_pools),
-                              _sds(self.k_scales), _sds(self.v_scales))
-            else:
-                def raw(params, kp, vp, tables, positions, tokens,
-                        sample_slots, temps, tks, tps, seeds, steps):
-                    logits, kp2, vp2 = forward_paged(
-                        cfg, params, kp, vp, tables, positions, tokens)
-                    with jax.named_scope("sampler"):
-                        nxt = sample_tokens(logits[sample_slots], temps,
-                                            tks, tps, seeds, steps)
-                    return nxt, kp2, vp2
-                pool_avals = (_sds(self.k_pools), _sds(self.v_pools))
+            # The step's ten host arrays travel as TWO (one int32, one
+            # float32: _pack_mixed), split again in here: each array
+            # handed over costs the host an allocation, a linearize and
+            # a transfer of its own, serial with the device (3.1 ms of
+            # dispatch a step, PERF.md PR 29).
             m = self.max_blocks_per_seq
             t = self.token_budget
             sw = self.sample_width
-            i32 = jnp.int32
-            avals = (
-                jax.tree.map(_sds, self.params),
-            ) + pool_avals + (
-                jax.ShapeDtypeStruct((t, m), i32),
-                jax.ShapeDtypeStruct((t,), i32),
-                jax.ShapeDtypeStruct((t,), i32),
-                jax.ShapeDtypeStruct((sw,), i32),
-                jax.ShapeDtypeStruct((sw,), jnp.float32),
-                jax.ShapeDtypeStruct((sw,), i32),
-                jax.ShapeDtypeStruct((sw,), jnp.float32),
-                jax.ShapeDtypeStruct((sw,), i32),
-                jax.ShapeDtypeStruct((sw,), i32),
-            )
+            quant_kv = self.k_scales is not None
+
+            def raw(params, *rest):
+                pools, (prev, ints, floats) = rest[:-3], rest[-3:]
+                tables, positions, tokens, feed_rows, sample_slots, \
+                    tks, seeds, steps = jnp.split(ints, np.cumsum(
+                        (t * m, t, t, t, sw, sw, sw)).tolist())
+                # lookahead: a slot whose token the host has not seen
+                # yet takes it from the previous step's samples, which
+                # never left the device (row feed_rows[slot]; -1: the
+                # host's own token)
+                tokens = jnp.where(feed_rows >= 0,
+                                   prev[jnp.maximum(feed_rows, 0)],
+                                   tokens)
+                temps, tps = floats[:sw], floats[sw:]
+                scales = dict(k_scale_pools=pools[2],
+                              v_scale_pools=pools[3]) if quant_kv else {}
+                out = cfg.forward_paged(
+                    params, pools[0], pools[1], tables.reshape(t, m),
+                    positions, tokens, **scales)
+                with jax.named_scope("sampler"):
+                    nxt = sample_tokens(out[0][sample_slots], temps,
+                                        tks, tps, seeds, steps)
+                return (nxt,) + tuple(out[1:])
+            avals = (jax.tree.map(_sds, self.params),) + tuple(
+                _sds(getattr(self, n))
+                for n in self._program_pools(kind)) + (
+                jax.ShapeDtypeStruct((sw,), jnp.int32),
+                jax.ShapeDtypeStruct((t * m + 3 * t + 4 * sw,), jnp.int32),
+                jax.ShapeDtypeStruct((2 * sw,), jnp.float32))
         elif kind in ("cow", "draft_cow"):
             # copy-on-write: clone one pool block's rows (every layer)
             # before a write would mutate a shared block. Scalar
@@ -645,8 +690,8 @@ class GenerationEngine:
             dcfg = self.draft_cfg
 
             def raw(params, kp, vp, tables, positions, tokens):
-                logits, kp2, vp2 = forward_paged(
-                    dcfg, params, kp, vp, tables, positions, tokens)
+                logits, kp2, vp2 = dcfg.forward_paged(
+                    params, kp, vp, tables, positions, tokens)
                 with jax.named_scope("sampler"):
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return nxt, kp2, vp2
@@ -709,8 +754,16 @@ class GenerationEngine:
         # checkpoint; samp rides along because two engines can share
         # every other dimension yet differ in spec_tokens. v=5: the
         # pools are donated and hold the heads' axes flat, so no entry
-        # stored before that is served.
-        meta = dict(base, kind=kind, bucket=bucket, v=5,
+        # stored before that is served. v=6: the pools' geometry is
+        # the model family's (kv_layers x kv_row, in `base`), and the
+        # weights' dtype rides along (wdt): a float32 and a bfloat16
+        # checkpoint of one config are two programs. v=7: the mixed
+        # step takes the previous step's samples and `feed_rows`.
+        weights = (self.draft_params if kind.startswith("draft")
+                   else self.params)
+        meta = dict(base, kind=kind, bucket=bucket, v=7,
+                    wdt=sorted({str(w.dtype)
+                                for w in jax.tree.leaves(weights)}),
                     blocks=self.kv.num_blocks,
                     block_size=self.kv.block_size,
                     width=self.decode_width,
@@ -793,7 +846,7 @@ class GenerationEngine:
         bs = self.kv.block_size
         blk = np.zeros(bucket, np.int32)  # TRASH_BLOCK
         off = (np.arange(bucket) % bs).astype(np.int32)
-        rows = (self.cfg.layers, bucket, self.cfg.hidden)
+        rows = (self.cfg.kv_layers, bucket, self.cfg.kv_row)
         self.k_pools = self.k_pools.at[:, blk, off].set(
             kc[:, 0].reshape(rows))
         self.v_pools = self.v_pools.at[:, blk, off].set(
@@ -810,13 +863,13 @@ class GenerationEngine:
 
     def _warm_mixed(self) -> None:
         t, sw = self.token_budget, self.sample_width
-        zt = jnp.zeros((t,), jnp.int32)
-        zs = jnp.zeros((sw,), jnp.int32)
+        zt = np.zeros((t,), np.int32)
+        zs = np.zeros((sw,), np.int32)
         # every slot writes the trash block
-        self._run("mixed",
-                  jnp.zeros((t, self.max_blocks_per_seq), jnp.int32),
-                  zt, zt, zs, jnp.zeros((sw,), jnp.float32), zs,
-                  jnp.ones((sw,), jnp.float32), zs, zs)
+        self._run("mixed", self._no_prev, *_pack_mixed(
+            np.zeros((t, self.max_blocks_per_seq), np.int32), zt, zt,
+            zt - 1, zs, np.zeros((sw,), np.float32), zs,
+            np.ones((sw,), np.float32), zs, zs))
 
     def _warm_cow(self, kind: str) -> None:
         # trash-block self-copy: compiles the clone, mutates nothing
@@ -914,7 +967,12 @@ class GenerationEngine:
             with _tm.span("pt/engine/admit", track="generation"):
                 self._admit()
             if self.active_count == 0:
+                # lookahead: a step whose riders all ended at their EOS
+                # has nobody left to hand a token to
+                self._inflight = None
                 return []
+            if self.lookahead:
+                return self._mixed_ahead()
             if self.prefill_chunk:
                 return self._mixed_once()
             return self._decode_once()
@@ -1081,7 +1139,7 @@ class GenerationEngine:
             tbl = np.asarray(table, np.int32)
             blk = tbl[np.minimum(pos // bs, len(tbl) - 1)]
             off = (pos % bs).astype(np.int32)
-            rows = (self.cfg.layers, bucket, self.cfg.hidden)
+            rows = (self.cfg.kv_layers, bucket, self.cfg.kv_row)
             self.k_pools = self.k_pools.at[:, blk, off].set(
                 kc[:, 0, :bucket].reshape(rows))
             self.v_pools = self.v_pools.at[:, blk, off].set(
@@ -1140,9 +1198,37 @@ class GenerationEngine:
         call step() again and the batch resumes exactly where it was —
         no token duplication, the basis of the mid-prompt fault
         recovery test."""
+        finished: List[GenerationResult] = []
+        plan = self._plan_mixed(finished)
+        if plan is None:
+            return finished
+        packed, decode_plan, chunk_plan = plan
+        t0 = time.perf_counter()
+        with _tm.trace_scope(self._riders(decode_plan, chunk_plan)):
+            with _tm.span("pt/engine/dispatch", track="generation"):
+                nxt = self._run("mixed", self._no_prev, *packed)
+            with _tm.span("pt/engine/fetch", track="generation"):
+                nxt = np.asarray(nxt)
+        dt_us = (time.perf_counter() - t0) * 1e6
+        self._emit_mixed(nxt, dt_us, decode_plan, chunk_plan, finished)
+        return finished
+
+    def _riders(self, decode_plan, chunk_plan) -> Optional[str]:
+        """The trace ids of the requests that ride a step."""
+        if not _tm.enabled():
+            return None
+        return ",".join(
+            tid for tid in (p[1].req.trace.trace_id
+                            for p in decode_plan + chunk_plan) if tid)
+
+    def _plan_mixed(self, finished: List[GenerationResult]):
+        """The host's half of a mixed step before the compiled call:
+        -> (the two packed host arrays, decode plan, chunk plan), or
+        None where no lane has anything to run. Sequences retired on
+        the way are appended to `finished`. Reads engine state only
+        (see _mixed_once), but for the block ledger."""
         with _tm.span("pt/engine/plan", track="generation"):
             failpoint("generation.decode")
-            finished: List[GenerationResult] = []
             # retire sequences whose PREVIOUS token already terminated them
             for lane, seq in enumerate(self._lane_seq):
                 if seq is None:
@@ -1168,20 +1254,29 @@ class GenerationEngine:
                     if self.prefix_cache is not None and \
                             self.prefix_cache.evict_for(1):
                         continue
+                    if self._inflight is not None:
+                        # lookahead: what the step on the device ends
+                        # gives its blocks back before anyone is
+                        # preempted (and a replay loses no token that
+                        # is still on the device). A cold cached prefix
+                        # goes first: that needs no wait for the device
+                        finished.extend(self._collect())
+                        continue
                     if not self._preempt_youngest():
                         raise
             decode_lanes = []
             prefill_lanes = []
             for ln, s in enumerate(self._lane_seq):
-                if s is None:
+                if s is None or self._spent(s):
                     continue
                 if s.prefilled >= len(s.req.prompt):
                     decode_lanes.append(ln)
                 else:
                     prefill_lanes.append(ln)
             if not decode_lanes and not prefill_lanes:
-                gauge_set("GAUGE_generation_active_seqs", 0)
-                return finished
+                if self._inflight is None:
+                    gauge_set("GAUGE_generation_active_seqs", 0)
+                return None
             # chunk plan BEFORE drafting, using the conservative s_cap slot
             # layout: the model drafter's call 0 ingests these chunk tokens
             # into the draft pools, so the plan must be fixed first. If the
@@ -1201,6 +1296,9 @@ class GenerationEngine:
             tables = np.full((t, m), TRASH_BLOCK, np.int32)
             positions = np.zeros((t,), np.int32)
             tokens = np.zeros((t,), np.int32)
+            # lookahead: the sampler row of the step on the device that
+            # holds this slot's token (-1: `tokens` holds it)
+            feed_rows = np.full((t,), -1, np.int32)
             # sampler arrays are [sample_width]: each LANE owns 1 + k
             # consecutive rows (rows ln*(1+k) .. ln*(1+k)+k); a decode lane
             # uses rows 0..len(drafts) for its verify chain, a prefill lane
@@ -1220,9 +1318,15 @@ class GenerationEngine:
             for ln in decode_lanes:
                 seq = self._lane_seq[ln]
                 d = drafts.get(ln, [])[:s_cap.get(ln, 0)]
-                feed = [seq.generated[-1]] + d
-                base = len(seq.generated)
                 row0 = ln * rpl
+                if seq.pending:
+                    # its last token is still on the device, in the
+                    # lane's own sampler row of the step before
+                    feed = [0]
+                    feed_rows[slot] = row0
+                else:
+                    feed = [seq.generated[-1]] + d
+                base = len(seq.generated) + seq.pending
                 for j in range(len(feed)):
                     tables[slot] = self._tables[ln]
                     positions[slot] = seq.ctx + j
@@ -1259,6 +1363,10 @@ class GenerationEngine:
                 seeds[row0] = sp.seed
                 steps[row0] = 0
             stat_add("STAT_generation_pad_tokens", t - slot)
+            # positions the live slots attend over this step: each sees
+            # the cache up to and with its own token
+            stat_add("STAT_generation_attended_tokens",
+                     int(positions[:slot].sum()) + slot)
             if self.k_scales is not None:
                 # this step's fresh K/V rows quantize inside the compiled
                 # call — the failpoint models a fault in that stage, and it
@@ -1270,23 +1378,16 @@ class GenerationEngine:
                            for i in range(slot)}
                 written.discard(TRASH_BLOCK)
                 stat_add("STAT_generation_kv_quant_blocks", len(written))
-        t0 = time.perf_counter()
-        riders = decode_lanes + [c[0] for c in chunk_plan]
-        tids = ",".join(
-            tid for tid in (self._lane_seq[ln].req.trace.trace_id
-                            for ln in riders) if tid) \
-            if _tm.enabled() else None
-        with _tm.trace_scope(tids):
-            with _tm.span("pt/engine/dispatch", track="generation"):
-                nxt = self._run(
-                    "mixed", jnp.asarray(tables), jnp.asarray(positions),
-                    jnp.asarray(tokens), jnp.asarray(sample_slots),
-                    jnp.asarray(temps), jnp.asarray(tks),
-                    jnp.asarray(tps), jnp.asarray(seeds),
-                    jnp.asarray(steps))
-            with _tm.span("pt/engine/fetch", track="generation"):
-                nxt = np.asarray(nxt)
-        dt_us = (time.perf_counter() - t0) * 1e6
+            return (_pack_mixed(tables, positions, tokens, feed_rows,
+                                sample_slots, temps, tks, tps, seeds,
+                                steps), decode_plan, chunk_plan)
+
+    def _emit_mixed(self, nxt, dt_us, decode_plan, chunk_plan,
+                    finished: List[GenerationResult]) -> None:
+        """The host's half of a mixed step after the fetch: every
+        sampled token to its sequence, the chunks' progress, the
+        retirements (appended to `finished`)."""
+        rpl = 1 + self.spec_tokens          # sampler rows per lane
         with _tm.span("pt/engine/emit", track="generation"):
             timer_observe("TIMER_generation_mixed_step_us", dt_us)
             # the mixed step IS the decode step of this engine — keep the
@@ -1344,6 +1445,112 @@ class GenerationEngine:
                     if done is not None:
                         finished.append(self._retire(ln, done))
             gauge_set("GAUGE_generation_active_seqs", self.active_count)
+
+    # --- lookahead: one step on the device while the host plans ---------
+
+    def _spent(self, seq: _Seq) -> bool:
+        """Its last token is sampled and still on the device: the
+        sequence takes no further slot and waits for _collect to retire
+        it. (Never true without lookahead: a sequence at its length is
+        retired by the emit that appended the token.)"""
+        return seq.pending > 0 and \
+            len(seq.generated) + seq.pending >= seq.req.max_new_tokens
+
+    def _mixed_ahead(self) -> List[GenerationResult]:
+        """One mixed step with lookahead: plan step n from what step
+        n-1 WILL have done (positions, chunk progress and lengths do
+        not depend on the tokens sampled), dispatch it feeding on the
+        tokens step n-1 leaves on the device, and only then fetch those
+        tokens and hand them out, while step n runs. The device goes
+        from one step into the next; the host's part, and any stall of
+        it shorter than a step, is hidden behind the device.
+
+        What the tokens decide is seen one step late: a sequence that
+        sampled its EOS in step n-1 still rides step n, and that slot's
+        sample is dropped (its K/V row lands in a block the sequence
+        owned when the step was dispatched; whoever gets the block next
+        writes before reading, and the device runs the steps in order).
+        A sequence at its length takes no slot (_spent) and its lane is
+        free again one step later than without lookahead. A fault
+        raised by the device surfaces at the fetch, one call late, and
+        leaves the engine to be reset (GenerationPool does)."""
+        finished: List[GenerationResult] = []
+        plan = self._plan_mixed(finished)
+        if plan is not None:
+            packed, decode_plan, chunk_plan = plan
+            prev = (self._no_prev if self._inflight is None
+                    else self._inflight[0])
+            with _tm.trace_scope(self._riders(decode_plan, chunk_plan)), \
+                    _tm.span("pt/engine/dispatch", track="generation"):
+                nxt = self._run("mixed", prev, *packed)
+            t_dispatch = time.perf_counter()
+        if self._inflight is not None:
+            finished.extend(self._collect())
+        if plan is not None:
+            self._advance(decode_plan, chunk_plan)
+            self._inflight = (nxt, decode_plan, chunk_plan, t_dispatch)
+        return finished
+
+    def _advance(self, decode_plan, chunk_plan) -> None:
+        """What the step just dispatched does to every sequence that
+        rides it, whatever it samples: one more position, one more
+        token on the device, the chunk's progress. A sequence the
+        collect in between retired (its EOS) is passed over."""
+        for ln, seq, _row0, _d in decode_plan:
+            if self._lane_seq[ln] is not seq:
+                continue
+            seq.ctx += 1
+            self._ctx[ln] = seq.ctx
+            seq.pending += 1
+        for ln, seq, start, take in chunk_plan:
+            if self._lane_seq[ln] is not seq:
+                continue
+            seq.prefilled = start + take
+            seq.ctx = seq.prefilled
+            self._ctx[ln] = seq.ctx
+            seq.req.trace.event("prefill_chunk", start=start, width=take)
+            self._publish_prefix(seq)
+            if seq.prefilled == len(seq.req.prompt):
+                seq.pending += 1        # its first token, row 0
+
+    def _collect(self) -> List[GenerationResult]:
+        """Fetch what the step on the device sampled (the wait for the
+        device, when the host is ahead) and hand each token to its
+        sequence; retire what ended. A sequence retired or preempted
+        since the step was dispatched has left its lane: its row is
+        dropped."""
+        nxt, decode_plan, chunk_plan, t_dispatch = self._inflight
+        self._inflight = None
+        finished: List[GenerationResult] = []
+        with _tm.span("pt/engine/fetch", track="generation"):
+            nxt = np.asarray(nxt)
+        with _tm.span("pt/engine/emit", track="generation"):
+            now = time.perf_counter()
+            # the step's time is the time it held the pipeline: from
+            # its dispatch, or from the fetch before it where the
+            # device was still busy with that step
+            dt_us = (now - max(t_dispatch, self._t_collected)) * 1e6
+            self._t_collected = now
+            timer_observe("TIMER_generation_mixed_step_us", dt_us)
+            timer_observe("TIMER_generation_decode_step_us", dt_us)
+            rows = [(ln, seq) for ln, seq, _r, _d in decode_plan] + [
+                (ln, seq) for ln, seq, start, take in chunk_plan
+                if start + take == len(seq.req.prompt)]
+            for ln, seq in rows:
+                if self._lane_seq[ln] is not seq:
+                    continue
+                seq.generated.append(int(nxt[ln]))
+                seq.pending -= 1
+                seq.req.trace.token()
+                if len(seq.generated) > 1:
+                    timer_observe("TIMER_generation_inter_token_us",
+                                  (now - seq.t_last_token) * 1e6)
+                seq.t_last_token = now
+                stat_add("STAT_generation_tokens")
+                done = self._finish_reason(seq)
+                if done is not None:
+                    finished.append(self._retire(ln, done))
+            gauge_set("GAUGE_generation_active_seqs", self.active_count)
         return finished
 
     def _spec_caps(self) -> Dict[int, int]:
@@ -1385,6 +1592,8 @@ class GenerationEngine:
         for lane, seq in enumerate(self._lane_seq):
             if seq is None:
                 continue
+            if self._spent(seq):
+                continue                # writes nothing more
             sid = id(seq)
             n = len(seq.req.prompt)
             if seq.prefilled >= n:
@@ -1724,6 +1933,20 @@ def _ngram_propose(hist: List[int], k: int) -> List[int]:
     return []
 
 
+# pool dtypes that are stored as they are, with no scale pools beside
+_PLAIN_KV = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
+
+
+def _pack_mixed(tables, positions, tokens, feed_rows, sample_slots,
+                temps, tks, tps, seeds, steps):
+    """The mixed step's ten host arrays as the two it takes them in
+    (`_build_fn`, kind `mixed`, splits them again by the same
+    order)."""
+    return (np.concatenate([tables.ravel(), positions, tokens, feed_rows,
+                            sample_slots, tks, seeds, steps]),
+            np.concatenate([temps, tps]))
+
+
 def _sds(v) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(jnp.shape(v), jnp.asarray(v).dtype)
 
@@ -1753,8 +1976,8 @@ class NaiveGenerator:
         if fn is None:
             cfg = self.cfg
             lanes = self.attn_lanes
-            fn = jax.jit(lambda p, t, l: forward_full(
-                cfg, p, t, l, attn_lanes=lanes)[0])
+            fn = jax.jit(lambda p, t, l: cfg.forward_full(
+                p, t, l, attn_lanes=lanes)[0])
             self._fns[bucket] = fn
         return fn
 

@@ -64,11 +64,42 @@ class DecoderConfig:
         return self.hidden // self.heads
 
     def meta(self) -> dict:
-        """JSON-able identity for program_cache.fn_fingerprint."""
-        return {"vocab": self.vocab_size, "hidden": self.hidden,
-                "layers": self.layers, "heads": self.heads,
-                "max_seq_len": self.max_seq_len,
+        """JSON-able identity for program_cache.fn_fingerprint: every
+        field changes the compiled program."""
+        return {"family": "gpt", "vocab": self.vocab_size,
+                "hidden": self.hidden, "layers": self.layers,
+                "heads": self.heads, "max_seq_len": self.max_seq_len,
                 "mlp_ratio": self.mlp_ratio}
+
+    # --- the seam the engine reaches a model FAMILY through ------------
+    # (docs/generation.md, "Model families"): the cache's geometry, the
+    # two forwards and whether quant.quantize_decoder_params knows the
+    # leaves. generation/looped.py is the second family.
+    weight_quant = True
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers of KV cache a token holds: one a layer."""
+        return self.layers
+
+    @property
+    def kv_heads(self) -> int:
+        return self.heads
+
+    @property
+    def kv_row(self) -> int:
+        """Width of a token's K (or V) row in one cache layer."""
+        return self.hidden
+
+    def forward_full(self, params, tokens, lengths, attn_lanes: int = 0):
+        return forward_full(self, params, tokens, lengths, attn_lanes)
+
+    def forward_paged(self, params, k_pools, v_pools, block_tables,
+                      ctx_lens, tokens, k_scale_pools=None,
+                      v_scale_pools=None):
+        return forward_paged(self, params, k_pools, v_pools,
+                             block_tables, ctx_lens, tokens,
+                             k_scale_pools, v_scale_pools)
 
 
 def init_params(cfg: DecoderConfig, seed: int = 0) -> dict:
@@ -250,8 +281,12 @@ def forward_paged(cfg: DecoderConfig, params: dict, k_pools, v_pools,
                 v, vsc = _quant.quantize_kv_rows(v, v_pools.dtype)
                 k_scale_pools = k_scale_pools.at[i, blk, off].set(ksc)
                 v_scale_pools = v_scale_pools.at[i, blk, off].set(vsc)
-            k_pools = k_pools.at[i, blk, off].set(k.reshape(row))
-            v_pools = v_pools.at[i, blk, off].set(v.reshape(row))
+            # (a bfloat16 pool rounds the row here, once; float32 and
+            # quantized rows are already the pool's type)
+            k_pools = k_pools.at[i, blk, off].set(
+                k.reshape(row).astype(k_pools.dtype))
+            v_pools = v_pools.at[i, blk, off].set(
+                v.reshape(row).astype(v_pools.dtype))
         # the kernel opens `paged_attention` itself, in either form
         o = paged_attention(q, k_pools, v_pools, block_tables,
                             ctx_lens + 1, sm_scale=sm_scale,

@@ -141,6 +141,11 @@ class GenerationPool:
             gauge_set("GAUGE_generation_queue_depth", 0)
         from .. import introspect
         introspect.unregister_readiness("generation_pool_%d" % id(self))
+        # the engine pointed back at this pool: left so, engine and pool
+        # keep each other (and the engine's device pools and weights)
+        # alive until a garbage collection finds the cycle
+        if self.engine.on_request_error == self._on_request_error:
+            self.engine.on_request_error = None
 
     def __enter__(self) -> "GenerationPool":
         return self
@@ -389,6 +394,7 @@ class GenerationPool:
         eng._tables[:] = 0
         eng._ctx[:] = 0
         eng._pending = []
+        eng._inflight = None    # lookahead: the step the fault took
         # kv.__init__ republished the block gauges; retract the rest
         # explicitly so the reset is retraction-COMPLETE even if the
         # ledger's publish set ever narrows

@@ -30,9 +30,10 @@ Layouts: q `[B, H, D]` (one new token per sequence), pools
 `[N, block_size, H, D]`, block_tables `[B, max_blocks]` int32,
 ctx_lens `[B]` int32 (number of VISIBLE keys, i.e. the new token's
 position + 1). Returns `[B, H, D]`. The engine passes its whole
-stacked pools instead, `[layers, N, block_size, H * D]` with a static
-`layer=`: every entry point reads that layer's blocks where they lie,
-with no slice of the array the step updates in place.
+stacked pools instead, `[layers, N, block_size, H * D]` with
+`layer=` (an int, or a traced scalar inside a layer loop): every entry
+point reads that layer's blocks where they lie, with no slice of the
+array the step updates in place.
 
 RAGGED entry (PR 10, chunked prefill): `ragged_paged_attention` takes
 q `[B, Cq, H, D]` where row b carries `q_lens[b]` real queries — 1 for
@@ -160,8 +161,8 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
     `None` scales take the EXACT pre-quant expressions, keeping the
     fp32 path bitwise-identical.
 
-    STACKED POOLS: with `layer` (a static int) the pools are the
-    engine's whole arrays, `[layers, N, bs, H * D]` with the heads'
+    STACKED POOLS: with `layer` (an int, or a traced scalar inside a
+    layer loop) the pools are the engine's whole arrays, `[layers, N, bs, H * D]` with the heads'
     two axes FLAT (scale pools `[layers, N, bs, H]`), and the gather
     indexes `(layer, block)` straight into them, so the caller never
     slices a layer's pool out of the array it updates in place
@@ -269,15 +270,15 @@ def _tile(ref, heads):
                      axis=1)
 
 
-def _ragged_kernel(tables_ref, qlens_ref, lens_ref, q_ref, k_ref, v_ref,
-                   o_ref, acc_ref, m_ref, l_ref, *, block_size, sm_scale,
-                   num_blocks):
+def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
+                   k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                   block_size, sm_scale, num_blocks):
     """Grid (B, max_blocks): sequential online-softmax over the
-    sequence's blocks, Cq queries per row. tables/q_lens/ctx_lens
-    arrive via scalar prefetch — the index maps already used tables_ref
-    to pick this (k, v) block, so the body only handles the causal
-    chunk mask and the (m, l, acc) recurrence carried per (head,
-    query)."""
+    sequence's blocks, Cq queries per row. tables/q_lens/ctx_lens (and
+    the stacked pools' layer, which only the index maps read) arrive
+    via scalar prefetch — the index maps already used tables_ref to
+    pick this (k, v) block, so the body only handles the causal chunk
+    mask and the (m, l, acc) recurrence carried per (head, query)."""
     b = pl.program_id(0)
     mi = pl.program_id(1)
 
@@ -328,10 +329,10 @@ def _ragged_kernel(tables_ref, qlens_ref, lens_ref, q_ref, k_ref, v_ref,
                                  (1, 0, 2)).astype(o_ref.dtype)
 
 
-def _ragged_kernel_quant(tables_ref, qlens_ref, lens_ref, q_ref, k_ref,
-                         v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref,
-                         l_ref, *, block_size, sm_scale, num_blocks,
-                         inv_grid):
+def _ragged_kernel_quant(tables_ref, qlens_ref, lens_ref, layer_ref,
+                         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
+                         acc_ref, m_ref, l_ref, *, block_size, sm_scale,
+                         num_blocks, inv_grid):
     """Quantized-KV twin of _ragged_kernel: the block's int8/fp8 K/V
     tile arrives in VMEM with its `[bs, H]` absmax scale rows (same
     tbl[bi, mi] index maps), and dequant (stored * scale / GRID) runs
@@ -400,7 +401,12 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
     N, bs, H * D]` with the heads flat (scales `[layers, N, bs, H]`):
     the index maps put `layer` in front of the table's block, the
     layer axis is squeezed out of the tile, and the kernel bodies
-    split the flat `[1, bs, H * D]` block into heads (_tile)."""
+    split the flat `[1, bs, H * D]` block into heads (_tile). `layer`
+    is a scalar-prefetch operand like the tables, so it may be a
+    TRACED scalar: the looped family's layer loop
+    (generation/looped.py) reads cache slot `pass * layers + layer`
+    from inside a `lax.scan`, where an index map could not close over
+    it."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
@@ -415,14 +421,16 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
         if layer is None:
             return pl.BlockSpec(
                 (1, bs) + tail,
-                lambda bi, mi, tbl, qls, lens: (tbl[bi, mi],) + zeros)
+                lambda bi, mi, tbl, qls, lens, lyr:
+                (tbl[bi, mi],) + zeros)
         return pl.BlockSpec(
             (None, 1, bs) + tail,
-            lambda bi, mi, tbl, qls, lens: (layer, tbl[bi, mi]) + zeros)
+            lambda bi, mi, tbl, qls, lens, lyr:
+            (lyr[0], tbl[bi, mi]) + zeros)
     kv_spec = pool_spec(h, d) if layer is None else pool_spec(h * d)
     in_specs = [
         pl.BlockSpec((1, cq, h, d),
-                     lambda bi, mi, tbl, qls, lens: (bi, 0, 0, 0)),
+                     lambda bi, mi, tbl, qls, lens, lyr: (bi, 0, 0, 0)),
         kv_spec,
         kv_spec,
     ]
@@ -439,12 +447,12 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
         kern = functools.partial(_ragged_kernel, block_size=bs,
                                  sm_scale=sm_scale, num_blocks=m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # block_tables, q_lens, ctx_lens
+        num_scalar_prefetch=4,  # block_tables, q_lens, ctx_lens, layer
         grid=(b, m),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
             (1, cq, h, d),
-            lambda bi, mi, tbl, qls, lens: (bi, 0, 0, 0)),
+            lambda bi, mi, tbl, qls, lens, lyr: (bi, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, cq, d), jnp.float32),   # acc
             pltpu.VMEM((h, cq), jnp.float32),      # running max
@@ -458,7 +466,9 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
         interpret=interpret,
         name="paged_attention",
     )(block_tables.astype(jnp.int32), q_lens.astype(jnp.int32),
-      ctx_lens.astype(jnp.int32), *operands)
+      ctx_lens.astype(jnp.int32),
+      jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1),
+      *operands)
 
 
 def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens,
@@ -534,9 +544,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     blocked kernel (interpret mode off-TPU). k_scales/v_scales
     (quantized pools, paddle_tpu/quant) flow to the dequant-fused
     forms of both paths; None = the untouched fp32 path. `layer`
-    (static) says the pools are the stacked `[layers, N, bs, ...]`
-    arrays and picks the layer to read, in both forms without a
-    slice."""
+    (an int or a traced scalar) says the pools are the stacked
+    `[layers, N, bs, ...]` arrays and picks the layer to read, in both
+    forms without a slice. Pools may be float32, bfloat16 (read as
+    they are and upcast where they are multiplied) or quantized."""
     mode = resolved_form()
     # ONE device-trace name for the gather and the attention over it,
     # in either form: a kernel change is read by the same metric

@@ -54,7 +54,9 @@ GRID_FP8 = 448.0
 # "::" cannot collide with program var names (slim uses ".quant_scale")
 SCALE_SUFFIX = "::scale"
 MODES = ("off", "int8", "fp8")
-KV_DTYPES = ("fp32", "int8", "fp8")
+# "bf16" is a plain narrower pool (no scale pools, no grid); "int8" and
+# "fp8" store a payload on a grid beside per-token-per-head scales
+KV_DTYPES = ("fp32", "bf16", "int8", "fp8")
 
 
 def supports_fp8() -> bool:

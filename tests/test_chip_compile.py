@@ -17,6 +17,7 @@ could land on another xdist worker.
 """
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -142,3 +143,92 @@ def test_ragged_paged_attention_compiles_for_v5e(one_chip, pool_dtype,
         fn = functools.partial(ragged_paged_attention_pallas,
                                interpret=False, layer=layer)
     _compile(fn, one_chip, *avals)
+
+
+# The looped family (generation/looped.py) at the sizes of the benchmark's
+# cell `ouro_2_6b_reason_c16`: a bfloat16 pool of 192 cache layers of
+# rows 16 x 128, 320 blocks of 16, 24 slots a step, and the cache layer
+# a TRACED scalar (`pass * 48 + layer`, from inside the layer loop).
+_LOOPED_POOL = _sds((192, 320, 16, 2048), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("form", ["reference", "pallas"])
+def test_paged_attention_takes_a_traced_layer_for_v5e(one_chip, form):
+    from paddle_tpu.kernels import paged_attention as pa
+    fn = {"reference": pa.paged_attention_reference,
+          "pallas": functools.partial(pa.paged_attention_pallas,
+                                      interpret=False)}[form]
+
+    def attend(q, kp, vp, tables, ctx, layer):
+        return fn(q, kp, vp, tables, ctx, layer=layer)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in (_sds((24, 16, 128), jnp.float32), _LOOPED_POOL,
+                      _LOOPED_POOL, _sds((24, 32), jnp.int32),
+                      _sds((24,), jnp.int32), _sds((), jnp.int32))]
+    txt = jax.jit(attend).lower(*args).compile().as_text()
+    assert ("tpu_custom_call" in txt) == (form == "pallas")
+    # the pools are read where they lie: nothing pool-shaped is made
+    assert not re.search(r"= bf16\[192,320,16,2048\]\S* (?!parameter)",
+                         txt)
+
+
+@pytest.mark.parametrize("form", ["reference", "pallas"])
+def test_looped_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch,
+                                                  form):
+    """The cell's whole step (48 layers x 4 passes, the published
+    widths, bfloat16 weights and pools, the sampler) as the engine
+    jits it: ONE loop body inside two nested loops, each pool aliased
+    to its output and never copied, weights + pools + temporaries
+    inside the chip's memory."""
+    import json
+    import os
+    from paddle_tpu.generation import looped, sample_tokens
+    from paddle_tpu.kernels import paged_attention as pa
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    src = json.load(open(os.path.join(root, "benchmark", "configs",
+                                      "ouro_2_6b.json")))
+    eng = src["engine"]
+    cfg = looped.LoopedDecoderConfig.from_source(src, eng["max_context"])
+    t, sw, m = eng["decode_width"] + 8, eng["decode_width"], \
+        eng["max_context"] // 16
+
+    def mixed(params, kp, vp, tables, positions, tokens, slots, temps,
+              tks, tps, seeds, steps):
+        logits, kp, vp = cfg.forward_paged(params, kp, vp, tables,
+                                           positions, tokens)
+        with jax.named_scope("sampler"):
+            return sample_tokens(logits[slots], temps, tks, tps, seeds,
+                                 steps), kp, vp
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    i32, f32 = jnp.int32, jnp.float32
+    params = {k: _sds(s, jnp.bfloat16)
+              for k, (s, _) in looped.leaf_shapes(cfg).items()}
+    assert _LOOPED_POOL.shape == (cfg.kv_layers,
+                                  eng["kv_pool_tokens"] // 16, 16,
+                                  cfg.kv_row)
+    args = on_chip((params, _LOOPED_POOL, _LOOPED_POOL, _sds((t, m), i32),
+                    _sds((t,), i32), _sds((t,), i32), _sds((sw,), i32),
+                    _sds((sw,), f32), _sds((sw,), i32), _sds((sw,), f32),
+                    _sds((sw,), i32), _sds((sw,), i32)))
+    with pa.kernel_form(form):
+        compiled = jax.jit(mixed, donate_argnums=(1, 2)).lower(
+            *args).compile()
+    txt = compiled.as_text()
+    assert txt.count(" while(") == 2
+    assert txt.count("tpu_custom_call") == (1 if form == "pallas" else 0)
+    head = txt.splitlines()[0]
+    alias = head[head.index("input_output_alias"):]
+    alias = alias[:alias.index("}, entry_computation_layout")]
+    assert len(re.findall(r"\(\d+, \{\}", alias)) == 2, alias
+    made = re.findall(r"= bf16\[192,320,16,2048\]\S* ([\w\-]+)\(", txt)
+    assert set(made) <= {"parameter", "get-tuple-element", "fusion",
+                         "scatter", "dynamic-update-slice"}, set(made)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 192 * 320 * 16 * 2048 * 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20

@@ -120,7 +120,10 @@ def test_engine_step_lands_in_the_trace_with_its_five_children(traced):
                 if any(e[0] == "pt/engine/step" for e in l))
     steps = _children(line, "pt/engine/step")
     assert len(steps) == traced["engine_steps"]
-    full = [k for _, k in steps if "pt/engine/dispatch" in k]
+    # a step with every phase: the engine runs one step ahead of the
+    # host, so its first call dispatches and has nothing to fetch yet
+    full = [k for _, k in steps
+            if "pt/engine/dispatch" in k and "pt/engine/fetch" in k]
     assert len(full) >= 3
     for kids in full:
         assert sorted(kids) == sorted(ENGINE_CHILDREN)
@@ -132,7 +135,8 @@ def test_the_children_cover_the_engine_step(traced):
                 if any(e[0] == "pt/engine/step" for e in l))
     shares = []
     for (s, d), kids in _children(line, "pt/engine/step"):
-        if "pt/engine/dispatch" not in kids:
+        if "pt/engine/dispatch" not in kids or \
+                "pt/engine/fetch" not in kids:
             continue
         shares.append(sum(cd for v in kids.values() for _, cd in v) / d)
     # what is left is the step's self time: a few statements between the
