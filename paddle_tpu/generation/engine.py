@@ -1363,6 +1363,11 @@ class GenerationEngine:
                 seeds[row0] = sp.seed
                 steps[row0] = 0
             stat_add("STAT_generation_pad_tokens", t - slot)
+            # a step that holds a sampled row takes the sampler's filter
+            # branch on the device (sampling.sample_tokens); the steps
+            # taken less this counter ran the argmax alone
+            if (temps > 0).any():
+                stat_add("STAT_generation_sampler_filter_steps")
             # positions the live slots attend over this step: each sees
             # the cache up to and with its own token
             stat_add("STAT_generation_attended_tokens",
@@ -1757,6 +1762,8 @@ class GenerationEngine:
                 return finished
             # idle lanes ride the fixed-width batch as padding
             stat_add("STAT_generation_pad_tokens", w - len(active))
+            if (self._temps > 0).any():
+                stat_add("STAT_generation_sampler_filter_steps")
             for ln in active:
                 seq = self._lane_seq[ln]
                 tokens[ln] = seq.generated[-1]
@@ -1813,6 +1820,7 @@ class GenerationEngine:
         self.kv.free(id(seq))
         self._tables[lane] = TRASH_BLOCK
         self._ctx[lane] = 0
+        self._temps[lane] = 0.0      # an idle lane is a greedy row
         toks = list(seq.generated)
         if reason == "eos":
             toks = toks[:-1]
@@ -1864,6 +1872,7 @@ class GenerationEngine:
         self.kv.evict(id(cand))
         self._tables[lane] = TRASH_BLOCK
         self._ctx[lane] = 0
+        self._temps[lane] = 0.0
         cand.req.trace.event("preempt", lane=lane,
                              ctx=int(cand.ctx),
                              generated=len(cand.generated))

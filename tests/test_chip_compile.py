@@ -145,6 +145,96 @@ def test_ragged_paged_attention_compiles_for_v5e(one_chip, pool_dtype,
     _compile(fn, one_chip, *avals)
 
 
+def _compile_mixed_step(cfg, sharding, params, pool, t, m, sw):
+    """The generation engine's mixed step as `engine._build_fn` traces
+    it for `cfg`'s model family (less the packing of its host arrays),
+    compiled for the described chip: `t` slots of `m` table entries,
+    `sw` sampler rows, both pools donated."""
+    from paddle_tpu.generation import sample_tokens
+
+    def mixed(params, kp, vp, tables, positions, tokens, slots, temps,
+              tks, tps, seeds, steps):
+        logits, kp, vp = cfg.forward_paged(params, kp, vp, tables,
+                                           positions, tokens)
+        with jax.named_scope("sampler"):
+            return sample_tokens(logits[slots], temps, tks, tps, seeds,
+                                 steps), kp, vp
+    i32, f32 = jnp.int32, jnp.float32
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        (params, pool, pool, _sds((t, m), i32), _sds((t,), i32),
+         _sds((t,), i32), _sds((sw,), i32), _sds((sw,), f32),
+         _sds((sw,), i32), _sds((sw,), f32), _sds((sw,), i32),
+         _sds((sw,), i32)))
+    return jax.jit(mixed, donate_argnums=(1, 2)).lower(*args).compile()
+
+
+def _computations(txt):
+    """Compiled HLO text -> {computation: its instruction lines}, the
+    entry computation under "ENTRY"."""
+    comps, name = {}, None
+    for line in txt.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _always_run(comps):
+    """The computations the entry reaches WITHOUT entering a branch of
+    a conditional: fusions, reducers, loop conditions and bodies."""
+    seen, todo = set(), ["ENTRY"]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            for line in comps[c]:
+                todo += re.findall(
+                    r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", line)
+    return seen
+
+
+def _assert_sorts_inside_the_one_conditional(txt):
+    """The sampler's batch-level branch survived the compiler: ONE
+    `conditional`, the sampler's, run on every step; and every sort of
+    the module (the filter path's) only behind a branch of it: none in
+    the entry computation or a loop body."""
+    comps = _computations(txt)
+    always = _always_run(comps)
+    conds = [ln for c in always for ln in comps[c] if " conditional(" in ln]
+    assert len(conds) == txt.count(" conditional(") == 1
+    assert "/sampler/" in conds[0]
+    sorting = {c for c, lines in comps.items()
+               if any(" sort(" in ln for ln in lines)}
+    assert sorting, "the filter path has no sort left"
+    assert not sorting & always, sorting & always
+
+
+def test_gpt2_mixed_step_sorts_only_inside_the_sampler_branch_for_v5e(
+        one_chip):
+    """GPT-2 small's whole mixed step at the sizes of the benchmark's
+    cell `gpt2_124m_chat_c32` (40 slots, 32 sampler rows over the
+    vocabulary of 50,257, a float32 pool of 32,768 tokens): the sorts
+    of `[32, 50257]` lie behind the sampler's conditional, both pools
+    are aliased."""
+    from paddle_tpu.generation import DecoderConfig, init_params
+    cfg = DecoderConfig(vocab_size=50257, hidden=768, layers=12, heads=12,
+                        max_seq_len=1024)
+    compiled = _compile_mixed_step(
+        cfg, one_chip, jax.eval_shape(lambda: init_params(cfg, seed=0)),
+        _sds((12, 2048, 16, 768), jnp.float32), t=40, m=1024 // 16, sw=32)
+    txt = compiled.as_text()
+    _assert_sorts_inside_the_one_conditional(txt)
+    assert re.search(r"= \(f32\[32,50257\][^=]* sort\(", txt)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * 12 * 2048 * 16 * 768 * 4
+
+
 # The looped family (generation/looped.py) at the sizes of the benchmark's
 # cell `ouro_2_6b_reason_c16`: a bfloat16 pool of 192 cache layers of
 # rows 16 x 128, 320 blocks of 16, 24 slots a step, and the cache layer
@@ -177,12 +267,13 @@ def test_looped_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch,
                                                   form):
     """The cell's whole step (48 layers x 4 passes, the published
     widths, bfloat16 weights and pools, the sampler) as the engine
-    jits it: ONE loop body inside two nested loops, each pool aliased
-    to its output and never copied, weights + pools + temporaries
-    inside the chip's memory."""
+    jits it: ONE loop body inside two nested loops, the sampler's
+    sorts behind its one conditional, each pool aliased to its output
+    and never copied, weights + pools + temporaries inside the chip's
+    memory."""
     import json
     import os
-    from paddle_tpu.generation import looped, sample_tokens
+    from paddle_tpu.generation import looped
     from paddle_tpu.kernels import paged_attention as pa
     monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -190,35 +281,19 @@ def test_looped_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch,
                                       "ouro_2_6b.json")))
     eng = src["engine"]
     cfg = looped.LoopedDecoderConfig.from_source(src, eng["max_context"])
-    t, sw, m = eng["decode_width"] + 8, eng["decode_width"], \
-        eng["max_context"] // 16
-
-    def mixed(params, kp, vp, tables, positions, tokens, slots, temps,
-              tks, tps, seeds, steps):
-        logits, kp, vp = cfg.forward_paged(params, kp, vp, tables,
-                                           positions, tokens)
-        with jax.named_scope("sampler"):
-            return sample_tokens(logits[slots], temps, tks, tps, seeds,
-                                 steps), kp, vp
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-    i32, f32 = jnp.int32, jnp.float32
     params = {k: _sds(s, jnp.bfloat16)
               for k, (s, _) in looped.leaf_shapes(cfg).items()}
     assert _LOOPED_POOL.shape == (cfg.kv_layers,
                                   eng["kv_pool_tokens"] // 16, 16,
                                   cfg.kv_row)
-    args = on_chip((params, _LOOPED_POOL, _LOOPED_POOL, _sds((t, m), i32),
-                    _sds((t,), i32), _sds((t,), i32), _sds((sw,), i32),
-                    _sds((sw,), f32), _sds((sw,), i32), _sds((sw,), f32),
-                    _sds((sw,), i32), _sds((sw,), i32)))
     with pa.kernel_form(form):
-        compiled = jax.jit(mixed, donate_argnums=(1, 2)).lower(
-            *args).compile()
+        compiled = _compile_mixed_step(
+            cfg, one_chip, params, _LOOPED_POOL,
+            t=eng["decode_width"] + 8, m=eng["max_context"] // 16,
+            sw=eng["decode_width"])
     txt = compiled.as_text()
     assert txt.count(" while(") == 2
+    _assert_sorts_inside_the_one_conditional(txt)
     assert txt.count("tpu_custom_call") == (1 if form == "pallas" else 0)
     head = txt.splitlines()[0]
     alias = head[head.index("input_output_alias"):]

@@ -16,6 +16,7 @@ from paddle_tpu.generation import (BlockPoolExhausted, DecoderConfig,
                                    TRASH_BLOCK, forward_full,
                                    forward_paged, init_params,
                                    sample_tokens)
+from paddle_tpu.generation.sampling import _sample_one
 from paddle_tpu.kernels.paged_attention import (paged_attention_pallas,
                                                 paged_attention_reference)
 from paddle_tpu.monitor import gauge_get, stat_get
@@ -242,6 +243,147 @@ def test_sampler_greedy_and_filters():
             logits, jnp.asarray([2.0]), jnp.asarray([0]),
             jnp.asarray([0.05]), jnp.asarray([seed]), jnp.asarray([3]))
         assert int(np.asarray(t)[0]) == 1
+
+
+# the sampler's batch-level branch (PR 30): rows of (temperature,
+# top_k, top_p); seeds and steps differ a row
+_BRANCH_BATCHES = {
+    "all_greedy": [(0.0, 0, 1.0)] * 5,
+    "all_greedy_filters_set": [(0.0, 8, 0.9), (0.0, 3, 0.5),
+                               (0.0, 0, 0.7), (0.0, 12, 1.0)],
+    "all_sampled_filters_unset": [(0.8, 0, 1.0)] * 5,
+    "all_sampled_filters_set": [(0.8, 8, 0.9), (1.3, 3, 0.5),
+                                (0.6, 0, 0.7), (0.9, 12, 1.0)],
+    "mixed_filters_unset": [(0.0, 0, 1.0), (0.8, 0, 1.0),
+                            (0.0, 0, 1.0), (1.1, 0, 1.0)],
+    "mixed_filters_set": [(0.0, 8, 0.9), (0.8, 8, 0.9), (0.0, 0, 1.0),
+                          (1.2, 0, 0.6), (0.7, 5, 1.0)],
+    "one_sampled_among_greedy": [(0.0, 0, 1.0)] * 6 + [(0.9, 0, 0.95)],
+    "one_row_greedy": [(0.0, 0, 1.0)],
+    "one_row_sampled": [(0.7, 4, 0.9)],
+}
+
+
+def _sampler_args(rows, seed0=11, step0=3):
+    n = len(rows)
+    return (jnp.asarray([r[0] for r in rows], jnp.float32),
+            jnp.asarray([r[1] for r in rows], jnp.int32),
+            jnp.asarray([r[2] for r in rows], jnp.float32),
+            jnp.arange(seed0, seed0 + n, dtype=jnp.int32),
+            jnp.arange(step0, step0 + n, dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("case", sorted(_BRANCH_BATCHES))
+def test_sample_tokens_equals_the_vmapped_lanes(case):
+    """`sample_tokens` decides ONE branch for the batch on the device;
+    whichever it takes, every row's token is the one the lanes vmapped
+    without the branch give (the parent's sampler, called directly)."""
+    rows = _BRANCH_BATCHES[case]
+    logits = jnp.asarray(np.random.default_rng(5).normal(
+        size=(len(rows), 257)) * 3.0, jnp.float32)
+    args = _sampler_args(rows)
+    oracle = jax.jit(jax.vmap(_sample_one))(logits, *args)
+    got = sample_tokens(logits, *args)
+    assert got.dtype == jnp.int32 and got.shape == (len(rows),)
+    assert np.array_equal(np.asarray(got), np.asarray(oracle))
+    greedy = np.asarray([r[0] <= 0 for r in rows])
+    assert np.array_equal(np.asarray(got)[greedy],
+                          np.asarray(jnp.argmax(logits, -1))[greedy])
+
+
+@pytest.mark.parametrize("row", [(0.0, 0, 1.0), (0.0, 6, 0.8),
+                                 (0.8, 0, 1.0), (0.8, 6, 0.8)],
+                         ids=["greedy", "greedy_filters_set",
+                              "sampled", "sampled_filters_set"])
+def test_row_token_whatever_its_batch_mates_ask_for(row):
+    """Determinism contract across the branch: a row's token is a pure
+    function of (its logits, seed, step). Its batch-mates flipping
+    between greedy and sampled flips the BATCH's branch (for a greedy
+    row) and must not move the row's token."""
+    logits = jnp.asarray(np.random.default_rng(9).normal(
+        size=(6, 129)) * 2.0, jnp.float32)
+    seen = set()
+    for mates in ([], [(0.0, 0, 1.0)] * 5, [(0.9, 0, 1.0)] * 5,
+                  [(0.9, 4, 0.7)] * 5,
+                  [(0.0, 0, 1.0), (1.0, 0, 1.0)] * 2 + [(0.0, 3, 0.5)]):
+        rows = [row] + mates
+        out = sample_tokens(logits[:len(rows)], *_sampler_args(rows))
+        seen.add(int(np.asarray(out)[0]))
+    assert len(seen) == 1
+
+
+def _mixed_requests(rng, n, sampled):
+    """n requests; `sampled(i)` says whether request i draws."""
+    return [GenerationRequest(
+        prompt=list(rng.integers(1, CFG.vocab_size,
+                                 int(rng.integers(2, 12)))),
+        max_new_tokens=int(rng.integers(3, 8)),
+        sampling=SamplingParams(
+            temperature=0.8 if sampled(i) else 0.0,
+            top_k=8 if i % 3 == 0 else 0,
+            top_p=0.9 if i % 4 == 0 else 1.0, seed=i),
+        request_id=i) for i in range(n)]
+
+
+_ENGINE_FORMS = {
+    "chunked_ahead": dict(prefill_chunk=3),
+    "chunked": dict(prefill_chunk=3, lookahead=0),
+    "two_phase": dict(prefill_chunk=0),
+    "speculative": dict(prefill_chunk=3, spec_tokens=2),
+}
+
+
+@pytest.mark.parametrize("form", sorted(_ENGINE_FORMS))
+def test_engine_streams_across_the_sampler_branch_match_naive(
+        params, form):
+    """A request list that is part greedy, part sampled, so that steps
+    of both branches (and lanes that change sides as requests come and
+    go) make up every stream: each equals the naive oracle's, which
+    samples one row at a time."""
+    reqs = _mixed_requests(np.random.default_rng(23), 9,
+                           lambda i: i % 3 == 1)
+    eng = _engine(params, **_ENGINE_FORMS[form])
+    res = {r.request_id: r.tokens for r in eng.generate(
+        [GenerationRequest(**r.__dict__) for r in reqs])}
+    naive = NaiveGenerator(CFG, params, buckets="pow2:16",
+                           attn_lanes=eng.attn_lanes)
+    for r in reqs:
+        assert naive.generate(r).tokens == res[r.request_id]
+
+
+@pytest.mark.parametrize("traffic", ["greedy", "sampled", "mixed"])
+@pytest.mark.parametrize("form", sorted(_ENGINE_FORMS))
+def test_sampler_filter_steps_counts_the_steps_with_a_sampled_row(
+        params, form, traffic):
+    """STAT_generation_sampler_filter_steps: one for each step whose
+    `temps`, as handed to the device, holds a value above 0: none in a
+    greedy run, every step where every request draws."""
+    eng = _engine(params, **_ENGINE_FORMS[form])
+    handed = []                 # each step's temps, as the device got them
+    run = eng._run
+
+    def spy(kind, *rest):
+        if kind == "mixed":
+            handed.append(np.asarray(rest[-1])[:eng.sample_width])
+        elif kind == "decode":
+            handed.append(np.asarray(rest[3]))
+        return run(kind, *rest)
+    eng._run = spy
+    reqs = _mixed_requests(
+        np.random.default_rng(31), 7,
+        {"greedy": lambda i: False, "sampled": lambda i: True,
+         "mixed": lambda i: i in (2, 3)}[traffic])
+    before = stat_get("STAT_generation_sampler_filter_steps")
+    eng.generate(reqs)
+    counted = stat_get("STAT_generation_sampler_filter_steps") - before
+    assert len(handed) > 5
+    assert counted == sum(bool((t > 0).any()) for t in handed)
+    if traffic == "greedy":
+        assert counted == 0
+    elif traffic == "sampled":
+        assert counted == len(handed)
+    else:
+        assert 0 < counted < len(handed)
 
 
 def test_sampling_params_validation():
