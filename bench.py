@@ -913,7 +913,7 @@ def bench_generation():
                         max_seq_len=128)
     params = init_params(cfg, seed=0)
     eng = GenerationEngine(cfg, params, num_blocks=256, block_size=8,
-                           decode_width=8, prefill_buckets="pow2:32")
+                           decode_width=8)
 
     rng = np.random.RandomState(0)
     R = 24
@@ -1066,194 +1066,6 @@ def bench_generation():
     }
 
 
-def bench_generation_mixed():
-    """mixed-workload generation block (ISSUE 10, docs/generation.md):
-    chunked prefill + ragged mixed step vs the PR-5 two-phase engine
-    over the SAME prompt-heavy request stream. The workload is chosen
-    to be pathological for two-phase: prompt lengths land just past a
-    pow2 bucket edge (65..96 -> bucket 128, up to ~2x padded prefill
-    compute) while other requests are mid-decode, so every prefill
-    head-of-line-blocks the decode lanes for a full padded forward.
-    The chunked engine streams the same prompts through the one mixed
-    executable a chunk at a time, decode tokens riding every step.
-
-    Gates (ISSUE 10 acceptance): chunked >= 1.3x generated tokens/s
-    AND lower decode-TPOT p95, zero steady-state recompiles, streams
-    bitwise-identical across naive/two-phase/chunked. TTFT/TPOT come
-    from each request's own RequestTrace (client-side percentiles, no
-    shared-timer crosstalk); TIMER_generation_mixed_step_us rides the
-    same persisted-snapshot stat_diff gate as the decode-step timer."""
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "tools"))
-    import stat_diff
-    from dataclasses import replace
-    from paddle_tpu.generation import (DecoderConfig, GenerationEngine,
-                                       GenerationRequest,
-                                       NaiveGenerator, SamplingParams,
-                                       init_params)
-    from paddle_tpu import monitor
-    from paddle_tpu import tracing as _tracing
-    from paddle_tpu.monitor import stat_get
-
-    cfg = DecoderConfig(vocab_size=128, hidden=64, layers=4, heads=4,
-                        max_seq_len=128)
-    params = init_params(cfg, seed=0)
-
-    rng = np.random.RandomState(7)
-    R = 32
-    reqs = []
-    for i in range(R):
-        plen = int(rng.randint(65, 74))     # just past the 64
-        # edge -> bucket 128: two-phase pads ~45% of every prefill
-        reqs.append(GenerationRequest(
-            prompt=list(rng.randint(1, cfg.vocab_size, size=plen)),
-            max_new_tokens=int(rng.randint(4, 9)),
-            sampling=SamplingParams(
-                temperature=0.8 if i % 2 else 0.0,
-                top_k=16 if i % 3 == 0 else 0, seed=i),
-            request_id=i))
-    total_new = sum(r.max_new_tokens for r in reqs)
-    total_prompt = sum(len(r.prompt) for r in reqs)
-
-    # --- naive oracle: full-context redecode, one request at a time --
-    naive = NaiveGenerator(cfg, params, buckets="pow2:128")
-    expected = {r.request_id: naive.generate(r) for r in reqs}
-
-    def _pct(xs, p):
-        if not xs:
-            return None
-        return round(sorted(xs)[int(p * (len(xs) - 1))], 1)
-
-    def run_pass(eng):
-        """Drain the full request stream once; return (wall, traces,
-        results, pad-token delta) for this pass."""
-        p0 = stat_get("STAT_generation_pad_tokens")
-        traces = {}
-        for r in reqs:
-            tr = _tracing.begin("generation")
-            traces[r.request_id] = tr
-            eng.submit(replace(r, trace=tr))
-        done = []
-        t0 = time.perf_counter()
-        while not eng.idle:
-            done.extend(eng.step())
-        wall = time.perf_counter() - t0
-        pad = stat_get("STAT_generation_pad_tokens") - p0
-        return wall, traces, done, pad
-
-    def report(best):
-        """tokens/s + per-request TTFT / mean-TPOT percentiles read
-        off the best pass's request traces."""
-        wall, traces, done, pad = best
-        ttfts, tpots = [], []
-        for tr in traces.values():
-            if getattr(tr, "t_first_token", None) is None:
-                continue
-            ttfts.append((tr.t_first_token - tr.t0) * 1e6)
-            if tr.tokens > 1:
-                tpots.append((tr.t_last_token - tr.t_first_token)
-                             / (tr.tokens - 1) * 1e6)
-        work = total_prompt + total_new
-        return {
-            "tokens_per_sec": round(total_new / wall, 1),
-            "ttft_us": {"p50": _pct(ttfts, 0.5),
-                        "p95": _pct(ttfts, 0.95)},
-            "decode_tpot_us": {"p50": _pct(tpots, 0.5),
-                               "p95": _pct(tpots, 0.95)},
-            "pad_tokens": int(pad),
-            "pad_ratio": round(pad / (pad + work), 3),
-        }, {res.request_id: res.tokens for res in done}
-
-    # Both engines drain the stream 4 times in ALTERNATING passes and
-    # each reports its best pass — for the same reason the tracing
-    # overhead block uses best-of-N: this container's CPU is noisy, and
-    # a throughput RATIO gate needs the noise floor below the margin.
-    # Interleaving matters as much as the repeats: machine-speed drift
-    # between two back-to-back best-of-N blocks moves the ratio, while
-    # alternated passes sample the same drift windows for both engines.
-    # token_budget=104 packs ~two 48-token prompt chunks plus the 8
-    # decode lanes into every mixed step, so the chunked engine also
-    # wins on step COUNT (not just padded width) — that keeps the
-    # speedup structural in both the compute-bound and the
-    # dispatch-overhead-bound regime of this CPU.
-    mk = lambda **kw: GenerationEngine(  # noqa: E731
-        cfg, params, num_blocks=256, block_size=8, decode_width=8,
-        prefill_buckets="pow2:128", **kw)
-    two_eng = mk(prefill_chunk=0)
-    chk_eng = mk(prefill_chunk=48, token_budget=104)
-    two_eng.warmup()
-    chk_eng.warmup()
-    c0 = stat_get("STAT_generation_compile")
-    two_best = chk_best = None
-    for _ in range(4):
-        for eng, which in ((two_eng, "two"), (chk_eng, "chk")):
-            got = run_pass(eng)
-            if which == "two":
-                if two_best is None or got[0] < two_best[0]:
-                    two_best = got
-            else:
-                if chk_best is None or got[0] < chk_best[0]:
-                    chk_best = got
-    # a re-drain of the same stream must compile nothing, for either
-    # engine: one shared delta across all 8 measured passes
-    recompiles = int(stat_get("STAT_generation_compile") - c0)
-    two_rep, two_tokens = report(two_best)
-    chk_rep, chk_tokens = report(chk_best)
-    two_rep["steady_state_recompiles"] = recompiles
-    chk_rep["steady_state_recompiles"] = recompiles
-
-    parity = all(two_tokens[i] == expected[i].tokens
-                 and chk_tokens[i] == expected[i].tokens
-                 for i in range(R))
-
-    # --- stat_diff: mixed-step latency vs the previous run ----------
-    keep = lambda name: "generation" in name  # noqa: E731
-    snap = monitor.snapshot()
-    cur = {
-        "counters": {k: v for k, v in snap["counters"].items()
-                     if keep(k)},
-        "gauges": {},
-        "timers": {k: v for k, v in snap["timers"].items()
-                   if keep(k)},
-    }
-    snap_path = os.environ.get(
-        "PT_GENERATION_MIXED_BENCH_SNAPSHOT",
-        os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                     "bench_generation_mixed_last.json"))
-    regressions = []
-    try:
-        prev = stat_diff.load_snapshot(snap_path)
-        regressions = stat_diff.find_regressions(
-            stat_diff.diff_snapshots(prev, cur), threshold_pct=25.0)
-        regressions = [r for r in regressions if r.startswith("timer")]
-    except OSError:
-        pass  # first run: nothing to compare against
-    try:
-        os.makedirs(os.path.dirname(snap_path), exist_ok=True)
-        with open(snap_path, "w") as f:
-            json.dump(cur, f)
-    except OSError:
-        pass
-
-    speedup = round(chk_rep["tokens_per_sec"]
-                    / two_rep["tokens_per_sec"], 2)
-    return {
-        "workload": "decoder L%d-H%d: %d requests, prompts 65..73 "
-                    "(bucket 128), %d new tokens, width 8 chunk 48 "
-                    "budget 104" % (cfg.layers, cfg.hidden, R,
-                                    total_new),
-        "two_phase": two_rep,
-        "chunked": chk_rep,
-        "speedup_chunked_vs_two_phase": speedup,
-        "meets_1p3x": speedup >= 1.3,
-        "decode_tpot_p95_improved":
-            chk_rep["decode_tpot_us"]["p95"]
-            < two_rep["decode_tpot_us"]["p95"],
-        "tokens_bitwise_identical": bool(parity),
-        "mixed_step_p95_regressions": regressions,
-    }
-
-
 def bench_generation_prefix():
     """prefix-cache generation block (ISSUE 14, docs/generation.md):
     cache-on vs cache-off chunked engines over the SAME agent-style
@@ -1344,8 +1156,7 @@ def bench_generation_prefix():
     # state, which is exactly the serving regime the cache targets.
     mk = lambda **kw: GenerationEngine(  # noqa: E731
         cfg, params, num_blocks=256, block_size=8, decode_width=8,
-        prefill_buckets="pow2:128", prefill_chunk=48, token_budget=104,
-        **kw)
+        prefill_chunk=48, token_budget=104, **kw)
     off_eng = mk(prefix_cache=False)
     on_eng = mk(prefix_cache=True)
     off_eng.warmup()
@@ -1479,8 +1290,7 @@ def bench_generation_spec():
     # prefix cache off in both: this block isolates speculation
     mk = lambda **kw: GenerationEngine(  # noqa: E731
         cfg, params, num_blocks=256, block_size=8, decode_width=8,
-        prefill_buckets="pow2:128", prefill_chunk=48,
-        prefix_cache=False, **kw)
+        prefill_chunk=48, prefix_cache=False, **kw)
     plain_eng = mk(spec_tokens=0)
     spec_eng = mk(spec_tokens=3, draft="ngram")
     plain_eng.warmup()
@@ -1607,8 +1417,7 @@ def bench_quantized_serving():
 
     mk = lambda p, nb, **kw: GenerationEngine(  # noqa: E731
         cfg, p, num_blocks=int(nb), block_size=bs, decode_width=8,
-        prefill_buckets="pow2:128", prefill_chunk=48,
-        prefix_cache=False, **kw)
+        prefill_chunk=48, prefix_cache=False, **kw)
     f32_eng = mk(params, nb_f32)
     q_eng = mk(qparams, nb_i8, quant_mode="int8", kv_dtype="int8")
     cap_ratio = q_eng.kv_capacity_seqs() / max(
@@ -3174,8 +2983,8 @@ def bench_frontdoor():
                                            "int8")
         mk_engine = lambda: GenerationEngine(  # noqa: E731
             gcfg, gq, num_blocks=64, block_size=8, decode_width=4,
-            prefill_buckets="pow2:32", prefill_chunk=16,
-            prefix_cache=False, quant_mode="int8", kv_dtype="int8")
+            prefill_chunk=16, prefix_cache=False, quant_mode="int8",
+            kv_dtype="int8")
 
         rng = np.random.RandomState(7)
         feed = lambda b: [rng.rand(b, H_IN).astype(np.float32)]  # noqa: E731
@@ -3491,11 +3300,6 @@ def main():
         # paged-KV continuous batching (the KV-cache reuse win is real
         # on CPU too — ISSUE 5)
         rec["generation"] = bench_generation()
-    if not os.environ.get("PT_SKIP_GENERATION_MIXED_BENCH"):
-        # chunked prefill + ragged mixed step vs two-phase on a
-        # prompt-heavy mixed workload (HOL-blocking removal is real on
-        # CPU too — ISSUE 10)
-        rec["generation_mixed"] = bench_generation_mixed()
     if not os.environ.get("PT_SKIP_GENERATION_PREFIX_BENCH"):
         # cross-request prefix caching: TTFT with a warm cache vs cold
         # recompute of a shared system prompt (the prefill compute

@@ -213,7 +213,7 @@ def generation_candidates(defaults: CandidateForm,
     d = defaults
     out = [d]
     variants: List[CandidateForm] = []
-    if "prefill_chunk" not in pins and d.prefill_chunk > 0:
+    if "prefill_chunk" not in pins:
         variants += [d._replace(prefill_chunk=d.prefill_chunk * 4),
                      d._replace(prefill_chunk=d.prefill_chunk * 2),
                      d._replace(prefill_chunk=max(1, d.prefill_chunk // 2))]
@@ -285,8 +285,7 @@ def _probe_pass(eng, cfg, probe_tokens: int, seed: int):
     is blind to the chunked-prefill geometry it exists to search."""
     reqs = probe_requests(cfg, eng.decode_width, probe_tokens,
                           seed=seed)
-    limit = ((2 if eng.prefill_chunk else 1) * cfg.max_seq_len + 4) \
-        * max(1, len(reqs))
+    limit = (2 * cfg.max_seq_len + 4) * max(1, len(reqs))
     for r in reqs:
         eng.submit(r)
     results, steps = [], 0

@@ -105,20 +105,14 @@ _DEFS: Dict[str, Any] = {
     # every in-flight sequence (block 0 is a reserved scratch block for
     # inactive decode lanes). decode_width is the fixed width of the
     # continuous decode batch — sequences join/leave slots without
-    # changing the compiled shape. prefill_buckets is the prompt-length
-    # ladder (same grammar as FLAGS_predictor_shape_buckets); the
-    # prompt is right-padded to the bucket so prefill hits a small warm
-    # set of executables.
+    # changing the compiled shape.
     "FLAGS_generation_kv_blocks": 128,
     "FLAGS_generation_block_size": 16,
     "FLAGS_generation_decode_width": 8,
-    "FLAGS_generation_prefill_buckets": "pow2:512",
     # chunked prefill (PR 10, docs/generation.md "Chunked prefill"):
     # prompts stream through the SAME fixed-shape mixed step that
-    # advances decode lanes, prefill_chunk prompt tokens per step.
-    # 0 disables chunking and restores the two-phase bucketed-prefill
-    # engine (FLAGS_generation_prefill_buckets then matters again; in
-    # chunked mode it is a compat shim — see MIGRATION.md).
+    # advances decode lanes, prefill_chunk prompt tokens per step
+    # (at least 1: there is no other engine).
     # token_budget is the mixed batch's slot count (decode lanes +
     # prefill slots per step); 0 = auto (decode_width + prefill_chunk).
     "FLAGS_generation_prefill_chunk": 8,
@@ -127,7 +121,7 @@ _DEFS: Dict[str, Any] = {
     # caching"): chunk-aligned running-hash lookup of cached prompt
     # prefixes; hits attach the shared immutable KV blocks (refcounted,
     # copy-on-write on divergence) and start prefill at the first
-    # uncached chunk. Chunked mode only; token streams stay
+    # uncached chunk. Token streams stay
     # bitwise-identical to cache-off runs — only completion ORDER can
     # change (MIGRATION.md).
     "FLAGS_generation_prefix_cache": True,

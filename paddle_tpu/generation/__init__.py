@@ -5,11 +5,11 @@ Three pillars on top of the serving stack:
 - paged KV cache: `KVCacheManager` ledgers a fixed preallocated block
   pool (`FLAGS_generation_kv_blocks` x `FLAGS_generation_block_size`
   tokens per layer); sequences hold block tables, not buffers.
-- decode engine: `GenerationEngine` — bucketed prefill (PR-4 shape
-  ladder), fused single-token decode over the pool
+- decode engine: `GenerationEngine` — chunked prefill and
+  single-token decode in ONE mixed step over the pool
   (kernels/paged_attention.py), greedy/top-k/top-p samplers with
   per-sequence PRNG. Fixed shapes end to end: steady state replays
-  two compiled steps (prefill-at-bucket, decode) with zero recompiles.
+  that one compiled step with zero recompiles.
 - model families: `DecoderConfig` (the GPT block, model.py) and
   `LoopedDecoderConfig` (a stack run several times a token, looped.py)
   reach the engine through the same seam on the config object.

@@ -1,8 +1,7 @@
 """GenerationEngine: chunked prefill + fixed-shape mixed decode.
 
 The engine owns the device state (params, the per-layer K/V block
-pools) and a fixed-width decode batch of `decode_width` LANES. In the
-default CHUNKED mode (FLAGS_generation_prefill_chunk > 0, PR 10) every
+pools) and a fixed-width decode batch of `decode_width` LANES. Every
 step runs ONE compiled mixed executable over a fixed
 `token_budget`-slot batch: each decode lane contributes one slot (its
 next token), each prefilling lane contributes up to `prefill_chunk`
@@ -15,14 +14,16 @@ through the mixed step chunk by chunk WHILE other lanes keep decoding
 first token -> decode one token per step -> leaves at
 EOS/max_new_tokens, blocks freed, lane reusable.
 
-With FLAGS_generation_prefill_chunk = 0 the engine falls back to the
-PR-5 two-phase scheme: bucketed whole-prompt prefill
-(FLAGS_generation_prefill_buckets, one compiled prefill per ladder
-rung) followed by fixed-width fused decode. In chunked mode the ladder
-is a compat shim collapsed to [max_seq_len] — see MIGRATION.md.
+This is the only engine: `prefill_chunk`
+(FLAGS_generation_prefill_chunk) is a geometry value of at least 1.
+The step loop comes in two forms over the same compiled step:
+`_mixed_ahead` dispatches step n before it fetches step n-1's tokens
+(the default without speculation), `_mixed_once` waits for every
+step's tokens (speculation, and the tests' `lookahead=0`).
 
 MODEL FAMILIES (PR 29). The engine reaches a model through its config
-object alone: `cfg.forward_full` / `cfg.forward_paged`, the cache's
+object alone: `cfg.forward_paged` (and `cfg.forward_full`, which only
+the reference `NaiveGenerator` runs), the cache's
 geometry (`cfg.kv_layers` cache layers of rows `cfg.kv_row`, read in
 one place, `_pool_specs`), `cfg.max_seq_len` (the context cap the
 block tables are sized by), `cfg.meta()` for the fingerprints. The GPT
@@ -36,23 +37,23 @@ the dtype they arrive in.
 Fixed shapes everywhere mean the steady state replays exactly the warm
 executables: STAT_generation_compile counts engine-level compilations
 (tests pin it at zero across a mixed-length continuous stream), and
-when the persistent program cache (PR 1) is enabled the prefill/decode
-steps are exported through program_cache.exported_entry so even a
-fresh process skips retrace+recompile.
+when the persistent program cache (PR 1) is enabled the mixed and
+copy-on-write steps are exported through program_cache.exported_entry
+so even a fresh process skips retrace+recompile.
 
-PR 14 layers two latency features over the chunked mixed step, both
-preserving the bitwise-determinism contract:
+PR 14 layers two latency features over the mixed step, both keeping a
+request's token stream what it is without them:
 
-- PREFIX CACHE (FLAGS_generation_prefix_cache, chunked mode only):
+- PREFIX CACHE (FLAGS_generation_prefix_cache):
   admission asks the PrefixCache (kv_cache.py) for the longest cached
   chunk chain matching the new prompt and attaches those immutable
   blocks read-only (refcounted) — prefill starts at the first uncached
   chunk, so a shared-prefix fleet pays prefill once and TTFT collapses
   to ~one chunk. As a prompt streams in, every completed chunk
-  boundary is published back to the cache. Because K/V at a position
-  is a pure function of the tokens at or before it (row independence,
-  pinned in tests), a cached block is bitwise-identical to a cold
-  recompute — hit streams match cold streams exactly. Any write into
+  boundary is published back to the cache. K/V at a position
+  is a function of the tokens at or before it, so a cached block holds
+  what a cold recompute would write — tests/test_generation_prefix.py
+  holds hit streams to cold streams. Any write into
   a still-shared block (divergence after the common prefix, or a
   producer growing past a published partial block) goes through
   COPY-ON-WRITE first: the ledger swaps in a private block and a
@@ -86,7 +87,7 @@ _tokens / _prefills / _evictions / _compile / _errors,
 STAT_generation_prefix_{hits,misses,hit_tokens,cow_copies} /
 _spec_{proposed,accepted} / _draft_faults,
 GAUGE_generation_active_seqs (+ kv_cache block + prefix gauges),
-TIMER_generation_prefill_us / _decode_step_us / _prefix_admit_us.
+TIMER_generation_mixed_step_us / _decode_step_us / _prefix_admit_us.
 
 Request tracing (tracing.py, docs/observability.md): every request
 carries a RequestTrace (opened by GenerationPool.submit, or by
@@ -116,7 +117,7 @@ from ..failpoints import failpoint
 from .. import flags as _flags
 from ..flags import get_flag
 from ..kernels.paged_attention import kernel_form as _kernel_form
-from ..inference import bucket_for, bucket_or_exact, parse_bucket_ladder
+from ..inference import bucket_for, parse_bucket_ladder
 from ..monitor import gauge_set, stat_add, timer_observe
 from .kv_cache import (TRASH_BLOCK, BlockPoolExhausted, KVCacheManager,
                        PrefixCache)
@@ -191,7 +192,6 @@ class GenerationEngine:
                  num_blocks: Optional[int] = None,
                  block_size: Optional[int] = None,
                  decode_width: Optional[int] = None,
-                 prefill_buckets=None,
                  prefill_chunk: Optional[int] = None,
                  token_budget: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -314,87 +314,61 @@ class GenerationEngine:
         bs = _knob("block_size", "FLAGS_generation_block_size", int)
         self.prefill_chunk = _knob(
             "prefill_chunk", "FLAGS_generation_prefill_chunk", int)
-        if self.prefill_chunk < 0:
-            raise ValueError("prefill_chunk must be >= 0")
-        tb_raw = _knob("token_budget",
-                       "FLAGS_generation_token_budget", int)
-        # geometry-dependent validations, deferred to the RESOLVED
-        # chunk (a policy entry always keeps chunk > 0 when it was
-        # tuned with spec/quantized KV on, but pins can force it)
-        if self.spec_tokens and not self.prefill_chunk:
+        if self.prefill_chunk < 1:
             raise ValueError(
-                "speculative decoding rides the chunked mixed step — "
-                "FLAGS_generation_spec_tokens needs "
-                "FLAGS_generation_prefill_chunk > 0")
-        if self.kv_dtype != "fp32" and not self.prefill_chunk:
-            raise ValueError(
-                "a KV pool narrower than float32 rides the chunked "
-                "mixed step — FLAGS_generation_kv_quant=%s needs "
-                "FLAGS_generation_prefill_chunk > 0" % self.kv_dtype)
+                "prefill_chunk must be >= 1: the two-phase engine "
+                "(bucketed prefill + decode, prefill_chunk=0) is gone "
+                "— every prompt streams through the mixed step")
+        tb = _knob("token_budget", "FLAGS_generation_token_budget", int)
         # One step ahead of the host (_mixed_ahead) wherever the step
-        # allows it: the chunked mixed step without speculation (a
-        # draft is verified against tokens the host has not seen yet).
-        # No flag: the argument is for tests, which hold the streams
-        # with it to the streams without.
-        can_look = bool(self.prefill_chunk) and not self.spec_tokens
+        # allows it: without speculation (a draft is verified against
+        # tokens the host has not seen yet). No flag: the argument is
+        # for tests, which hold the streams with it to the streams
+        # without.
+        can_look = not self.spec_tokens
         self.lookahead = int(can_look if lookahead is None else lookahead)
         if self.lookahead not in (0, 1):
             raise ValueError("lookahead must be 0 or 1")
         if self.lookahead and not can_look:
             raise ValueError(
-                "lookahead dispatches the chunked mixed step ahead of "
-                "the tokens it feeds on — it needs "
-                "FLAGS_generation_prefill_chunk > 0 and "
+                "lookahead dispatches the mixed step ahead of the "
+                "tokens it feeds on — it needs "
                 "FLAGS_generation_spec_tokens 0")
-        if self.prefill_chunk:
-            # chunked mode: prompts stream through the mixed step, so
-            # the bucket ladder is a compat shim with one rung
-            # (MIGRATION.md) — submit still validates against it
-            self.prefill_ladder = [cfg.max_seq_len]
-            tb = int(tb_raw)
-            # auto budget leaves room for every lane's k draft slots
-            # so speculation never starves prefill chunks
-            self.token_budget = (
-                tb if tb > 0 else
-                self.decode_width * (1 + self.spec_tokens)
-                + self.prefill_chunk)
-            if self.token_budget < self.decode_width:
-                raise ValueError(
-                    "token_budget %d < decode_width %d: every decode "
-                    "lane needs a slot each step" % (self.token_budget,
-                                                     self.decode_width))
-            # sampler rows: 1 + k per lane (a lane's plain-decode slot
-            # plus its verify slots) — the mixed fn gathers these out
-            # of the t-slot logits so the sampler's sort never runs on
-            # prompt/padding slots; with spec off this is exactly the
-            # PR-10 per-lane sampler cost
-            self.sample_width = self.decode_width * (1 + self.spec_tokens)
-        else:
-            self.token_budget = self.decode_width
-            self.sample_width = self.decode_width
-            spec = (prefill_buckets if prefill_buckets is not None
-                    else get_flag("FLAGS_generation_prefill_buckets"))
-            self.prefill_ladder = [b for b in parse_bucket_ladder(spec)
-                                   if b <= cfg.max_seq_len]
-            if not self.prefill_ladder:
-                self.prefill_ladder = [cfg.max_seq_len]
+        # auto budget leaves room for every lane's k draft slots so
+        # speculation never starves prefill chunks
+        self.token_budget = (
+            tb if tb > 0 else
+            self.decode_width * (1 + self.spec_tokens)
+            + self.prefill_chunk)
+        if self.token_budget < self.decode_width:
+            raise ValueError(
+                "token_budget %d < decode_width %d: every decode "
+                "lane needs a slot each step" % (self.token_budget,
+                                                 self.decode_width))
+        # sampler rows: 1 + k per lane (a lane's plain-decode slot
+        # plus its verify slots) — the mixed fn gathers these out of
+        # the t-slot logits so the sampler's sort never runs on
+        # prompt/padding slots; with spec off this is exactly the
+        # PR-10 per-lane sampler cost
+        self.sample_width = self.decode_width * (1 + self.spec_tokens)
         self.kv = KVCacheManager(nb, bs)
         # table width: enough blocks for a max-length context
         self.max_blocks_per_seq = self.kv.blocks_for_tokens(
             cfg.max_seq_len)
-        # fixed attention lane count shared by prefill and decode —
-        # the bitwise-parity requirement (model.forward_full docstring)
+        # the key axis of a lane's paged view. NaiveGenerator takes it
+        # (model.forward_full, `attn_lanes`) so that the oracle attends
+        # over an axis as wide as the engine's: the token-stream tests
+        # (tests/test_generation.py) compare the two
         self.attn_lanes = self.max_blocks_per_seq * bs
         # device pools: made below by _restore_pools, once the draft
         # model's config is known (it makes whichever are missing)
         self.k_pools = self.v_pools = None
         self.k_scales = self.v_scales = None
-        # cross-request prefix cache (chunked mode only: the chunk is
-        # the hash unit)
+        # cross-request prefix cache (the chunk is the hash unit)
         pc_on = bool(prefix_cache if prefix_cache is not None
                      else get_flag("FLAGS_generation_prefix_cache"))
         self.prefix_cache = (PrefixCache(self.kv, self.prefill_chunk)
-                             if pc_on and self.prefill_chunk else None)
+                             if pc_on else None)
         # drafter for speculative decoding: "ngram" is a host-side
         # prompt-lookup (zero device cost); "model" runs a small draft
         # decoder over its OWN paged pools indexed by the same tables
@@ -429,7 +403,6 @@ class GenerationEngine:
         w = self.decode_width
         self._lane_seq: List[Optional[_Seq]] = [None] * w
         self._tables = np.zeros((w, self.max_blocks_per_seq), np.int32)
-        self._ctx = np.zeros((w,), np.int32)
         self._temps = np.zeros((w,), np.float32)
         self._top_ks = np.zeros((w,), np.int32)
         self._top_ps = np.ones((w,), np.float32)
@@ -557,54 +530,20 @@ class GenerationEngine:
 
     # --- compiled-step registry ---------------------------------------
 
-    def _get_fn(self, kind: str, bucket: int = 0):
-        key = (kind, bucket)
-        fn = self._fns.get(key)
+    def _get_fn(self, kind: str):
+        fn = self._fns.get(kind)
         if fn is not None:
             return fn
         with _kernel_form(self.kernel):
-            fn = self._build_fn(kind, bucket)
-        self._fns[key] = fn
+            fn = self._build_fn(kind)
+        self._fns[kind] = fn
         return fn
 
-    def _build_fn(self, kind: str, bucket: int):
+    def _build_fn(self, kind: str):
         stat_add("STAT_generation_compile")
         cfg = self.cfg
-        if kind == "prefill":
-            lanes = self.attn_lanes
-
-            def raw(params, tokens, lengths):
-                return cfg.forward_full(params, tokens, lengths,
-                                        attn_lanes=lanes)
-            avals = (
-                jax.tree.map(_sds, self.params),
-                jax.ShapeDtypeStruct((1, bucket), jnp.int32),
-                jax.ShapeDtypeStruct((1,), jnp.int32),
-            )
-        elif kind == "decode":
-            def raw(params, kp, vp, tables, ctx, tokens, temps, tks,
-                    tps, seeds, steps):
-                logits, kp2, vp2 = cfg.forward_paged(
-                    params, kp, vp, tables, ctx, tokens)
-                nxt = sample_tokens(logits, temps, tks, tps, seeds,
-                                    steps)
-                return nxt, kp2, vp2
-            w, m = self.decode_width, self.max_blocks_per_seq
-            i32 = jnp.int32
-            avals = (
-                jax.tree.map(_sds, self.params),
-                _sds(self.k_pools), _sds(self.v_pools),
-                jax.ShapeDtypeStruct((w, m), i32),
-                jax.ShapeDtypeStruct((w,), i32),
-                jax.ShapeDtypeStruct((w,), i32),
-                jax.ShapeDtypeStruct((w,), jnp.float32),
-                jax.ShapeDtypeStruct((w,), i32),
-                jax.ShapeDtypeStruct((w,), jnp.float32),
-                jax.ShapeDtypeStruct((w,), i32),
-                jax.ShapeDtypeStruct((w,), i32),
-            )
-        elif kind == "mixed":
-            # ONE executable for every step of the chunked engine: T =
+        if kind == "mixed":
+            # ONE executable for every step of the engine: T =
             # token_budget SLOTS of (block-table row, position, token)
             # — a decode lane's next token, one of its k draft tokens
             # to verify, or one prompt token of a prefill chunk;
@@ -707,16 +646,14 @@ class GenerationEngine:
             )
         else:
             raise ValueError(kind)
-        return self._aot_or_jit(kind, bucket, raw, avals)
+        return self._aot_or_jit(kind, raw, avals)
 
     def _program_pools(self, kind: str) -> tuple:
         """Names of the pools `kind`'s program takes, writes and
         returns, in the order of its arguments and results."""
-        if kind == "prefill":
-            return ()                     # returns fresh K/V, no pools
         if kind.startswith("draft"):
             return ("dk_pools", "dv_pools")
-        if self.k_scales is not None and kind != "decode":
+        if self.k_scales is not None:
             return ("k_pools", "v_pools", "k_scales", "v_scales")
         return ("k_pools", "v_pools")
 
@@ -727,18 +664,17 @@ class GenerationEngine:
         first = 0 if kind.endswith("cow") else 1
         return tuple(range(first, first + len(self._program_pools(kind))))
 
-    def _aot_or_jit(self, kind: str, bucket: int, raw, avals):
+    def _aot_or_jit(self, kind: str, raw, avals):
         """Route the step through the persistent AOT program cache
         (PR 1) when a cache dir resolves; plain jit otherwise. Both
         paths register with the XLA program accounting registry
-        (core/program_accounting.py) so /programz shows every prefill
-        bucket and the decode step with compile-time flops/bytes.
+        (core/program_accounting.py) so /programz shows every step
+        with compile-time flops/bytes.
         Every program that returns new pools DONATES the old ones, on
         both paths, so the update happens in the arrays the engine
         holds: after a call the pool arrays passed in are dead and the
         caller keeps the ones returned."""
-        tag = ("generation_prefill_b%d" % bucket if kind == "prefill"
-               else "generation_%s" % kind)
+        tag = "generation_%s" % kind
         base = (self.draft_cfg.meta() if kind.startswith("draft")
                 else self.cfg.meta())
         # v=4: ISSUE-16 adaptive dispatch — kern is the RESOLVED
@@ -761,7 +697,7 @@ class GenerationEngine:
         # step takes the previous step's samples and `feed_rows`.
         weights = (self.draft_params if kind.startswith("draft")
                    else self.params)
-        meta = dict(base, kind=kind, bucket=bucket, v=7,
+        meta = dict(base, kind=kind, v=7,
                     wdt=sorted({str(w.dtype)
                                 for w in jax.tree.leaves(weights)}),
                     blocks=self.kv.num_blocks,
@@ -795,71 +731,35 @@ class GenerationEngine:
             key=program_accounting.key_token(sorted(meta.items())),
             meta=meta)
 
-    def warmup(self, buckets=None) -> dict:
-        """Compile-ahead. Chunked mode warms the ONE mixed-step
-        executable (there is nothing else to compile — the collapsed
-        ladder never runs); two-phase mode warms the decode step plus
-        every prefill bucket (or the given subset). Steady state then
-        never compiles. The engine's resolved kernel form is pinned
-        for anything traced here (the rare accounted-compile fallback
-        traces at first call, inside this block)."""
+    def warmup(self) -> dict:
+        """Compile-ahead: the ONE mixed-step executable, the
+        copy-on-write clone where the prefix cache is on, the draft
+        model's two where it drafts. Steady state then never compiles.
+        The engine's resolved kernel form is pinned for anything
+        traced here (the rare accounted-compile fallback traces at
+        first call, inside this block)."""
         with _kernel_form(self.kernel):
-            return self._warmup_inner(buckets)
+            return self._warmup_inner()
 
-    def _warmup_inner(self, buckets=None) -> dict:
+    def _warmup_inner(self) -> dict:
         report = {}
-        if self.prefill_chunk:
-            t0 = time.perf_counter()
-            self._warm_mixed()
-            report["mixed"] = round(time.perf_counter() - t0, 4)
-            if self.prefix_cache is not None:
-                # the COW copy must be warm too: the first write into a
-                # shared block happens in steady state, and the
-                # zero-steady-state-recompile pin counts it
-                t0 = time.perf_counter()
-                self._warm_cow("cow")
-                report["cow"] = round(time.perf_counter() - t0, 4)
-            if self.draft_params is not None:
-                t0 = time.perf_counter()
-                self._warm_draft()
-                self._warm_cow("draft_cow")
-                report["draft"] = round(time.perf_counter() - t0, 4)
-            self._warmed = True
-            return report
         t0 = time.perf_counter()
-        self._warm_decode()
-        report["decode"] = round(time.perf_counter() - t0, 4)
-        for b in sorted(set(buckets) if buckets is not None
-                        else self.prefill_ladder):
+        self._warm_mixed()
+        report["mixed"] = round(time.perf_counter() - t0, 4)
+        if self.prefix_cache is not None:
+            # the COW copy must be warm too: the first write into a
+            # shared block happens in steady state, and the
+            # zero-steady-state-recompile pin counts it
             t0 = time.perf_counter()
-            self._warm_prefill(int(b))
-            report[int(b)] = round(time.perf_counter() - t0, 4)
+            self._warm_cow("cow")
+            report["cow"] = round(time.perf_counter() - t0, 4)
+        if self.draft_params is not None:
+            t0 = time.perf_counter()
+            self._warm_draft()
+            self._warm_cow("draft_cow")
+            report["draft"] = round(time.perf_counter() - t0, 4)
         self._warmed = True
         return report
-
-    def _warm_prefill(self, bucket: int) -> None:
-        fn = self._get_fn("prefill", bucket)
-        _, kc, vc = fn(self.params, jnp.zeros((1, bucket), jnp.int32),
-                       jnp.ones((1,), jnp.int32))
-        # the cache scatter is an eager op with bucket-shaped index
-        # arrays — compile it now too (into the trash block, harmless)
-        bs = self.kv.block_size
-        blk = np.zeros(bucket, np.int32)  # TRASH_BLOCK
-        off = (np.arange(bucket) % bs).astype(np.int32)
-        rows = (self.cfg.kv_layers, bucket, self.cfg.kv_row)
-        self.k_pools = self.k_pools.at[:, blk, off].set(
-            kc[:, 0].reshape(rows))
-        self.v_pools = self.v_pools.at[:, blk, off].set(
-            vc[:, 0].reshape(rows))
-
-    def _warm_decode(self) -> None:
-        w = self.decode_width
-        z = jnp.zeros((w,), jnp.int32)
-        # every lane parked on the trash block
-        self._run("decode",
-                  jnp.zeros((w, self.max_blocks_per_seq), jnp.int32), z, z,
-                  jnp.zeros((w,), jnp.float32), z,
-                  jnp.ones((w,), jnp.float32), z, z)
 
     def _warm_mixed(self) -> None:
         t, sw = self.token_budget, self.sample_width
@@ -880,7 +780,7 @@ class GenerationEngine:
     def _run(self, kind: str, *rest):
         """Run `kind`'s compiled program on the pools it writes and
         KEEP the pools it returns: the arrays passed in are donated
-        and dead after the call. `mixed`, `decode` and `draft_mixed`
+        and dead after the call. `mixed` and `draft_mixed`
         take (weights, *pools, *rest) and return (result, *pools): the
         result is handed back; `cow` and `draft_cow` take (*pools,
         *rest) and return the pools alone."""
@@ -920,10 +820,6 @@ class GenerationEngine:
                 "prompt (%d) + max_new_tokens (%d) exceeds max_seq_len "
                 "%d" % (len(prompt), req.max_new_tokens,
                         self.cfg.max_seq_len))
-        if bucket_for(len(prompt), self.prefill_ladder) is None:
-            raise ValueError(
-                "prompt length %d overflows the prefill ladder %r"
-                % (len(prompt), self.prefill_ladder))
         if self.kv.blocks_for_tokens(total) > self.kv.num_blocks - 1:
             raise ValueError(
                 "request needs %d blocks but the pool only has %d "
@@ -958,7 +854,7 @@ class GenerationEngine:
 
     def step(self) -> List[GenerationResult]:
         """One scheduler tick: admit pending requests into free lanes,
-        advance every active lane (one mixed or decode batch), retire
+        advance every active lane (one mixed batch), retire
         finished sequences. Returns the finished results (possibly
         empty)."""
         # host spans on the profiler's clock (telemetry.py): the five
@@ -973,9 +869,7 @@ class GenerationEngine:
                 return []
             if self.lookahead:
                 return self._mixed_ahead()
-            if self.prefill_chunk:
-                return self._mixed_once()
-            return self._decode_once()
+            return self._mixed_once()
 
     def _admit(self) -> None:
         """Admit pending requests into free lanes, oldest first (the
@@ -998,10 +892,7 @@ class GenerationEngine:
                 continue
             seq = self._pending[0]
             try:
-                ok = (self._admit_chunked(seq, lane)
-                      if self.prefill_chunk
-                      else self._prefill_into(seq, lane))
-                if not ok:
+                if not self._admit_chunked(seq, lane):
                     break                      # pool full: try later
             except Exception as e:
                 if seq.evictions and \
@@ -1072,7 +963,6 @@ class GenerationEngine:
         self._lane_seq[lane] = seq
         sp = seq.req.sampling
         self._tables[lane] = self.kv.table(sid, self.max_blocks_per_seq)
-        self._ctx[lane] = cached_use
         self._temps[lane] = sp.temperature
         self._top_ks[lane] = sp.top_k
         self._top_ps[lane] = sp.top_p
@@ -1092,98 +982,8 @@ class GenerationEngine:
         stat_add("STAT_generation_prefills")
         return True
 
-    def _prefill_into(self, seq: _Seq, lane: int) -> bool:
-        """Run bucketed prefill for `seq` and park it in `lane`.
-        Returns False (untouched state) when the pool can't hold the
-        prompt right now."""
-        prompt = seq.req.prompt
-        n = len(prompt)
-        need = self.kv.blocks_for_tokens(n + 1)  # room for 1st decode
-        if need > self.kv.free_blocks:
-            return False
-        # before any state mutation: an injected raise leaves the
-        # engine consistent (the request is still pending; _admit's
-        # per-request isolation turns it into a delivered error)
-        failpoint("generation.prefill")
-        tr = seq.req.trace
-        tr.stage("prefill_start")
-        if seq.evictions:
-            tr.event("replay", evictions=seq.evictions)
-        # pad accounting (STAT_generation_pad_tokens): the bucketed
-        # prefill pays bucket - n wasted token slots — the waste the
-        # chunked/ragged path exists to remove
-        bucket = bucket_or_exact(n, self.prefill_ladder,
-                                 pad_stat="STAT_generation_pad_tokens")
-        t0 = time.perf_counter()
-        with _tm.trace_scope(tr.trace_id), \
-                _tm.span("pt/engine/prefill", track="generation"):
-            fn = self._get_fn("prefill", bucket)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :n] = prompt
-            logits, kc, vc = fn(self.params, jnp.asarray(toks),
-                                jnp.asarray([n], np.int32))
-            sid = id(seq)
-            self.kv.alloc(sid, need)
-            table = self.kv.table(sid, self.max_blocks_per_seq)
-            # scatter the prefill K/V into the pool: positions 0..n-1
-            # land at (table[pos//bs], pos%bs). The index arrays span
-            # the whole BUCKET, not just n — a length-n scatter would
-            # compile once per distinct prompt length (measured ~80ms
-            # each on CPU), a bucket-length one compiles once per
-            # ladder rung. Pad positions land in the trash block (via
-            # the trash-padded table) or in allocated-but-unwritten
-            # slots; neither is ever visible (the position mask only
-            # exposes slots the decode loop has since overwritten).
-            bs = self.kv.block_size
-            pos = np.arange(bucket)
-            tbl = np.asarray(table, np.int32)
-            blk = tbl[np.minimum(pos // bs, len(tbl) - 1)]
-            off = (pos % bs).astype(np.int32)
-            rows = (self.cfg.kv_layers, bucket, self.cfg.kv_row)
-            self.k_pools = self.k_pools.at[:, blk, off].set(
-                kc[:, 0, :bucket].reshape(rows))
-            self.v_pools = self.v_pools.at[:, blk, off].set(
-                vc[:, 0, :bucket].reshape(rows))
-        timer_observe("TIMER_generation_prefill_us",
-                      (time.perf_counter() - t0) * 1e6)
-        stat_add("STAT_generation_prefills")
-        # the prompt's "next token" comes from the prefill logits: feed
-        # it to the first decode step via the sampler's step counter 0
-        first = self._sample_host(seq, np.asarray(logits)[0], step=0)
-        # TTFT lands here (first call only — a preemption replay keeps
-        # the original first-token time; replays re-observe TPOT)
-        tr.token()
-        seq.generated.append(first)
-        seq.ctx = n
-        seq.lane = lane
-        seq.t_last_token = time.perf_counter()
-        self._lane_seq[lane] = seq
-        sp = seq.req.sampling
-        self._tables[lane] = table
-        self._ctx[lane] = n
-        self._temps[lane] = sp.temperature
-        self._top_ks[lane] = sp.top_k
-        self._top_ps[lane] = sp.top_p
-        self._seeds[lane] = sp.seed
-        stat_add("STAT_generation_tokens")
-        return True
-
-    def _sample_host(self, seq: _Seq, logits_row: np.ndarray,
-                     step: int) -> int:
-        """Sample ONE token outside the decode batch (prefill's first
-        token) — same vmapped sampler as the decode step, width-1, so
-        the token stream is identical to an all-device run."""
-        out = sample_tokens(
-            jnp.asarray(logits_row)[None],
-            jnp.asarray([seq.req.sampling.temperature], jnp.float32),
-            jnp.asarray([seq.req.sampling.top_k], jnp.int32),
-            jnp.asarray([seq.req.sampling.top_p], jnp.float32),
-            jnp.asarray([seq.req.sampling.seed], jnp.int32),
-            jnp.asarray([step], jnp.int32))
-        return int(np.asarray(out)[0])
-
     def _mixed_once(self) -> List[GenerationResult]:
-        """One MIXED step (chunked mode): assemble up to token_budget
+        """One MIXED step: assemble up to token_budget
         slots — every decoding lane's next token first (decode never
         waits on a prefill: the no-head-of-line-blocking contract),
         then up to prefill_chunk prompt tokens per prefilling lane in
@@ -1411,7 +1211,6 @@ class GenerationEngine:
                 for j in range(s + 1):
                     tok = int(nxt[row0 + j])
                     seq.ctx += 1
-                    self._ctx[ln] = seq.ctx
                     seq.generated.append(tok)
                     seq.req.trace.token()
                     timer_observe("TIMER_generation_inter_token_us",
@@ -1431,15 +1230,14 @@ class GenerationEngine:
             for ln, seq, start, take in chunk_plan:
                 seq.prefilled = start + take
                 seq.ctx = seq.prefilled
-                self._ctx[ln] = seq.ctx
                 seq.req.trace.event("prefill_chunk", start=start,
                                     width=take)
                 self._publish_prefix(seq)
                 if seq.prefilled == len(seq.req.prompt):
                     # final chunk: its last slot's logits sampled the first
                     # generated token through the lane's sampler row 0
-                    # (step 0 — identical fold_in to the two-phase prefill,
-                    # so streams match bitwise).
+                    # (step 0: the fold_in NaiveGenerator's first
+                    # sample takes, so the streams match).
                     seq.generated.append(int(nxt[ln * rpl]))
                     # TTFT lands at the TRUE first sampled token (first
                     # token() call only; replays re-observe TPOT)
@@ -1505,14 +1303,12 @@ class GenerationEngine:
             if self._lane_seq[ln] is not seq:
                 continue
             seq.ctx += 1
-            self._ctx[ln] = seq.ctx
             seq.pending += 1
         for ln, seq, start, take in chunk_plan:
             if self._lane_seq[ln] is not seq:
                 continue
             seq.prefilled = start + take
             seq.ctx = seq.prefilled
-            self._ctx[ln] = seq.ctx
             seq.req.trace.event("prefill_chunk", start=start, width=take)
             self._publish_prefix(seq)
             if seq.prefilled == len(seq.req.prompt):
@@ -1734,77 +1530,6 @@ class GenerationEngine:
             pc.insert(key, tokens_b, blocks)
             seq.published = tokens_b
 
-    def _decode_once(self) -> List[GenerationResult]:
-        """Advance all active lanes one token (inactive lanes spin on
-        the trash block)."""
-        with _tm.span("pt/engine/plan", track="generation"):
-            # before the retire loop and any lane mutation: a caller that
-            # catches the InjectedFault can call step() again and the batch
-            # resumes exactly where it was (basis of the replay-under-fault
-            # determinism test)
-            failpoint("generation.decode")
-            finished: List[GenerationResult] = []
-            # retire sequences whose PREVIOUS token already terminated them
-            for lane, seq in enumerate(self._lane_seq):
-                if seq is None:
-                    continue
-                done = self._finish_reason(seq)
-                if done is not None:
-                    finished.append(self._retire(lane, done))
-            self._ensure_blocks()
-            w = self.decode_width
-            tokens = np.zeros((w,), np.int32)
-            steps = np.zeros((w,), np.int32)
-            active = [ln for ln, s in enumerate(self._lane_seq)
-                      if s is not None]
-            if not active:
-                gauge_set("GAUGE_generation_active_seqs", 0)
-                return finished
-            # idle lanes ride the fixed-width batch as padding
-            stat_add("STAT_generation_pad_tokens", w - len(active))
-            if (self._temps > 0).any():
-                stat_add("STAT_generation_sampler_filter_steps")
-            for ln in active:
-                seq = self._lane_seq[ln]
-                tokens[ln] = seq.generated[-1]
-                steps[ln] = len(seq.generated)
-        t0 = time.perf_counter()
-        # chrome-trace lanes carry which requests rode this step; the
-        # join only matters (and only costs) when telemetry is on
-        tids = ",".join(
-            t for t in (self._lane_seq[ln].req.trace.trace_id
-                        for ln in active) if t) \
-            if _tm.enabled() else None
-        with _tm.trace_scope(tids):
-            with _tm.span("pt/engine/dispatch", track="generation"):
-                nxt = self._run(
-                    "decode", jnp.asarray(self._tables),
-                    jnp.asarray(self._ctx), jnp.asarray(tokens),
-                    jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                    jnp.asarray(self._top_ps), jnp.asarray(self._seeds),
-                    jnp.asarray(steps))
-            with _tm.span("pt/engine/fetch", track="generation"):
-                nxt = np.asarray(nxt)
-        timer_observe("TIMER_generation_decode_step_us",
-                      (time.perf_counter() - t0) * 1e6)
-        with _tm.span("pt/engine/emit", track="generation"):
-            now = time.perf_counter()
-            for ln in active:
-                seq = self._lane_seq[ln]
-                seq.ctx += 1
-                self._ctx[ln] = seq.ctx
-                seq.generated.append(int(nxt[ln]))
-                seq.req.trace.token()
-                timer_observe("TIMER_generation_inter_token_us",
-                              (now - seq.t_last_token) * 1e6)
-                seq.t_last_token = now
-                stat_add("STAT_generation_tokens")
-                done = self._finish_reason(seq)
-                if done is not None:
-                    finished.append(self._retire(ln, done))
-            gauge_set("GAUGE_generation_active_seqs", self.active_count)
-        return finished
-
     def _finish_reason(self, seq: _Seq) -> Optional[str]:
         eos = seq.req.eos_token
         if eos is not None and seq.generated and \
@@ -1819,7 +1544,6 @@ class GenerationEngine:
         self._lane_seq[lane] = None
         self.kv.free(id(seq))
         self._tables[lane] = TRASH_BLOCK
-        self._ctx[lane] = 0
         self._temps[lane] = 0.0      # an idle lane is a greedy row
         toks = list(seq.generated)
         if reason == "eos":
@@ -1831,27 +1555,6 @@ class GenerationEngine:
             request_id=seq.req.request_id,
             prompt_len=len(seq.req.prompt), tokens=toks,
             finish_reason=reason, evictions=seq.evictions)
-
-    def _ensure_blocks(self) -> None:
-        """Before a decode step, every active lane whose NEXT write
-        position crosses into an unowned block gets one more block.
-        Pool empty -> preempt the youngest sequence (deterministic
-        replay) until the survivors fit."""
-        while True:
-            try:
-                for lane, seq in enumerate(self._lane_seq):
-                    if seq is None:
-                        continue
-                    sid = id(seq)
-                    need = self.kv.blocks_for_tokens(seq.ctx + 1)
-                    while len(self.kv.owned(sid)) < need:
-                        self.kv.extend(sid)
-                        self._tables[lane] = self.kv.table(
-                            sid, self.max_blocks_per_seq)
-                return
-            except BlockPoolExhausted:
-                if not self._preempt_youngest():
-                    raise
 
     def _preempt_youngest(self) -> bool:
         """Evict the most recently admitted active sequence: free its
@@ -1871,7 +1574,6 @@ class GenerationEngine:
         self._lane_seq[lane] = None
         self.kv.evict(id(cand))
         self._tables[lane] = TRASH_BLOCK
-        self._ctx[lane] = 0
         self._temps[lane] = 0.0
         cand.req.trace.event("preempt", lane=lane,
                              ctx=int(cand.ctx),
@@ -1904,11 +1606,9 @@ class GenerationEngine:
             self.submit(r)
         out: List[GenerationResult] = []
         steps = 0
-        # chunked mode spends up to ceil(prompt/chunk) extra steps per
-        # request streaming the prompt in — double the per-request
-        # allowance so long prompts converge
-        per_req = ((2 if self.prefill_chunk else 1)
-                   * self.cfg.max_seq_len + 4)
+        # up to ceil(prompt/chunk) steps per request stream the prompt
+        # in before its max_new_tokens decode steps
+        per_req = 2 * self.cfg.max_seq_len + 4
         limit = (max_steps if max_steps is not None
                  else per_req * max(1, len(reqs)))
         while not self.idle and steps < limit:
@@ -1967,16 +1667,14 @@ class NaiveGenerator:
     functions, same sampler, same bucketing of the growing context —
     so its token streams are comparable and its cost is honest."""
 
-    def __init__(self, cfg: DecoderConfig, params, buckets=None,
+    def __init__(self, cfg: DecoderConfig, params, buckets="pow2:512",
                  attn_lanes: int = 0):
         self.cfg = cfg
         self.params = jax.tree.map(jnp.asarray, params)
-        spec = (buckets if buckets is not None
-                else get_flag("FLAGS_generation_prefill_buckets"))
-        self.ladder = [b for b in parse_bucket_ladder(spec)
+        self.ladder = [b for b in parse_bucket_ladder(buckets)
                        if b <= cfg.max_seq_len] or [cfg.max_seq_len]
-        # pass the paged engine's attn_lanes to make this oracle
-        # bitwise-comparable (model.forward_full docstring)
+        # the paged engine's attn_lanes: the oracle's key axis as
+        # wide as the engine's view (model.forward_full docstring)
         self.attn_lanes = int(attn_lanes)
         self._fns: Dict[int, Any] = {}
 

@@ -275,7 +275,9 @@ def forward_full(cfg: LoopedDecoderConfig, params: dict, tokens,
     `[B, S]`, lengths `[B]` -> (logits `[B, vocab]` at position
     lengths-1, k_cache, v_cache each `[kv_layers, B, S, kv_heads,
     head_dim]`). `attn_lanes` pads the attention's key axis to the
-    paged path's lane count (same reason as there)."""
+    paged path's lane count, so that the streams the tests compare
+    agree (same reason as there; tests/test_generation_looped.py::
+    test_engine_streams_equal_the_naive_generators)."""
     b, s = tokens.shape
     pos = jnp.arange(s, dtype=jnp.int32)
     x = params["tok_emb"][tokens].astype(jnp.float32)

@@ -2,21 +2,27 @@
 
 A small GPT-style pre-LN transformer expressed as (config, params dict,
 forward functions) — no layers framework, no Program: the generation
-subsystem needs a model whose full-context and paged-incremental
-forwards can be proven BITWISE equal, so both are written here against
-the same primitive ops in the same order.
+subsystem needs a model whose full-context forward is the reference
+for its paged-incremental one, so both are written here against the
+same primitive ops in the same order.
 
-The parity contract (tests/test_generation.py pins it):
+The parity contract:
 
     forward_full(tokens[:, :t+1]) logits at position t
-        == forward_paged(token t, pools holding positions 0..t-1)
+        ~= forward_paged(token t, pools holding positions 0..t-1)
 
-and it holds bitwise on XLA:CPU because (a) both paths route attention
+to within rounding, not bit for bit: both paths route attention
 through kernels.paged_attention.attend_reference (same einsums, same
-finite NEG_INF masking — padded/masked lanes contribute exact 0.0),
-(b) per-position work (LN, QKV/MLP matmuls) is row-independent on this
-backend (tests/test_serving.py pins row independence for the same
-reason), and (c) everything runs float32.
+finite NEG_INF masking — padded/masked lanes contribute exact 0.0)
+over a key axis of the same width (`attn_lanes`) and run float32, but
+a backend picks a matmul's tiling from the batch's shape, so the last
+bits of a row move with its batch (a few ULP on XLA:CPU; logit gaps of
+4.7e-3 and 0.011 between the kernel forms on the chip, PERF.md). Held
+by tests/test_generation.py::test_paged_decode_bitwise_parity_every_step
+and tests/test_kernels.py::
+test_chunked_prefill_mixed_batch_bitwise_vs_forward_full (a tolerance
+ten times the largest gap measured, and a planted fault that reads far
+over it); the engine tests hold the token STREAMS to NaiveGenerator's.
 
 Params are a flat dict of jnp arrays — pytree-friendly for jit and for
 program_cache.exported_entry avals.
@@ -163,15 +169,17 @@ def forward_full(cfg: DecoderConfig, params: dict, tokens, lengths,
     (visible prefix per row; padding beyond it is masked out of
     attention). Returns (logits `[B, vocab]` at position lengths-1,
     k_cache, v_cache each `[layers, B, S, heads, head_dim]`) — the
-    caches feed prefill's scatter into the block pool.
+    caches let a test fill a block pool with a prompt's rows.
 
     `attn_lanes` (static) pads the attention K/V axis to a FIXED lane
-    count — the bitwise-parity requirement: XLA regroups a reduction
-    when its length changes (Tk=16 vs Tk=32 sums associate nonzero
-    elements differently, measured 1-ulp drift), so the full-context
-    and paged paths must reduce over the SAME number of lanes. The
-    engine passes its pool-table span (max_blocks_per_seq *
-    block_size); 0 keeps the raw S lanes (standalone use).
+    count: the oracle's key axis as wide as the paged view. XLA
+    regroups a reduction when its length changes (Tk=16 vs Tk=32 sums
+    associate nonzero elements differently), so with the SAME number
+    of lanes the two paths' softmax sums agree and the token streams
+    the tests compare (module docstring) do not part at a near-tie.
+    NaiveGenerator passes the engine's pool-table span
+    (max_blocks_per_seq * block_size); 0 keeps the raw S lanes
+    (standalone use).
     """
     b, s = tokens.shape
     pos = jnp.arange(s, dtype=jnp.int32)
@@ -228,7 +236,7 @@ def forward_paged(cfg: DecoderConfig, params: dict, k_pools, v_pools,
     reads layer i of it through `paged_attention(..., layer=i)` — no
     layer's pool is ever sliced out, and nothing is stacked at the
     end. A caller that jits this with the pools DONATED (the engine's
-    `mixed`, `decode` and `draft_mixed` programs) gets the arrays it
+    `mixed` and `draft_mixed` programs) gets the arrays it
     passed back, the step's rows written; a caller that does not
     donate pays one copy of each pool at the program's edge, and
     computes the same values.
@@ -239,8 +247,8 @@ def forward_paged(cfg: DecoderConfig, params: dict, k_pools, v_pools,
     slots with duplicated table rows and consecutive positions; because
     every layer scatters all slots' K/V before the attention gather,
     later chunk-mates see earlier ones' keys within the same call, so a
-    prompt streamed through this step is bitwise-identical to
-    `forward_full` at every position (pinned in tests/test_kernels.py).
+    prompt streamed through this step reads what `forward_full` reads
+    at every position, to within rounding (tests/test_kernels.py).
 
     Inactive slots (the scheduler parks them) carry ctx_lens whose
     block-table slot is the trash block — their writes land in trash

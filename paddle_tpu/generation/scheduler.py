@@ -392,7 +392,6 @@ class GenerationPool:
         eng._restore_pools()
         eng._lane_seq = [None] * eng.decode_width
         eng._tables[:] = 0
-        eng._ctx[:] = 0
         eng._pending = []
         eng._inflight = None    # lookahead: the step the fault took
         # kv.__init__ republished the block gauges; retract the rest

@@ -14,10 +14,10 @@ Two execution paths, selected by FLAGS_paged_attention_kernel:
 
 - "reference" (default): gather + masked softmax in plain XLA. This is
   the parity oracle — `attend_reference` here is the SAME function the
-  generation model uses for full-context prefill, so a paged decode
-  step is bitwise-identical to a full-context recompute of the same
-  position (masked lanes contribute exp(-1e30 - m) == 0.0 exactly, and
-  adding exact zeros never perturbs the reduction).
+  generation model's full-context forward uses, so a paged decode
+  step agrees with a full-context recompute of the same position to
+  within rounding (masked lanes contribute exp(-1e30 - m) == 0.0
+  exactly, and adding exact zeros never perturbs the reduction).
 - "pallas": the blocked kernel below — grid over (batch, blocks),
   block tables scalar-prefetched so each grid step's BlockSpec
   index_map DMAs exactly one pool block into VMEM, online-softmax
@@ -149,9 +149,10 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
     The gather materializes each sequence's `[max_blocks * block_size]`
     logical KV view (masked positions hide stale or foreign blocks
     behind the table), then runs the shared attend_reference core with
-    Tq == Cq — the same ops and reduction shapes as full-context
-    prefill, which is what makes the chunked path bitwise-comparable to
-    `forward_full` recompute (tests/test_kernels.py).
+    Tq == Cq — the same ops and reduction shapes as the full-context
+    forward, which is what keeps the chunked path within rounding of a
+    `forward_full` recompute (tests/test_kernels.py::
+    test_chunked_prefill_mixed_batch_bitwise_vs_forward_full).
 
     QUANTIZED KV (ISSUE 15): int8/fp8 pools ride with per-token-per-head
     absmax scales `k_scales`/`v_scales` `[N, bs, H]` — the gather pulls
@@ -540,8 +541,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     """Decode-step attention over the paged KV pool. Routed by
     FLAGS_paged_attention_kernel (a lowering flag: it is baked into
     every generation compile key), subject to the kernel_form override
-    above: "reference" is the bitwise parity path; "pallas" runs the
-    blocked kernel (interpret mode off-TPU). k_scales/v_scales
+    above: "reference" is the parity path (the oracle's own attention
+    core); "pallas" runs the blocked kernel (interpret mode off-TPU).
+    k_scales/v_scales
     (quantized pools, paddle_tpu/quant) flow to the dequant-fused
     forms of both paths; None = the untouched fp32 path. `layer`
     (an int or a traced scalar) says the pools are the stacked

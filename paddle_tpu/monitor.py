@@ -68,7 +68,7 @@ The generation engine (docs/generation.md) exposes:
   STAT_generation_blocks_allocated / _blocks_freed (KV ledger churn);
 - GAUGE_generation_blocks_free / _blocks_used (pool occupancy),
   _active_seqs, _queue_depth;
-- always-on TIMER_generation_prefill_us / _decode_step_us /
+- always-on TIMER_generation_mixed_step_us / _decode_step_us /
   _inter_token_us histograms (tokens/s and p95 inter-token latency are
   the generation SLO; bench.py's generation block gates on the
   decode-step p95 via tools/stat_diff.py).

@@ -218,13 +218,13 @@ finally:
 # chunked-prefill generation smoke (ISSUE 10, docs/generation.md):
 # drive the mixed ragged step under the same dp4xmp2 plan — prompts
 # stream through the one fixed-shape executable in chunks while a
-# second request decodes, streams must be bitwise-identical to the
-# two-phase engine, with zero steady-state recompiles after warmup.
+# second request decodes, streams must equal the naive full-context
+# oracle's, with zero steady-state recompiles after warmup.
 generation = {"ok": False}
 try:
     from paddle_tpu.generation import (DecoderConfig, GenerationEngine,
-                                       GenerationRequest, SamplingParams,
-                                       init_params)
+                                       GenerationRequest, NaiveGenerator,
+                                       SamplingParams, init_params)
     from paddle_tpu.monitor import stat_get
 
     gcfg = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=4,
@@ -240,14 +240,15 @@ try:
     def gen_run(chunk):
         eng = GenerationEngine(gcfg, gparams, num_blocks=64,
                                block_size=4, decode_width=2,
-                               prefill_buckets="pow2:32",
                                prefill_chunk=chunk)
         eng.warmup()
         c0 = stat_get("STAT_generation_compile")
         res = eng.generate(greqs)
-        # key by request id: completion ORDER legitimately differs
-        # between the two admission disciplines; the STREAMS must not
+        # keyed by request id: the oracle runs one request at a time
+        naive = NaiveGenerator(gcfg, gparams, buckets="pow2:32",
+                               attn_lanes=eng.attn_lanes)
         return ({r.request_id: r.tokens for r in res},
+                {r.request_id: naive.generate(r).tokens for r in greqs},
                 int(stat_get("STAT_generation_compile") - c0))
 
     # PR 14 smokes under the same plan: (a) cross-request prefix
@@ -269,13 +270,11 @@ try:
         kw.setdefault("num_blocks", 64)
         kw.setdefault("block_size", 4)
         kw.setdefault("decode_width", 2)
-        kw.setdefault("prefill_buckets", "pow2:32")
         kw.setdefault("prefill_chunk", 4)
         return GenerationEngine(gcfg, gparams, **kw)
 
     with use_plan(plan):
-        chunked_toks, chunked_compiles = gen_run(4)
-        twophase_toks, _ = gen_run(0)
+        chunked_toks, naive_toks, chunked_compiles = gen_run(4)
 
         cold = {r.request_id: r.tokens
                 for r in mk_eng(prefix_cache=False).generate(preqs())}
@@ -301,10 +300,10 @@ try:
             stat_get("STAT_generation_spec_accepted") - a0)
         spec_identical = spec == plain
     generation = {
-        "ok": (chunked_toks == twophase_toks and chunked_compiles == 0
+        "ok": (chunked_toks == naive_toks and chunked_compiles == 0
                and prefix_identical and prefix_hits > 0
                and spec_identical and spec_proposed > 0),
-        "streams_bitwise_identical": chunked_toks == twophase_toks,
+        "streams_bitwise_identical": chunked_toks == naive_toks,
         "steady_state_recompiles": chunked_compiles,
         "prefill_chunk": 4,
         "chunks": int(sum((len(r.prompt) + 3) // 4 for r in greqs)),
@@ -347,7 +346,6 @@ try:
         b0 = stat_get("STAT_generation_kv_quant_blocks")
         q_eng = GenerationEngine(gcfg, qparams, num_blocks=64,
                                  block_size=4, decode_width=2,
-                                 prefill_buckets="pow2:32",
                                  prefill_chunk=4, prefix_cache=False,
                                  quant_mode=qmode, kv_dtype="int8")
         # served through the continuous-batching pool, as deployed
@@ -946,7 +944,7 @@ try:
             workers_max=2,
             factory=lambda: GenerationEngine(
                 _gcfg, _gq, num_blocks=32, block_size=8,
-                decode_width=2, prefill_buckets="pow2:16",
+                decode_width=2,
                 prefill_chunk=8, prefix_cache=False,
                 quant_mode="int8", kv_dtype="int8")),
     ])

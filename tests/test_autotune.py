@@ -127,14 +127,6 @@ def test_candidates_respect_pins():
     assert any(c.block_size != 16 for c in cands)  # free dim varies
 
 
-def test_two_phase_defaults_do_not_invent_chunking():
-    # prefill_chunk=0 (two-phase mode) stays 0 across every candidate:
-    # the tuner varies a knob's magnitude, never flips the mode
-    d = CandidateForm("reference", 16, 0, 0)
-    cands = generation_candidates(d, pins={}, budget=10)
-    assert all(c.prefill_chunk == 0 for c in cands)
-
-
 # ---------------------------------------------------------------------------
 # steady-state resolve is ONE dict lookup
 # ---------------------------------------------------------------------------

@@ -201,11 +201,11 @@ def mixed_text():
     eng = _engine()
     got = {}
 
-    def capture(kind, bucket, raw, avals):
+    def capture(kind, raw, avals):
         got.update(raw=raw, avals=avals)
         return jax.jit(raw)
     eng._aot_or_jit = capture
-    eng._build_fn("mixed", 0)
+    eng._build_fn("mixed")
     return jax.jit(got["raw"]).lower(*got["avals"]).as_text(debug_info=True)
 
 

@@ -538,7 +538,6 @@ def _gengine(gparams, **kw):
     kw.setdefault("num_blocks", 64)
     kw.setdefault("block_size", 4)
     kw.setdefault("decode_width", 4)
-    kw.setdefault("prefill_buckets", "pow2:16")
     return GenerationEngine(GCFG, gparams, **kw)
 
 

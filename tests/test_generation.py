@@ -1,5 +1,5 @@
-"""Generation engine tests: paged KV cache, bitwise prefill/decode
-parity, sampler determinism, continuous batching, backpressure, and
+"""Generation engine tests: paged KV cache, paged-step/full-context
+logit parity, sampler determinism, continuous batching, backpressure, and
 the zero-steady-state-recompile pin (docs/generation.md)."""
 import threading
 import time
@@ -23,10 +23,6 @@ from paddle_tpu.monitor import gauge_get, stat_get
 from paddle_tpu.serving import ServingQueueFull
 
 
-def _bits(a):
-    return np.asarray(a, np.float32).view(np.uint32)
-
-
 CFG = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=4,
                     max_seq_len=32)
 
@@ -40,7 +36,6 @@ def _engine(params, **kw):
     kw.setdefault("num_blocks", 64)
     kw.setdefault("block_size", 4)
     kw.setdefault("decode_width", 4)
-    kw.setdefault("prefill_buckets", "pow2:16")
     return GenerationEngine(CFG, params, **kw)
 
 
@@ -96,13 +91,26 @@ def test_kv_manager_freed_blocks_recycle():
 
 
 # ---------------------------------------------------------------------------
-# bitwise prefill/decode parity
+# prefill/decode parity
 # ---------------------------------------------------------------------------
 
-def test_paged_decode_bitwise_parity_every_step(params):
+# The paged step and the full-context forward run the same float32
+# operations over a key axis of the same width, yet XLA:CPU does not
+# give them the same last bits: it picks a matmul's tiling from the
+# batch's shape ([3, h] against [3, 16, h] here). Largest gap measured
+# over 24 seeds of weights and tokens: 1.43e-6, on logits up to 4.2 (a
+# few ULP). The limit is ten times that. A pool that holds bfloat16
+# rows, the planted fault, reads 5.5e-3 to 1.4e-2 over the same seeds.
+PARITY_ATOL = 1.5e-5
+
+
+@pytest.mark.parametrize("fault", [None, "bf16_pool"])
+def test_paged_decode_bitwise_parity_every_step(params, fault):
     """The acceptance gate: at EVERY decode step the paged single-token
-    logits equal a full-context recompute of the same position, bit for
-    bit (fixed attention lanes — model.forward_full docstring)."""
+    logits equal a full-context recompute of the same position within
+    PARITY_ATOL (no longer bit for bit: see there). With the fault
+    planted the same comparison reads ten times over the limit, so
+    the limit is one a broken cache cannot pass."""
     bs, nblocks = 4, 32
     m = -(-CFG.max_seq_len // bs)
     lanes = m * bs
@@ -132,9 +140,11 @@ def test_paged_decode_bitwise_parity_every_step(params):
 
     dec = jax.jit(lambda p, k, v, t, c, x: forward_paged(
         CFG, p, k, v, t, c, x))
-    kpj, vpj = jnp.asarray(kp), jnp.asarray(vp)
+    pool_dtype = jnp.bfloat16 if fault else jnp.float32
+    kpj, vpj = jnp.asarray(kp, pool_dtype), jnp.asarray(vp, pool_dtype)
     cur, cl = toks.copy(), lens.copy()
     nxt = np.asarray(jnp.argmax(last, -1), np.int32)
+    gaps = []
     for step in range(6):
         for i in range(3):
             need = mgr.blocks_for_tokens(int(cl[i]) + 1)
@@ -147,9 +157,14 @@ def test_paged_decode_bitwise_parity_every_step(params):
             cur[i, cl[i]] = nxt[i]
         cl = cl + 1
         oracle, _, _ = ff(params, jnp.asarray(cur), jnp.asarray(cl))
-        assert np.array_equal(_bits(logits), _bits(oracle)), \
-            "bitwise parity broke at step %d" % step
+        if fault is None:
+            np.testing.assert_allclose(
+                logits, oracle, rtol=0, atol=PARITY_ATOL,
+                err_msg="parity broke at step %d" % step)
+        gaps.append(np.abs(np.asarray(logits - oracle)).max())
         nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+    if fault:
+        assert max(gaps) >= 10 * PARITY_ATOL, gaps
 
 
 def test_engine_tokens_match_naive_full_context(params):
@@ -328,7 +343,6 @@ def _mixed_requests(rng, n, sampled):
 _ENGINE_FORMS = {
     "chunked_ahead": dict(prefill_chunk=3),
     "chunked": dict(prefill_chunk=3, lookahead=0),
-    "two_phase": dict(prefill_chunk=0),
     "speculative": dict(prefill_chunk=3, spec_tokens=2),
 }
 
@@ -365,8 +379,6 @@ def test_sampler_filter_steps_counts_the_steps_with_a_sampled_row(
     def spy(kind, *rest):
         if kind == "mixed":
             handed.append(np.asarray(rest[-1])[:eng.sample_width])
-        elif kind == "decode":
-            handed.append(np.asarray(rest[3]))
         return run(kind, *rest)
     eng._run = spy
     reqs = _mixed_requests(
@@ -430,7 +442,7 @@ def test_eviction_replay_is_deterministic(params):
     """Pool pressure preempts the youngest sequence; its deterministic
     replay must yield the same tokens as an uncontended run."""
     small = GenerationEngine(CFG, params, num_blocks=10, block_size=4,
-                             decode_width=4, prefill_buckets="pow2:16")
+                             decode_width=4)
     reqs = [GenerationRequest(prompt=[i + 1] * 10, max_new_tokens=14,
                               sampling=SamplingParams(temperature=0.9,
                                                       seed=i),
@@ -454,7 +466,7 @@ def test_submit_validation_is_per_request(params):
         eng.submit(GenerationRequest(prompt=[1], max_new_tokens=0))
     # a request larger than the whole pool can never run
     tiny = GenerationEngine(CFG, params, num_blocks=3, block_size=4,
-                            decode_width=2, prefill_buckets="pow2:16")
+                            decode_width=2)
     with pytest.raises(ValueError):
         tiny.submit(GenerationRequest(prompt=[1] * 10,
                                       max_new_tokens=10))
@@ -599,11 +611,10 @@ def test_decode_width_one_matches_width_four(params):
 # chunked prefill + mixed step (PR 10)
 # ---------------------------------------------------------------------------
 
-def test_chunked_streams_match_two_phase_and_naive(params):
-    """The chunked mixed step (default) produces bitwise the SAME token
-    streams as the PR-5 two-phase engine (prefill_chunk=0) and the
-    naive full-recompute oracle — the sampler step indices and the
-    paged logits are identical in all three."""
+def test_chunked_streams_match_naive(params):
+    """The chunked mixed step produces the SAME token streams as the
+    naive full-recompute oracle: the sampler's step indices are the
+    oracle's, and the paged logits lie within rounding of its."""
     rng = np.random.default_rng(11)
     reqs = [GenerationRequest(
         prompt=list(rng.integers(1, CFG.vocab_size,
@@ -613,12 +624,8 @@ def test_chunked_streams_match_two_phase_and_naive(params):
                                 seed=i),
         request_id=i) for i in range(6)]
     chunked = _engine(params, prefill_chunk=3)
-    two_phase = _engine(params, prefill_chunk=0)
     a = {r.request_id: r.tokens for r in chunked.generate(
         [GenerationRequest(**r.__dict__) for r in reqs])}
-    b = {r.request_id: r.tokens for r in two_phase.generate(
-        [GenerationRequest(**r.__dict__) for r in reqs])}
-    assert a == b
     naive = NaiveGenerator(CFG, params, buckets="pow2:16",
                            attn_lanes=chunked.attn_lanes)
     for r in reqs:
@@ -654,16 +661,9 @@ def test_decode_advances_during_chunked_prefill(params):
 
 
 def test_pad_tokens_stat_emitted(params):
-    """STAT_generation_pad_tokens: the two-phase engine pays bucket
-    padding per prefill, the chunked engine only unused mixed-batch
-    slots — both emit the stat (satellite: pad waste is observable)."""
-    p0 = stat_get("STAT_generation_pad_tokens")
-    two_phase = _engine(params, prefill_chunk=0)
-    two_phase.generate([GenerationRequest(prompt=[1] * 5,
-                                          max_new_tokens=2,
-                                          request_id=0)])
-    # prompt 5 pads to bucket 8: at least 3 pad tokens from prefill
-    assert stat_get("STAT_generation_pad_tokens") >= p0 + 3
+    """STAT_generation_pad_tokens: the engine pays for the unused
+    slots of its mixed batch and emits the stat (satellite: pad waste
+    is observable)."""
     p1 = stat_get("STAT_generation_pad_tokens")
     chunked = _engine(params, prefill_chunk=4)
     chunked.generate([GenerationRequest(prompt=[1] * 5,
@@ -769,29 +769,6 @@ def test_generation_bench_acceptance(tmp_path, monkeypatch):
     assert block["steady_state_recompiles"] == 0
     assert block["speedup_paged_vs_naive"] >= 2.0
     assert block["decode_step_p95_regressions"] == []
-
-
-@pytest.mark.slow
-def test_generation_mixed_bench_acceptance(tmp_path, monkeypatch):
-    """ISSUE-10 acceptance: chunked prefill >= 1.3x two-phase
-    generated tokens/s AND lower decode-TPOT p95 on the prompt-heavy
-    mixed workload, zero steady-state recompiles, streams bitwise
-    identical across naive/two-phase/chunked."""
-    import importlib.util
-    import os
-    monkeypatch.setenv("PT_GENERATION_MIXED_BENCH_SNAPSHOT",
-                       str(tmp_path / "gen_mixed_snap.json"))
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "pt_bench", os.path.join(repo, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    block = mod.bench_generation_mixed()
-    assert block["tokens_bitwise_identical"] is True
-    assert block["chunked"]["steady_state_recompiles"] == 0
-    assert block["meets_1p3x"] is True
-    assert block["decode_tpot_p95_improved"] is True
-    assert block["chunked"]["pad_ratio"] < block["two_phase"]["pad_ratio"]
 
 
 @pytest.mark.slow

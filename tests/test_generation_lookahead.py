@@ -207,7 +207,6 @@ def test_a_sequence_at_its_length_takes_no_further_slot():
 
 
 @pytest.mark.parametrize("kw", [dict(spec_tokens=2),
-                                dict(prefill_chunk=0),
                                 dict(lookahead=2)])
 def test_what_cannot_ride_the_lookahead_is_refused_or_runs_without(kw):
     cfg, params = _family("gpt")

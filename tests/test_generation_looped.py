@@ -148,13 +148,11 @@ def test_paged_prefill_chunks_then_decode_equal_forward_full(passes):
 
 
 @PASSES
-@pytest.mark.parametrize("mode", ["chunked", "two_phase", "pallas"])
+@pytest.mark.parametrize("mode", ["chunked", "pallas"])
 def test_engine_streams_equal_the_naive_generators(passes, mode):
     cfg = _cfg(passes)
     params = looped.init_params(cfg, seed=2)
-    kw = {"chunked": {}, "pallas": {"kernel": "pallas"},
-          "two_phase": {"prefill_chunk": 0,
-                        "prefill_buckets": "pow2:32"}}[mode]
+    kw = {"chunked": {}, "pallas": {"kernel": "pallas"}}[mode]
     eng = _engine(cfg, params, **kw)
     naive = NaiveGenerator(cfg, params, attn_lanes=eng.attn_lanes)
     reqs = _reqs()
@@ -317,7 +315,7 @@ def test_compiled_step_aliases_every_pool_and_copies_none():
     shapes = {"%s[%s]" % (dt, ",".join(d))
               for d in (dims, ["1"] + dims[1:], dims[1:])}
     for kind in ("mixed", "cow"):
-        txt = eng._fns[(kind, 0)]._compiled.as_text()
+        txt = eng._fns[kind]._compiled.as_text()
         head = txt.splitlines()[0]
         alias = head[head.index("input_output_alias"):]
         alias = alias[:alias.index("}, entry_computation_layout")]
@@ -455,7 +453,7 @@ def test_from_source_reads_the_published_keys():
 
 
 @pytest.mark.parametrize("what", ["kv_dtype", "weight_quant", "gqa",
-                                  "odd_head", "cap", "bf16_two_phase"])
+                                  "odd_head", "cap"])
 def test_what_the_family_cannot_take_is_refused_loudly(what):
     cfg = _cfg(2)
     params = looped.init_params(cfg)
@@ -471,12 +469,24 @@ def test_what_the_family_cannot_take_is_refused_loudly(what):
     elif what == "odd_head":
         with pytest.raises(ValueError, match="even"):
             dataclasses.replace(cfg, head_dim=11)
-    elif what == "cap":
+    else:
         with pytest.raises(ValueError, match="context cap"):
             dataclasses.replace(cfg, max_seq_len=65537)
+
+
+@pytest.mark.parametrize("family", ["gpt", "looped"])
+def test_a_prefill_chunk_below_one_is_refused(family):
+    """There is one engine, the mixed step's: `prefill_chunk=0` once
+    chose a second one (bucketed prefill + decode), and is refused."""
+    if family == "gpt":
+        cfg = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=4,
+                            max_seq_len=32)
+        params = init_params(cfg, seed=0)
     else:
-        with pytest.raises(ValueError, match="chunked mixed step"):
-            _engine(cfg, params, kv_dtype="bf16", prefill_chunk=0)
+        cfg = _cfg(2)
+        params = looped.init_params(cfg)
+    with pytest.raises(ValueError, match="two-phase engine"):
+        _engine(cfg, params, prefill_chunk=0)
 
 
 def test_the_context_cap_is_the_engines_not_the_models():

@@ -337,10 +337,10 @@ def test_spec_with_prefix_cache_composes(params):
 
 
 def test_spec_requires_chunked_mode_and_validates_draft(params):
-    with pytest.raises(ValueError):
+    # the two-phase engine is gone: no chunk, no engine for a drafter
+    with pytest.raises(ValueError, match="prefill_chunk must be >= 1"):
         GenerationEngine(CFG, params, num_blocks=16, block_size=4,
-                         decode_width=2, prefill_chunk=0,
-                         prefill_buckets="pow2:16", spec_tokens=2)
+                         decode_width=2, prefill_chunk=0, spec_tokens=2)
     with pytest.raises(ValueError):
         _engine(params, spec_tokens=2, draft="model")  # no draft_cfg
     with pytest.raises(ValueError):

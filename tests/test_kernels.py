@@ -445,12 +445,42 @@ def test_ragged_flag_seam():
     assert np.array_equal(_bits(routed), _bits(ref))
 
 
-def test_chunked_prefill_mixed_batch_bitwise_vs_forward_full():
+# forward_paged's mixed step against forward_full, float32 on both
+# sides over a key axis of the same width: XLA:CPU picks a matmul's
+# tiling from the batch's shape ([5, h] slots against [1, 32, h]), so
+# the last bits differ. Largest gap measured over 24 seeds of weights
+# and tokens: 1.43e-6 on logits up to 4.2; the limit is ten times that.
+# The planted fault (every slot's mask one lane short, so a token does
+# not see its own key) reads 2.7 to 4.4 over the same seeds, and 0.2 at
+# its least at any one position.
+MIXED_PARITY_ATOL = 1.5e-5
+
+
+@pytest.mark.parametrize("fault", [None, "mask_one_lane_short"])
+def test_chunked_prefill_mixed_batch_bitwise_vs_forward_full(
+        fault, monkeypatch):
     """PR-5's paged==full parity pin extended to chunked prefill: a
     prompt streamed through the mixed step in 4-token chunks — sharing
     its batch with a concurrently DECODING sequence — produces, at
-    every prompt position and every decode step, logits bitwise equal
-    to a full-context forward_full recompute."""
+    every prompt position and every decode step, logits within
+    MIXED_PARITY_ATOL of a full-context forward_full recompute (no
+    longer bit for bit: see there). With the fault planted the same
+    comparisons read ten times over the limit."""
+    from paddle_tpu.generation import model as gen_model
+    if fault:
+        real = gen_model.paged_attention
+        monkeypatch.setattr(
+            gen_model, "paged_attention",
+            lambda q, k, v, tables, ctx, **kw: real(q, k, v, tables,
+                                                    ctx - 1, **kw))
+    gaps = []
+
+    def check(got, want, what):
+        if fault is None:
+            np.testing.assert_allclose(got, want, rtol=0,
+                                       atol=MIXED_PARITY_ATOL,
+                                       err_msg=what)
+        gaps.append(np.abs(np.asarray(got - want)).max())
     cfg = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=4,
                         max_seq_len=32)
     params = init_params(cfg, seed=0)
@@ -490,7 +520,7 @@ def test_chunked_prefill_mixed_batch_bitwise_vs_forward_full():
     logits, kp, vp = step(params, kp, vp, jnp.asarray(tables),
                           jnp.asarray(pos), jnp.asarray(toks))
     for j in range(len(pb)):
-        assert np.array_equal(_bits(logits[j]), _bits(oracle(pb[:j + 1])))
+        check(logits[j], oracle(pb[:j + 1]), "B's prompt at %d" % j)
     btoks = pb + [int(np.argmax(np.asarray(logits[len(pb) - 1])))]
     # A's 13-token prompt streams in chunks of 4 while B greedy-decodes
     filled = 0
@@ -504,13 +534,14 @@ def test_chunked_prefill_mixed_batch_bitwise_vs_forward_full():
             toks[1 + j] = pa[filled + j]
         logits, kp, vp = step(params, kp, vp, jnp.asarray(tables),
                               jnp.asarray(pos), jnp.asarray(toks))
-        assert np.array_equal(_bits(logits[0]), _bits(oracle(btoks))), \
-            "decode lane diverged while chunk [%d:%d) prefilled" \
-            % (filled, filled + take)
+        check(logits[0], oracle(btoks),
+              "decode lane diverged while chunk [%d:%d) prefilled"
+              % (filled, filled + take))
         for j in range(take):
-            assert np.array_equal(
-                _bits(logits[1 + j]),
-                _bits(oracle(pa[:filled + j + 1]))), \
-                "chunked prefill diverged at position %d" % (filled + j)
+            check(logits[1 + j], oracle(pa[:filled + j + 1]),
+                  "chunked prefill diverged at position %d"
+                  % (filled + j))
         btoks.append(int(np.argmax(np.asarray(logits[0]))))
         filled += take
+    if fault:
+        assert max(gaps) >= 10 * MIXED_PARITY_ATOL, gaps

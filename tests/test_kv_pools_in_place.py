@@ -136,7 +136,7 @@ def test_pool_programs_update_in_place(tmp_path, params, cached, kv):
         argnums = eng._pool_argnums(kind)
         names = eng._program_pools(kind)
         assert len(names) == len(argnums) > 0, kind
-        _check_program(eng._fns[(kind, 0)]._compiled.as_text(),
+        _check_program(eng._fns[kind]._compiled.as_text(),
                        [getattr(eng, n) for n in names], argnums,
                        eng.draft_params if kind == "draft_mixed"
                        else eng.params)
@@ -199,7 +199,7 @@ def test_fault_inside_a_donated_call_leaves_live_zero_pools(flag_guard,
     eng = _engine(params, kv_dtype=kv)
     eng.warmup()
     specs = eng._pool_specs()
-    key = ("mixed", 0)
+    key = "mixed"
     real = eng._fns[key]
     argnums = eng._pool_argnums("mixed")
     state = {"armed": False, "seen": None}

@@ -181,9 +181,9 @@ def test_kv_dequant_reference_vs_pallas_interpret():
 
 
 def test_quantized_kv_requires_chunked_mode(params):
-    with pytest.raises(ValueError, match="chunked"):
-        _engine(params, prefill_chunk=0, prefill_buckets="pow2:16",
-                kv_dtype="int8")
+    # the two-phase engine, which refused a quantized pool, is gone
+    with pytest.raises(ValueError, match="prefill_chunk must be >= 1"):
+        _engine(params, prefill_chunk=0, kv_dtype="int8")
 
 
 def test_all_four_samplers_within_budget(params):

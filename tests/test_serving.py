@@ -1,10 +1,10 @@
 """Serving-grade Predictor tests (ISSUE 4, docs/serving.md).
 
 Covers the tentpole: the bucket ladder parser, shape-bucketed
-Predictor execution (bitwise parity with exact shapes + pinned
+Predictor execution (row parity with exact shapes + pinned
 STAT_executor_compile deltas), compile-ahead warmup through the AOT
 program cache (zero steady-state recompiles), the PredictorPool
-micro-batcher (multi-threaded mixed-shape stress with bitwise parity
+micro-batcher (multi-threaded mixed-shape stress with row parity
 vs serial execution, serving counter deltas, queue backpressure,
 error isolation, lifecycle), and the framework-free SerializedCore
 batch padding (static pad-up + overflow, env-ladder for
@@ -35,6 +35,17 @@ def model_dir(tmp_path):
     d = str(tmp_path / "model")
     pt.io.save_inference_model(d, ["x"], [y], exe, main_program=main)
     return d
+
+
+def _assert_rows_close(got, want):
+    """A request's rows, whatever batch they rode in. Not bit for bit:
+    XLA:CPU chooses its matmul tiling from the batch's shape, so a
+    row's last bits move with the bucket (measured over 40 seeds x 10
+    sizes: a padded bucket and the exact batch read at most 2.4e-7
+    apart on outputs up to 1.6, a tenth of this budget; the absolute
+    part is for outputs near zero). A row taken from the wrong request
+    differs in its first digit (4.8e-2 at the least)."""
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
 def _reqs(sizes, width=6, seed=0):
@@ -90,7 +101,7 @@ def test_bucketed_parity_and_compile_count(model_dir):
 
     for o, e in zip(outs, expected):
         assert o.shape == e.shape
-        np.testing.assert_array_equal(o, e)  # bitwise: rows independent
+        _assert_rows_close(o, e)
     # 8 requests, 6 distinct sizes, but only buckets {1,2,4,8} compile
     assert compiles == 4
     assert stat_get("STAT_predictor_bucket_hit") - h0 == 4
@@ -162,7 +173,7 @@ def test_pool_concurrent_parity_and_counters(model_dir):
             t.join()
 
         for o, e in zip(outs, expected):
-            np.testing.assert_array_equal(o, e)  # bitwise vs serial
+            _assert_rows_close(o, e)             # vs serial
         assert stat_get("STAT_executor_compile") - c0 == 0
         assert stat_get("STAT_serving_requests") - q0 == len(reqs)
         batches = stat_get("STAT_serving_batches") - b0
@@ -300,7 +311,7 @@ def test_serialized_static_pad_up(model_dir, tmp_path):
     (r,) = _reqs([3])
     out = core.run([r])[0]
     assert out.shape[0] == 3
-    np.testing.assert_array_equal(out, np.asarray(ref.run([r])[0]))
+    _assert_rows_close(out, np.asarray(ref.run([r])[0]))
     assert core.stats["padded_calls"] == 1
     assert core.stats["pad_rows"] == 5
     with pytest.raises(ValueError):  # b > compiled batch is loud

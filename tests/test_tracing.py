@@ -261,7 +261,7 @@ def test_preempt_and_replay_events_on_generation_replay():
     # gets preempted mid-decode and replayed (test_generation.py's
     # eviction scenario)
     eng = GenerationEngine(cfg, params, num_blocks=10, block_size=4,
-                           decode_width=2, prefill_buckets="pow2:16")
+                           decode_width=2)
     reqs = [GenerationRequest(prompt=[1 + i] * 12, max_new_tokens=12,
                               request_id=i) for i in range(2)]
     results = eng.generate(reqs)
@@ -298,7 +298,7 @@ def test_generation_trace_decomposition_timers():
                         max_seq_len=32)
     params = init_params(cfg, seed=0)
     eng = GenerationEngine(cfg, params, num_blocks=64, block_size=4,
-                           decode_width=4, prefill_buckets="pow2:16")
+                           decode_width=4)
     t0 = timer_get("TIMER_generation_ttft_us")["count"]
     q0 = timer_get("TIMER_generation_queue_wait_us")["count"]
     eng.generate([GenerationRequest(prompt=[1, 2, 3],
