@@ -61,6 +61,7 @@ import numpy as np
 
 from .failpoints import failpoint
 from .flags import get_flag
+from .kernels.paged_attention import resolved_form
 from .monitor import stat_add, timer_observe
 
 __all__ = ["CandidateForm", "DispatchPolicy", "generation_candidates",
@@ -335,8 +336,7 @@ def resolve_generation(cfg, params, *, num_blocks: int,
     budget = max(1, int(get_flag("FLAGS_autotune_candidates")))
     probe_tokens = max(4, int(get_flag("FLAGS_autotune_probe_tokens")))
     defaults = CandidateForm(
-        kernel=str(pins.get("kernel",
-                            get_flag("FLAGS_paged_attention_kernel"))),
+        kernel=str(pins.get("kernel", resolved_form())),
         block_size=int(pins.get("block_size",
                                 get_flag("FLAGS_generation_block_size"))),
         prefill_chunk=int(pins.get(

@@ -151,9 +151,14 @@ def _memory_analysis(compiled):
 
 def _fill_record(rec: ProgramRecord, compiled) -> None:
     ca = _cost_analysis(compiled)
-    rec.flops = float(ca.get("flops", 0.0) or 0.0)
-    rec.transcendentals = float(ca.get("transcendentals", 0.0) or 0.0)
-    rec.bytes_accessed = float(ca.get("bytes accessed", 0.0) or 0.0)
+
+    def cost(key):
+        # XLA answers -1 where it does not know (an executable that
+        # jax's persistent cache loaded back): unknown counts nothing
+        return max(0.0, float(ca.get(key, 0.0) or 0.0))
+    rec.flops = cost("flops")
+    rec.transcendentals = cost("transcendentals")
+    rec.bytes_accessed = cost("bytes accessed")
     ma = _memory_analysis(compiled)
     if ma is not None:
         for attr, field in (("argument_size_in_bytes", "argument_bytes"),

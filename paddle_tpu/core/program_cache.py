@@ -279,14 +279,6 @@ def has_trace(cache_dir: str, fingerprint: str) -> bool:
 POLICY_MAGIC = b"PTPOL1\n"
 POLICY_FORMAT_VERSION = 1
 
-# The knobs autotune searches. They are EXCLUDED from the policy
-# fingerprint's lowering snapshot: the policy's job is to choose them,
-# so keying the policy on their current values would fragment the key
-# space (every flag flip would look like a new deployment). A pinned
-# tuned flag still isolates correctly — pins ride the key meta itself
-# (autotune.py puts them there), not the flag snapshot.
-TUNED_FLAGS = ("FLAGS_paged_attention_kernel",)
-
 
 def _policy_path(cache_dir: str, fingerprint: str) -> str:
     return os.path.join(cache_dir, "policy", fingerprint + ".json")
@@ -295,19 +287,19 @@ def _policy_path(cache_dir: str, fingerprint: str) -> str:
 def policy_fingerprint(meta: dict) -> str:
     """Disk key for one autotune policy entry: sha256 over the
     caller's key metadata (shape-bucket, backend, quant-mode, pins) +
-    the NON-tuned lowering flags + jax/jaxlib/backend versions + the
-    framework source token — the fn_fingerprint invalidation surface
-    minus the knobs the policy itself chooses (TUNED_FLAGS)."""
+    the lowering flags + jax/jaxlib/backend versions + the framework
+    source token — the fn_fingerprint invalidation surface. No knob
+    the policy itself chooses is a lowering flag (the kernel form is
+    pinned by the engine's `kernel=`, which rides `meta["pins"]`), so
+    none has to be kept out of the key."""
     import jax
     import jaxlib
     from ..flags import lowering_snapshot
-    flags = tuple(kv for kv in lowering_snapshot()
-                  if kv[0] not in TUNED_FLAGS)
     h = hashlib.sha256()
     h.update(json.dumps({
         "tag": "autotune_policy",
         "meta": meta,
-        "flags": flags,
+        "flags": lowering_snapshot(),
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),

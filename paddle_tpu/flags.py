@@ -139,12 +139,6 @@ _DEFS: Dict[str, Any] = {
     # (generation.GenerationPool): submit blocks, then raises
     # ServingQueueFull — same backpressure contract as PredictorPool
     "FLAGS_generation_queue_depth": 256,
-    # paged-attention decode path (kernels/paged_attention.py):
-    # "reference" = gather + masked softmax in plain XLA (runs
-    # everywhere, the parity oracle), "pallas" = the blocked Pallas
-    # kernel (scalar-prefetched block tables; interpret-mode on CPU).
-    # Read at trace time -> part of every generation compile key.
-    "FLAGS_paged_attention_kernel": "reference",
     # mesh-native SPMD runtime (paddle_tpu/mesh/, docs/spmd.md): a mesh
     # spec string ("dp4", "dp=4,mp=2", "dp4xmp2") builds a process-wide
     # default ShardingPlan that Executor / TrainStep / hapi / Predictor
@@ -258,11 +252,12 @@ _DEFS: Dict[str, Any] = {
     # geometry), keep only candidates whose token streams are
     # bitwise-identical to the reference form, pick the winner by
     # measured step time, and persist it in the program cache's
-    # policy/ sidecar. OFF by default; when on, the four geometry
+    # policy/ sidecar. OFF by default; when on, the three geometry
     # flags below become PINS (override precedence: explicitly-set
-    # flags / ctor args > persisted policy > defaults — MIGRATION.md):
-    #   FLAGS_paged_attention_kernel, FLAGS_generation_block_size,
-    #   FLAGS_generation_prefill_chunk, FLAGS_generation_token_budget
+    # flags / ctor args > persisted policy > defaults — MIGRATION.md;
+    # the kernel form is pinned by the engine's `kernel=` alone):
+    #   FLAGS_generation_block_size, FLAGS_generation_prefill_chunk,
+    #   FLAGS_generation_token_budget
     "FLAGS_autotune": False,
     # candidate budget: how many forms one tune may trial (the
     # reference/default form is always candidate #1; the Pallas kernel
@@ -385,7 +380,6 @@ _LOWERING_FLAGS = [
     "FLAGS_embedding_onehot_grad",
     "FLAGS_flash_attention_fallback",
     "FLAGS_flash_inkernel_dropout",
-    "FLAGS_paged_attention_kernel",
     # not read during lowering, but it changes the COMPILED executable
     # (jit donate_argnums): a mid-process flip must miss the caches
     "FLAGS_executor_donate_state",

@@ -116,7 +116,8 @@ from ..core import program_cache
 from ..failpoints import failpoint
 from .. import flags as _flags
 from ..flags import get_flag
-from ..kernels.paged_attention import kernel_form as _kernel_form
+from ..kernels.paged_attention import (kernel_form as _kernel_form,
+                                       resolved_form as _resolved_form)
 from ..inference import bucket_for, parse_bucket_ladder
 from ..monitor import gauge_set, stat_add, timer_observe
 from .kv_cache import (TRASH_BLOCK, BlockPoolExhausted, KVCacheManager,
@@ -281,7 +282,8 @@ class GenerationEngine:
                 pins[name] = cast(arg)
             elif _flags.explicitly_set(flag):
                 pins[name] = cast(get_flag(flag))
-        _pin("kernel", kernel, "FLAGS_paged_attention_kernel", str)
+        if kernel is not None:      # no flag: `kernel=` alone pins it
+            pins["kernel"] = str(kernel)
         _pin("block_size", block_size,
              "FLAGS_generation_block_size", int)
         _pin("prefill_chunk", prefill_chunk,
@@ -306,8 +308,14 @@ class GenerationEngine:
             if self._policy_entry is not None:
                 return cast(self._policy_entry[name])
             return cast(get_flag(flag))
-        self.kernel = _knob("kernel", "FLAGS_paged_attention_kernel",
-                            str)
+        # the form the steps will be traced in: a pin, the policy's
+        # winner, else what the backend resolves (the Pallas kernel on
+        # a TPU, the reference form elsewhere, an enclosing
+        # kernel_form block before either)
+        self.kernel = (pins["kernel"] if "kernel" in pins
+                       else str(self._policy_entry["kernel"])
+                       if self._policy_entry is not None
+                       else _resolved_form())
         if self.kernel not in ("reference", "pallas"):
             raise ValueError("unknown paged-attention kernel %r "
                              "(reference|pallas)" % self.kernel)
@@ -678,9 +686,9 @@ class GenerationEngine:
         base = (self.draft_cfg.meta() if kind.startswith("draft")
                 else self.cfg.meta())
         # v=4: ISSUE-16 adaptive dispatch — kern is the RESOLVED
-        # kernel form (the flag may say "reference" while the policy
-        # baked "pallas" via the kernel_form override, so the flag in
-        # lowering_snapshot no longer tells the whole story), and
+        # kernel form (a pin, the policy's winner or the backend's
+        # own, baked in through the kernel_form override: no flag of
+        # lowering_snapshot tells it), and
         # policy is the entry label that produced this geometry, which
         # is what makes zero-steady-state-recompiles provable across a
         # restart: a process that reloads the persisted policy builds
@@ -1172,6 +1180,12 @@ class GenerationEngine:
             # the cache up to and with its own token
             stat_add("STAT_generation_attended_tokens",
                      int(positions[:slot].sum()) + slot)
+            # ... and the pool blocks those contexts span: what the
+            # Pallas kernel copies (the reference form gathers every
+            # slot's whole table, token_budget x max_blocks_per_seq)
+            stat_add("STAT_generation_attended_blocks",
+                     int((positions[:slot] // self.kv.block_size + 1)
+                         .sum()))
             if self.k_scales is not None:
                 # this step's fresh K/V rows quantize inside the compiled
                 # call — the failpoint models a fault in that stage, and it

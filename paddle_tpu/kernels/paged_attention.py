@@ -10,21 +10,26 @@ current context length. Because the pool, the tables, and the decode
 batch are all fixed-shape, the decode step compiles ONCE and every
 mixed-length continuous batch reuses it.
 
-Two execution paths, selected by FLAGS_paged_attention_kernel:
+Two execution paths. The backend selects one (`resolved_form()`: the
+kernel on a TPU, the reference form elsewhere); `kernel_form(...)` and
+the engine's `kernel=` argument pin either, and there is no flag:
 
-- "reference" (default): gather + masked softmax in plain XLA. This is
+- "reference": gather + masked softmax in plain XLA. This is
   the parity oracle — `attend_reference` here is the SAME function the
   generation model's full-context forward uses, so a paged decode
   step agrees with a full-context recompute of the same position to
   within rounding (masked lanes contribute exp(-1e30 - m) == 0.0
-  exactly, and adding exact zeros never perturbs the reduction).
-- "pallas": the blocked kernel below — grid over (batch, blocks),
-  block tables scalar-prefetched so each grid step's BlockSpec
-  index_map DMAs exactly one pool block into VMEM, online-softmax
-  (m, l, acc) carried in VMEM scratch across the sequential grid.
-  Interpret mode runs it on CPU; on TPU hardware the same structure is
-  the Mosaic-ready seam (one block resident at a time, MXU dots, no
-  [S] contiguous KV ever materialized).
+  exactly, and adding exact zeros never perturbs the reduction). It
+  gathers every slot's WHOLE table: its cost follows the table's
+  width, not the tokens held.
+- "pallas": the blocked kernel below — a grid step a slot and inside
+  it a loop over the slot's LIVE blocks, G at a time; block tables
+  scalar-prefetched, the pools left in HBM and only live blocks
+  copied into VMEM, one async copy each, the next group in flight
+  while this one is attended over; online-softmax (m, l, acc) carried
+  in VMEM scratch across the loop; float32 products on the MXU with
+  the heads flat, as the pool stores them. Its cost follows the tokens a slot attends
+  over. Interpret mode runs it on CPU for its own tests.
 
 Layouts: q `[B, H, D]` (one new token per sequence), pools
 `[N, block_size, H, D]`, block_tables `[B, max_blocks]` int32,
@@ -253,137 +258,203 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: one pool block in VMEM per grid step
+# Pallas kernel: a slot per grid step, its LIVE blocks G at a time
 # ---------------------------------------------------------------------------
 
-def _tile(ref, heads):
-    """The resident pool block as float32 `[bs, H, D]`. A block of the
-    engine's stacked pools arrives flat, `[1, bs, H * D]`
-    (ragged_paged_attention_pallas, `layer`), and is split into heads
-    here, in VMEM, by lane slices (Mosaic has no such reshape, and
-    none of an int8 tile: hence the cast first); a `[1, bs, H, D]`
-    block is taken as it is."""
-    t = ref[0].astype(jnp.float32)
-    if t.ndim == 3:
-        return t
-    d = t.shape[1] // heads
-    return jnp.stack([t[:, i * d:(i + 1) * d] for i in range(heads)],
-                     axis=1)
+# Fast memory the kernel may hold K and V tiles in: two buffers each
+# (the group computed on and the group in flight). The number of pool
+# blocks a step of the kernel's loop handles follows from it
+# (blocks_per_step).
+_KV_VMEM_BUDGET = 4 * 1024 * 1024
+# a loop step's lanes, one vreg row of scores a head: beyond this a
+# group only adds masked work for the contexts that end inside it
+# (on the chip, the attention of one step's layers at the benchmark's
+# two geometries: 1.96 / 1.58 / 1.62 / 1.95 ms and 11.7 / 9.4 / 9.8 /
+# 15.8 ms at 64 / 128 / 256 / 512 lanes; PERF.md, PR 32)
+_MAX_STEP_TOKENS = 128
+
+
+def blocks_per_step(block_size: int, row_bytes: int,
+                    max_blocks: int) -> int:
+    """G, the pool blocks one step of the kernel's loop attends over:
+    the largest power of two whose K and V tiles, two buffers each,
+    fit `_KV_VMEM_BUDGET`, at most `_MAX_STEP_TOKENS` positions and at
+    most the table's width. Derived from what the call can see (a
+    row's bytes `H * D * itemsize`, the block size, the table) — no
+    flag and no argument: 8 blocks of GPT-2's 48 KB (float32 rows of
+    768) and of the looped family's 64 KB (bfloat16 rows of 2,048),
+    1.5 and 2 MiB of tiles; the budget binds from rows of 8 KB on."""
+    g = _KV_VMEM_BUDGET // (4 * block_size * row_bytes)
+    g = min(g, _MAX_STEP_TOKENS // block_size, max_blocks)
+    return 1 << (max(g, 1).bit_length() - 1)
+
+
+def _pieces(x):
+    """float32 `x` as three bfloat16 addends, largest first. The MXU
+    multiplies bfloat16; three addends carry a float32's 24 bits, so
+    products of such pieces summed in float32 are the float32 product
+    (what `Precision.HIGHEST` does). A value that IS a bfloat16 (a
+    bfloat16 or int8/fp8 pool's row) is its own one piece."""
+    hi = x.astype(jnp.bfloat16)
+    x = x - hi.astype(jnp.float32)
+    mid = x.astype(jnp.bfloat16)
+    return [hi, mid, (x - mid.astype(jnp.float32)).astype(jnp.bfloat16)]
+
+
+def _dot_pieces(lhs3, rhs_pieces, contract_rhs):
+    """sum over the piece pairs that matter of lhs_i . rhs_j, float32.
+    `lhs3` holds the left side's pieces stacked along rows, `[3 *
+    rows, C]`; piece j of the right side meets the first `3 - j` of
+    them (the six products of a float32 x float32 at full precision,
+    three where the right side is exact in bfloat16): one MXU pass per
+    right piece, the left pieces riding as extra rows."""
+    rows = lhs3.shape[0] // 3
+    total = None
+    for j, r in enumerate(rhs_pieces):
+        n = 3 - j
+        y = jax.lax.dot_general(
+            lhs3[:n * rows], r, (((1,), (contract_rhs,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        for i in range(n):
+            part = y[i * rows:(i + 1) * rows]
+            total = part if total is None else total + part
+    return total
 
 
 def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
-                   k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                   block_size, sm_scale, num_blocks):
-    """Grid (B, max_blocks): sequential online-softmax over the
-    sequence's blocks, Cq queries per row. tables/q_lens/ctx_lens (and
-    the stacked pools' layer, which only the index maps read) arrive
-    via scalar prefetch — the index maps already used tables_ref to
-    pick this (k, v) block, so the body only handles the causal chunk
-    mask and the (m, l, acc) recurrence carried per (head, query)."""
-    b = pl.program_id(0)
-    mi = pl.program_id(1)
+                   *refs, block_size, sm_scale, group, heads, quant):
+    """Grid (B,): one grid step a slot, and inside it a loop over the
+    slot's LIVE groups of G table entries — entries below `ceil((ctx +
+    q_len) / block_size)` — so that nothing is run, and nothing
+    copied, for the rest of the table. The pools stay in HBM; each live
+    block is one async copy into a `[2, G, bs, H * D]` buffer, and
+    while one group is attended over the next live group (of this
+    slot, or the first of the next slot) is in flight into the other
+    buffer. A parked slot (trash block, position 0) costs one block.
 
-    @pl.when(mi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    The heads stay FLAT, as the pool holds them. Row `h` of the
+    block-diagonal query `[Hp, H * D]` holds head h's query in lanes
+    `h * D .. (h + 1) * D` and zeros elsewhere, so ONE product with the
+    `[T, H * D]` key tile gives every head's scores `[Hp, T]` (heads
+    on sublanes, positions on lanes), and `p @ V` gives `[Hp, H * D]`
+    whose diagonal blocks are the heads' outputs — no split of a tile
+    into heads, which on the TPU is a relayout of it. Products are
+    float32 (_pieces); the running max, sum and accumulator are
+    float32 scratch carried across the slot's groups."""
+    if quant:
+        k_hbm, v_hbm, ks_ref, vs_ref, *refs = refs
+    else:
+        k_hbm, v_hbm, *refs = refs
+    (o_ref, kbuf, vbuf, sems, slot_ref, q3_ref, acc_ref, m_ref,
+     l_ref) = refs
+    streams = ((k_hbm, kbuf), (v_hbm, vbuf))
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    g_, bs = group, block_size
+    max_blocks = tables_ref.shape[1]
+    cq, width = q_ref.shape[1], q_ref.shape[2]
+    d = width // heads
+    rows = acc_ref.shape[0]                   # Cq * Hp
+    hp = rows // cq
+    t = g_ * bs
+    lyr = layer_ref[0]
+
+    def live_blocks(bi):
+        """Table entries slot `bi` attends over: up to its chunk's last
+        visible key, at least one (a parked slot's trash block)."""
+        n = (lens_ref[bi] + qlens_ref[bi] + bs - 1) // bs
+        return jnp.clip(n, 1, max_blocks)
+
+    def copies(bi, gi, buf_i, go):
+        """Start (or wait for) the copies of group `gi` of slot `bi`
+        into buffer `buf_i`: one per live block and pool."""
+        n = live_blocks(bi)
+        for g in range(g_):
+            @pl.when(gi * g_ + g < n)
+            def _():
+                # waiting needs the copy's shape only, not its source
+                blk = tables_ref[bi, gi * g_ + g] if go == "start" else 0
+                for si, (hbm, buf) in enumerate(streams):
+                    cp = pltpu.make_async_copy(
+                        hbm.at[lyr, blk], buf.at[buf_i, g],
+                        sems.at[buf_i, si])
+                    cp.start() if go == "start" else cp.wait()
+
+    @pl.when(b == 0)
+    def _first():
+        # lanes of a buffer no copy has reached are masked, but a
+        # masked p of 0.0 times whatever fast memory held is not 0.0
+        for _, buf in streams:
+            buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        copies(0, 0, 0, "start")
+
+    rowi = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
+    diag = (lane >= rowi * d) & (lane < (rowi + 1) * d)  # head h's lanes
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    q = q_ref[0].astype(jnp.float32) * sm_scale          # [Cq, H * D]
+    qb = jnp.concatenate(
+        [jnp.where(diag, q[j:j + 1], 0.0) for j in range(cq)], axis=0)
+    for i, piece in enumerate(_pieces(qb)):
+        q3_ref[i * rows:(i + 1) * rows] = piece
 
     ctx = lens_ref[b]
     qlen = qlens_ref[b]
+    n_groups = (live_blocks(b) + g_ - 1) // g_
 
-    # blocks entirely past the chunk's last visible key (position
-    # ctx + qlen - 1) contribute nothing; skipping the math (the DMA
-    # already happened) keeps the scratch recurrence exact for ragged
-    # lengths
-    @pl.when(mi * block_size < ctx + qlen)
-    def _body():
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # [Cq, H, D]
-        k = _tile(k_ref, q.shape[1])                     # [bs, H, D]
-        v = _tile(v_ref, q.shape[1])
-        # batch over heads, contract D: [H, Cq, bs]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32)
-        pos = mi * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where((pos <= ctx + qi) & (qi < qlen), s, NEG_INF)
-        m_prev = m_ref[...]                              # [H, Cq]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-        p = jnp.exp(s - m_new[:, :, None])
-        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+    def tile(buf, buf_i):
+        """The group's rows `[T, H * D]`, exact as bfloat16 pieces."""
+        x = buf[buf_i]
+        if x.dtype == jnp.bfloat16:
+            return [x.reshape(t, width)]
+        if quant:           # int8 / fp8 values ARE bfloat16 values
+            return [x.astype(jnp.float32).astype(jnp.bfloat16)
+                    .reshape(t, width)]
+        return _pieces(x.reshape(t, width))
+
+    def attend(gi, buf_i):
+        more = gi + 1 < n_groups
+        nbi = jnp.where(more, b, b + 1)
+        ngi = jnp.where(more, gi + 1, 0)
+
+        @pl.when(nbi < nb)
+        def _prefetch():
+            copies(nbi, ngi, 1 - buf_i, "start")
+        copies(b, gi, buf_i, "wait")
+        s = _dot_pieces(q3_ref[...], tile(kbuf, buf_i), 1)
+        if quant:       # dequant: stored * scale / GRID, a key a head
+            s = s * jnp.concatenate([ks_ref[0, gi]] * cq, axis=0)
+        pos = gi * t + jax.lax.broadcasted_iota(jnp.int32, (hp, t), 1)
+        mask = jnp.concatenate(
+            [(pos <= ctx + j) & (j < qlen) for j in range(cq)], axis=0)
+        s = jnp.where(mask, s, NEG_INF)                  # [rows, T]
+        m_prev = m_ref[...]                              # [rows, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                  keepdims=True)
         m_ref[...] = m_new
-        # [H, Cq, bs] x [bs, H, D] -> [H, Cq, D]: batch over H
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, :, None] + pv
+        if quant:       # (a masked lane's scale may be anything)
+            p = jnp.where(mask, p * jnp.concatenate([vs_ref[0, gi]] * cq,
+                                                    axis=0), 0.0)
+        p3 = jnp.concatenate(_pieces(p), axis=0)         # [3 rows, T]
+        pv = _dot_pieces(p3, tile(vbuf, buf_i), 0)       # [rows, H*D]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        return 1 - buf_i
 
-    @pl.when(mi == num_blocks - 1)
-    def _finish():
-        l = l_ref[...]
-        l_safe = jnp.where(l <= 0.0, 1.0, l)
-        o_ref[0] = jnp.transpose(acc_ref[...] / l_safe[:, :, None],
-                                 (1, 0, 2)).astype(o_ref.dtype)
+    slot_ref[0] = jax.lax.fori_loop(0, n_groups, attend, slot_ref[0])
 
-
-def _ragged_kernel_quant(tables_ref, qlens_ref, lens_ref, layer_ref,
-                         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, block_size, sm_scale,
-                         num_blocks, inv_grid):
-    """Quantized-KV twin of _ragged_kernel: the block's int8/fp8 K/V
-    tile arrives in VMEM with its `[bs, H]` absmax scale rows (same
-    tbl[bi, mi] index maps), and dequant (stored * scale / GRID) runs
-    INSIDE the online-softmax loop — the fp32 KV never exists outside
-    this block's VMEM residency, which is the whole HBM win."""
-    b = pl.program_id(0)
-    mi = pl.program_id(1)
-
-    @pl.when(mi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    ctx = lens_ref[b]
-    qlen = qlens_ref[b]
-
-    @pl.when(mi * block_size < ctx + qlen)
-    def _body():
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # [Cq, H, D]
-        # in-loop dequant: [bs, H, D] stored * [bs, H, 1] scale/GRID
-        k = _tile(k_ref, q.shape[1]) \
-            * (ks_ref[0].astype(jnp.float32) * inv_grid)[:, :, None]
-        v = _tile(v_ref, q.shape[1]) \
-            * (vs_ref[0].astype(jnp.float32) * inv_grid)[:, :, None]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32)
-        pos = mi * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where((pos <= ctx + qi) & (qi < qlen), s, NEG_INF)
-        m_prev = m_ref[...]                              # [H, Cq]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-        p = jnp.exp(s - m_new[:, :, None])
-        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, :, None] + pv
-
-    @pl.when(mi == num_blocks - 1)
-    def _finish():
-        l = l_ref[...]
-        l_safe = jnp.where(l <= 0.0, 1.0, l)
-        o_ref[0] = jnp.transpose(acc_ref[...] / l_safe[:, :, None],
-                                 (1, 0, 2)).astype(o_ref.dtype)
+    l = l_ref[...]
+    o = acc_ref[...] / jnp.where(l <= 0.0, 1.0, l)       # [rows, H*D]
+    o_ref[0] = jnp.concatenate(
+        [jnp.sum(jnp.where(diag, o[j * hp:(j + 1) * hp], 0.0),
+                 axis=0, keepdims=True) for j in range(cq)],
+        axis=0).astype(o_ref.dtype)
 
 
 def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
@@ -392,84 +463,101 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                   interpret: Optional[bool] = None,
                                   k_scales=None, v_scales=None,
                                   layer: Optional[int] = None):
-    """Blocked ragged kernel: same grid over (sequence, pool block) as
-    the decode kernel, but each VMEM tile scores the whole Cq-wide
-    chunk against one resident block, so prefill chunks and decode
-    singles share one executable shape. Quantized pools (k_scales /
-    v_scales given) route to the _ragged_kernel_quant twin — the fp32
-    kernel is untouched so the quant-off executable stays identical.
+    """The blocked ragged kernel (_ragged_kernel): a grid step a slot,
+    scoring the slot's whole Cq-wide chunk against its live blocks, G
+    at a time, so prefill chunks and decode singles share one
+    executable shape and a slot costs what its context spans, not what
+    its table could hold.
+
+    Quantized pools (k_scales / v_scales given) run the same body: an
+    int8/fp8 block is copied as it is stored, and the dequant (stored
+    * scale / GRID) is folded into the scores and the probabilities
+    inside the loop — the fp32 KV never exists. The `[bs, H]` absmax
+    scale rows (a sixteenth of their int8 rows' bytes at D = 64) are
+    gathered through the tables beforehand, heads on sublanes and
+    positions on lanes, the layout the scores have: their minor axis
+    of H is no copy's shape on the TPU.
+
     With `layer` the pools are the engine's stacked arrays, `[layers,
-    N, bs, H * D]` with the heads flat (scales `[layers, N, bs, H]`):
-    the index maps put `layer` in front of the table's block, the
-    layer axis is squeezed out of the tile, and the kernel bodies
-    split the flat `[1, bs, H * D]` block into heads (_tile). `layer`
-    is a scalar-prefetch operand like the tables, so it may be a
-    TRACED scalar: the looped family's layer loop
-    (generation/looped.py) reads cache slot `pass * layers + layer`
-    from inside a `lax.scan`, where an index map could not close over
-    it."""
+    N, bs, H * D]` with the heads flat (scales `[layers, N, bs, H]`),
+    which is the layout the kernel works in; a single layer's `[N, bs,
+    H, D]` pool (tests) is viewed as one. `layer` is a scalar-prefetch
+    operand like the tables, so it may be a TRACED scalar: the looped
+    family's layer loop (generation/looped.py) reads cache slot `pass
+    * layers + layer` from inside a `lax.scan`."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = _use_interpret()
     b, cq, h, d = q.shape
-    bs = k_pool.shape[1 if layer is None else 2]
+    quant = k_scales is not None
+    if layer is None:
+        n, bs = k_pool.shape[:2]
+        k_pool = k_pool.reshape(1, n, bs, h * d)
+        v_pool = v_pool.reshape(1, n, bs, h * d)
+        if quant:
+            k_scales = k_scales.reshape(1, n, bs, h)
+            v_scales = v_scales.reshape(1, n, bs, h)
+        layer = 0
+    bs, width = k_pool.shape[2:]
     m = block_tables.shape[1]
+    g = blocks_per_step(bs, width * k_pool.dtype.itemsize, m)
+    hp = -(-h // 16) * 16          # a bfloat16 tile's sublanes
+    rows = cq * hp
+    groups = -(-m // g)
+    idx = lambda bi, tbl, qls, lens, lyr: (bi, 0, 0)  # noqa: E731
+    row_spec = pl.BlockSpec((1, cq, width), idx)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [row_spec, hbm, hbm]
+    operands = [q.reshape(b, cq, width), k_pool, v_pool]
+    if quant:
+        inv = _inv_grid(k_pool.dtype)
 
-    def pool_spec(*tail):
-        """One table-picked block of a pool: `(1, bs, *tail)` tiles."""
-        zeros = (0,) * (1 + len(tail))
-        if layer is None:
-            return pl.BlockSpec(
-                (1, bs) + tail,
-                lambda bi, mi, tbl, qls, lens, lyr:
-                (tbl[bi, mi],) + zeros)
-        return pl.BlockSpec(
-            (None, 1, bs) + tail,
-            lambda bi, mi, tbl, qls, lens, lyr:
-            (lyr[0], tbl[bi, mi]) + zeros)
-    kv_spec = pool_spec(h, d) if layer is None else pool_spec(h * d)
-    in_specs = [
-        pl.BlockSpec((1, cq, h, d),
-                     lambda bi, mi, tbl, qls, lens, lyr: (bi, 0, 0, 0)),
-        kv_spec,
-        kv_spec,
+        def scale_rows(sc):           # [B, groups, Hp, G * bs]
+            sc = sc[layer, block_tables].reshape(b, m * bs, h) * inv
+            sc = jnp.pad(jnp.transpose(sc, (0, 2, 1)),
+                         ((0, 0), (0, hp - h),
+                          (0, (groups * g - m) * bs)))
+            return jnp.transpose(sc.reshape(b, hp, groups, g * bs),
+                                 (0, 2, 1, 3))
+        in_specs += [pl.BlockSpec(
+            (1, groups, hp, g * bs),
+            lambda bi, tbl, qls, lens, lyr: (bi, 0, 0, 0))] * 2
+        operands += [scale_rows(k_scales), scale_rows(v_scales)]
+    scratch = [
+        pltpu.VMEM((2, g, bs, width), k_pool.dtype),
+        pltpu.VMEM((2, g, bs, width), v_pool.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((1,), jnp.int32),               # the buffer in use
+        pltpu.VMEM((3 * rows, width), jnp.bfloat16),  # query pieces
+        pltpu.VMEM((rows, width), jnp.float32),    # acc
+        pltpu.VMEM((rows, 1), jnp.float32),        # running max
+        pltpu.VMEM((rows, 1), jnp.float32),        # running denom
     ]
-    operands = [q, k_pool, v_pool]
-    if k_scales is not None:
-        # scale rows ride the SAME block-table index map as their
-        # payload tile, one [bs, H] row set per resident block
-        in_specs += [pool_spec(h), pool_spec(h)]
-        operands += [k_scales, v_scales]
-        kern = functools.partial(
-            _ragged_kernel_quant, block_size=bs, sm_scale=sm_scale,
-            num_blocks=m, inv_grid=_inv_grid(k_pool.dtype))
-    else:
-        kern = functools.partial(_ragged_kernel, block_size=bs,
-                                 sm_scale=sm_scale, num_blocks=m)
+    kern = functools.partial(
+        _ragged_kernel, block_size=bs, sm_scale=sm_scale, group=g,
+        heads=h, quant=quant)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # block_tables, q_lens, ctx_lens, layer
-        grid=(b, m),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, cq, h, d),
-            lambda bi, mi, tbl, qls, lens, lyr: (bi, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, cq, d), jnp.float32),   # acc
-            pltpu.VMEM((h, cq), jnp.float32),      # running max
-            pltpu.VMEM((h, cq), jnp.float32),      # running denom
-        ],
+        out_specs=row_spec,
+        scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, cq, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, cq, width), q.dtype),
+        # the copies of a slot's first group start in the slot before
+        # it: the grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
     )(block_tables.astype(jnp.int32), q_lens.astype(jnp.int32),
       ctx_lens.astype(jnp.int32),
-      jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1),
-      *operands)
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+    return out.reshape(b, cq, h, d)
 
 
 def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens,
@@ -489,23 +577,24 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens,
 
 
 # ---------------------------------------------------------------------------
-# public entry: flag-routed seam (+ the autotune override)
+# public entry: the form follows the backend (+ the trace-scoped pin)
 # ---------------------------------------------------------------------------
 
-# Trace-scoped kernel-form override (paddle_tpu/autotune.py): the
-# dispatch policy's winning form must be bakeable into a compile
-# WITHOUT flipping the process-global flag (two engines in one process
-# may resolve different forms). The engine wraps its trace-time
-# construction in kernel_form(...); the flag stays the default route
-# and the compile-key story is unchanged — the engine puts the
-# RESOLVED form into its program fingerprint meta (kern=..., v=4).
+# Trace-scoped kernel-form pin: the engine's `kernel=` argument, the
+# dispatch policy's winning form (paddle_tpu/autotune.py) and the
+# tests must be able to bake a form into a compile without a
+# process-global switch (two engines in one process may resolve
+# different forms). The engine wraps its trace-time construction in
+# kernel_form(...) and puts the RESOLVED form into its program
+# fingerprint meta (kern=...), so a cached program of one form never
+# serves the other.
 _FORM_OVERRIDE: Optional[str] = None
 
 
 class kernel_form:
     """Context manager pinning the kernel form ("reference"|"pallas")
     for computations TRACED inside the block. None passes through to
-    FLAGS_paged_attention_kernel."""
+    what the backend resolves (resolved_form)."""
 
     __slots__ = ("form", "_prev")
 
@@ -527,22 +616,23 @@ class kernel_form:
 
 def resolved_form() -> str:
     """The kernel form the next trace will bake in: the active
-    kernel_form override, else FLAGS_paged_attention_kernel."""
+    kernel_form pin, else the backend's — the Pallas kernel on a TPU,
+    the reference form everywhere else (XLA:CPU, where the kernel
+    would run interpreted, and every oracle)."""
     if _FORM_OVERRIDE is not None:
         return _FORM_OVERRIDE
-    from ..flags import get_flag
-    return str(get_flag("FLAGS_paged_attention_kernel"))
+    return "pallas" if jax.default_backend() == "tpu" else "reference"
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                     sm_scale: Optional[float] = None,
                     k_scales=None, v_scales=None,
                     layer: Optional[int] = None):
-    """Decode-step attention over the paged KV pool. Routed by
-    FLAGS_paged_attention_kernel (a lowering flag: it is baked into
-    every generation compile key), subject to the kernel_form override
-    above: "reference" is the parity path (the oracle's own attention
-    core); "pallas" runs the blocked kernel (interpret mode off-TPU).
+    """Decode-step attention over the paged KV pool, in the form
+    resolved_form() answers: "pallas", the blocked kernel, on a TPU
+    (interpret mode where a test pins it elsewhere); "reference", the
+    parity path (the oracle's own attention core), on every other
+    backend; a kernel_form block pins either.
     k_scales/v_scales
     (quantized pools, paddle_tpu/quant) flow to the dequant-fused
     forms of both paths; None = the untouched fp32 path. `layer`
@@ -571,8 +661,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
                            layer: Optional[int] = None):
     """Mixed prefill+decode attention over the paged KV pool: q
     `[B, Cq, H, D]` with per-row true query length (1 = decode, chunk
-    width = prefill). Routed by the same FLAGS_paged_attention_kernel
-    seam (+ kernel_form override) as the decode entry; k_scales /
+    width = prefill). Routed like the decode entry (resolved_form:
+    the backend's form, or a kernel_form pin); k_scales /
     v_scales select the quantized-KV dequant-fused forms."""
     mode = resolved_form()
     with jax.named_scope("paged_attention"):

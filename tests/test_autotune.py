@@ -271,15 +271,19 @@ def test_quant_modes_never_share_a_policy(tmp_path, params):
         {"off", "int8"}
 
 
-def test_tuned_flags_excluded_from_policy_fingerprint():
-    """The knobs the policy CHOOSES cannot fragment its key space —
-    flipping FLAGS_paged_attention_kernel must not change the policy
-    fingerprint (pins ride the key meta instead)."""
-    from paddle_tpu.flags import set_flags
-    meta = {"kind": "generation", "backend": "cpu"}
-    fp = program_cache.policy_fingerprint(meta)
-    set_flags({"FLAGS_paged_attention_kernel": "pallas"})
-    assert program_cache.policy_fingerprint(meta) == fp
+def test_pinned_kernel_rides_the_policy_key_meta(tmp_path, params):
+    """No flag names the kernel form, so the policy's key holds no
+    knob the policy chooses; a form pinned by `kernel=` rides the key
+    meta's pins and isolates its own entry."""
+    from paddle_tpu.flags import lowering_snapshot
+    assert not [k for k, _ in lowering_snapshot() if "paged" in k]
+    meta = {"kind": "generation", "backend": "cpu", "pins": {}}
+    pinned = dict(meta, pins={"kernel": "pallas"})
+    assert program_cache.policy_fingerprint(meta) != \
+        program_cache.policy_fingerprint(pinned)
+    _engine(params, program_cache_dir=str(tmp_path / "c"))
+    pins = [json.loads(k)["pins"] for k in autotune._POLICY._table]
+    assert pins and all(p["kernel"] == "reference" for p in pins)
 
 
 # ---------------------------------------------------------------------------

@@ -215,24 +215,113 @@ def _assert_sorts_inside_the_one_conditional(txt):
     assert not sorting & always, sorting & always
 
 
+@pytest.mark.parametrize("form", ["reference", "pallas"])
 def test_gpt2_mixed_step_sorts_only_inside_the_sampler_branch_for_v5e(
-        one_chip):
+        one_chip, monkeypatch, form):
     """GPT-2 small's whole mixed step at the sizes of the benchmark's
     cell `gpt2_124m_chat_c32` (40 slots, 32 sampler rows over the
     vocabulary of 50,257, a float32 pool of 32,768 tokens): the sorts
     of `[32, 50257]` lie behind the sampler's conditional, both pools
-    are aliased."""
+    are aliased, and in the form a TPU resolves every layer's
+    attention is one kernel call over the pool where it lies."""
     from paddle_tpu.generation import DecoderConfig, init_params
+    from paddle_tpu.kernels import paged_attention as pa
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     cfg = DecoderConfig(vocab_size=50257, hidden=768, layers=12, heads=12,
                         max_seq_len=1024)
-    compiled = _compile_mixed_step(
-        cfg, one_chip, jax.eval_shape(lambda: init_params(cfg, seed=0)),
-        _sds((12, 2048, 16, 768), jnp.float32), t=40, m=1024 // 16, sw=32)
+    with pa.kernel_form(form):
+        compiled = _compile_mixed_step(
+            cfg, one_chip,
+            jax.eval_shape(lambda: init_params(cfg, seed=0)),
+            _sds((12, 2048, 16, 768), jnp.float32), t=40, m=1024 // 16,
+            sw=32)
     txt = compiled.as_text()
     _assert_sorts_inside_the_one_conditional(txt)
+    assert txt.count("tpu_custom_call") == (12 if form == "pallas" else 0)
+    made = re.findall(r"= f32\[12,2048,16,768\]\S* ([\w\-]+)\(", txt)
+    assert set(made) <= {"parameter", "get-tuple-element", "fusion",
+                         "scatter", "dynamic-update-slice"}, set(made)
     assert re.search(r"= \(f32\[32,50257\][^=]* sort\(", txt)
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 2 * 12 * 2048 * 16 * 768 * 4
+
+
+# The grouped kernel alone at BOTH serve cells' geometry, with the G and
+# the fast memory its shapes derive: `gpt2_124m_chat_c32` (float32 rows
+# of 768, 40 slots of 64 table entries, a static layer) and
+# `ouro_2_6b_reason_c16` (bfloat16 rows of 2,048, 24 slots of 32
+# entries, the layer a traced scalar).
+@pytest.mark.parametrize("pool,slots,entries,heads,traced,g,vmem", [
+    (_sds((12, 2048, 16, 768), jnp.float32), 40, 64, 12, False,
+     8, 3 * 2 ** 19),
+    (_sds((192, 320, 16, 2048), jnp.bfloat16), 24, 32, 16, True,
+     8, 2 * 2 ** 20)], ids=["gpt2_124m_chat_c32", "ouro_2_6b_reason_c16"])
+def test_grouped_kernel_compiles_at_the_cells_geometry_for_v5e(
+        one_chip, pool, slots, entries, heads, traced, g, vmem):
+    from paddle_tpu.kernels import paged_attention as pa
+    bs, width = pool.shape[2:]
+    row_bytes = width * pool.dtype.itemsize
+    assert pa.blocks_per_step(bs, row_bytes, entries) == g
+    # K and V tiles of G blocks, two buffers each
+    assert 4 * g * bs * row_bytes == vmem <= pa._KV_VMEM_BUDGET
+
+    def attend(q, kp, vp, tables, ctx, layer):
+        return pa.paged_attention_pallas(
+            q, kp, vp, tables, ctx, interpret=False,
+            layer=layer if traced else 7)
+    txt = _compile(attend, one_chip,
+                   _sds((slots, heads, width // heads), jnp.float32),
+                   pool, pool, _sds((slots, entries), jnp.int32),
+                   _sds((slots,), jnp.int32), _sds((), jnp.int32))
+    assert txt.count("tpu_custom_call") == 1
+    # the pools are read where they lie: nothing pool-shaped is made
+    shape = ",".join(map(str, pool.shape))
+    assert not re.search(r"= \w+\[%s\]\S* (?!parameter)" % shape, txt)
+
+
+def test_the_form_follows_the_backend_and_rides_the_fingerprint(
+        monkeypatch):
+    """The route: `resolved_form()` answers from the backend (the
+    kernel on a TPU, the reference form elsewhere), `kernel_form(...)`
+    and the engine's `kernel=` pin it, and the form the engine
+    resolved is in its programs' compile key."""
+    from paddle_tpu.core import program_accounting
+    from paddle_tpu.generation import (DecoderConfig, GenerationEngine,
+                                       init_params)
+    from paddle_tpu.kernels import paged_attention as pa
+    cfg = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=2,
+                        max_seq_len=64)
+    params = init_params(cfg, 0)
+
+    def engine(**kw):
+        return GenerationEngine(cfg, params, decode_width=2,
+                                num_blocks=16, **kw)
+    assert pa.resolved_form() == "reference"           # XLA:CPU
+    assert engine().kernel == "reference"
+    with pa.kernel_form("pallas"):
+        assert pa.resolved_form() == "pallas"
+        assert engine().kernel == "pallas"
+        assert engine(kernel="reference").kernel == "reference"
+    with monkeypatch.context() as on_a_tpu:
+        on_a_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        assert pa.resolved_form() == "pallas"
+        assert engine().kernel == "pallas"
+        assert engine(kernel="reference").kernel == "reference"
+        with pa.kernel_form("reference"):
+            assert pa.resolved_form() == "reference"
+    with pytest.raises(ValueError, match="unknown paged-attention"):
+        engine(kernel="mosaic")
+    metas = []
+    real = program_accounting.accounted
+    monkeypatch.setattr(
+        program_accounting, "accounted",
+        lambda jitted, avals, *, tag, key="", meta=None:
+        metas.append((meta["kern"], key)) or
+        real(jitted, avals, tag=tag, key=key, meta=meta))
+    for form in ("reference", "pallas"):
+        engine(kernel=form)._get_fn("cow")
+    assert [m[0] for m in metas] == ["reference", "pallas"]
+    assert metas[0][1] != metas[1][1]
 
 
 # The looped family (generation/looped.py) at the sizes of the benchmark's

@@ -130,20 +130,32 @@ def test_engine_step_lands_in_the_trace_with_its_five_children(traced):
         assert all(len(v) == 1 for v in kids.values())
 
 
-def test_the_children_cover_the_engine_step(traced):
+@pytest.mark.parametrize("taken_out", [(), ("pt/engine/fetch",)],
+                         ids=["all_children", "without_fetch"])
+def test_the_children_cover_the_engine_step(traced, taken_out):
+    """What the children leave is the step's self time: a few statements
+    between them. Held over the SUM of the steps: a toy step on XLA:CPU
+    lasts 2 ms, and one preemption of 0.3 ms under the suite's six
+    workers takes a single step under any share worth holding. Read
+    over five runs alone: the children 99.1 % of the steps (`fetch` 87,
+    `plan` 6.5, `dispatch` 4.5, `emit` 1); under the suite's load a
+    step lasts 13 ms, the children cover 99.7 % and `fetch`, the wait
+    for XLA:CPU, is 95 % of it. With that child taken out (the planted
+    fault: a span that went missing) the rest must NOT cover; the
+    small children's shares shrink under load, so no limit on the sum
+    tells one of THEM missing."""
     line = next(l for l in traced["lines"]
                 if any(e[0] == "pt/engine/step" for e in l))
-    shares = []
+    steps = covered = 0
     for (s, d), kids in _children(line, "pt/engine/step"):
         if "pt/engine/dispatch" not in kids or \
                 "pt/engine/fetch" not in kids:
             continue
-        shares.append(sum(cd for v in kids.values() for _, cd in v) / d)
-    # what is left is the step's self time: a few statements between the
-    # children. A toy step on XLA:CPU lasts 2 ms, so that a scheduling hiccup
-    # of 0.1 ms shows; the middle step is held to the 95 %, each to 90 %
-    assert sorted(shares)[len(shares) // 2] >= 0.95, shares
-    assert min(shares) >= 0.90, shares
+        steps += d
+        covered += sum(cd for n, v in kids.items() if n not in taken_out
+                       for _, cd in v)
+    assert steps > 0
+    assert (covered / steps >= 0.95) == (not taken_out), covered / steps
 
 
 def test_trainstep_call_lands_in_the_trace_with_its_two_children(traced):

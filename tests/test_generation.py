@@ -582,17 +582,28 @@ def test_paged_attention_pallas_matches_reference():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_paged_attention_flag_seam(params):
-    """FLAGS_paged_attention_kernel is a lowering flag: flipping it is
-    visible in lowering_snapshot (compile keys miss, never stale)."""
-    from paddle_tpu.flags import get_flags, lowering_snapshot, set_flags
-    prior = get_flags(["FLAGS_paged_attention_kernel"])
-    snap0 = lowering_snapshot()
-    try:
-        set_flags({"FLAGS_paged_attention_kernel": "pallas"})
-        assert lowering_snapshot() != snap0
-    finally:
-        set_flags(prior)
+def test_paged_attention_kernel_pin_seam(params, monkeypatch):
+    """The engine's `kernel=` pins the form its steps are traced in,
+    and the form rides every step's compile key (`kern=`): pinned the
+    other way the key misses, never a stale program. No flag names
+    the form any more."""
+    from paddle_tpu.core import program_accounting
+    from paddle_tpu.flags import lowering_snapshot
+    assert not [k for k, _ in lowering_snapshot() if "paged" in k]
+    keys = {}
+    real = program_accounting.accounted
+
+    def accounted(jitted, avals, *, tag, key="", meta=None):
+        keys[meta["kern"]] = key
+        return real(jitted, avals, tag=tag, key=key, meta=meta)
+    monkeypatch.setattr(program_accounting, "accounted", accounted)
+    for form in ("reference", "pallas"):
+        eng = _engine(params, kernel=form)
+        assert eng.kernel == form
+        eng._get_fn("cow")
+    assert set(keys) == {"reference", "pallas"}
+    assert keys["reference"] != keys["pallas"]
+    assert _engine(params).kernel == "reference"      # XLA:CPU's own
 
 
 def test_decode_width_one_matches_width_four(params):

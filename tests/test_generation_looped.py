@@ -525,12 +525,17 @@ def test_a_closed_pool_lets_go_of_the_engine_and_its_device_state():
 def test_attended_tokens_counts_the_positions_the_live_slots_see():
     cfg = _cfg(2)
     eng = _engine(cfg, looped.init_params(cfg), prefix_cache=False)
+    assert eng.kv.block_size == 4
     eng.submit(GenerationRequest(prompt=list(range(5)), max_new_tokens=3))
     a0 = stat_get("STAT_generation_attended_tokens")
+    b0 = stat_get("STAT_generation_attended_blocks")
     eng.step()      # the 5 prompt tokens: positions 0..4 see 1..5 keys
     assert stat_get("STAT_generation_attended_tokens") - a0 == 15
+    # ... in blocks of 4: positions 0..3 span one block, position 4 two
+    assert stat_get("STAT_generation_attended_blocks") - b0 == 6
     eng.step()      # one decode slot at position 5
     assert stat_get("STAT_generation_attended_tokens") - a0 == 15 + 6
+    assert stat_get("STAT_generation_attended_blocks") - b0 == 6 + 2
 
 
 # ---------------------------------------------------------------------------

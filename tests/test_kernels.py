@@ -424,18 +424,15 @@ def test_ragged_pallas_interpret_matches_reference():
                 atol=2e-5, rtol=2e-5)
 
 
-def test_ragged_flag_seam():
-    """FLAGS_paged_attention_kernel routes the ragged entry exactly
-    like the decode entry."""
-    from paddle_tpu.flags import get_flags, set_flags
+def test_ragged_kernel_form_seam():
+    """A kernel_form pin routes the ragged entry exactly like the
+    decode entry; outside the block XLA:CPU takes the reference form,
+    to the bit."""
+    from paddle_tpu.kernels.paged_attention import kernel_form
     q, kp, vp, tbl, q_lens, ctx = _ragged_case(seed=7)
     ref = ragged_paged_attention_reference(q, kp, vp, tbl, q_lens, ctx)
-    prior = get_flags(["FLAGS_paged_attention_kernel"])
-    try:
-        set_flags({"FLAGS_paged_attention_kernel": "pallas"})
+    with kernel_form("pallas"):
         pal = ragged_paged_attention(q, kp, vp, tbl, q_lens, ctx)
-    finally:
-        set_flags(prior)
     for i in range(q.shape[0]):
         for j in range(int(q_lens[i])):
             np.testing.assert_allclose(
@@ -443,6 +440,169 @@ def test_ragged_flag_seam():
                 atol=2e-5, rtol=2e-5)
     routed = ragged_paged_attention(q, kp, vp, tbl, q_lens, ctx)
     assert np.array_equal(_bits(routed), _bits(ref))
+
+
+# ---------------------------------------------------------------------------
+# the grouped kernel (PR 32): G table entries a step of its loop, and no block
+# past a slot's length is read. Interpret mode, the engine's stacked
+# flat pools, against the reference form.
+# ---------------------------------------------------------------------------
+
+from paddle_tpu import quant as _quant  # noqa: E402
+from paddle_tpu.kernels import paged_attention as _pa  # noqa: E402
+
+_G_BS, _G_M, _G_H, _G_D, _G_LAYERS, _G_N = 4, 5, 2, 8, 3, 26
+_POOL_DTYPES = pytest.mark.parametrize(
+    "pool_dtype", ["float32", "bfloat16", "int8"])
+
+
+@pytest.fixture
+def groups_of_two(monkeypatch):
+    """Blocks of 4 positions, G = 2 (a group is 8 positions), tables
+    of 5 entries (not a multiple of G): the derivation that gives 8
+    at the cells' sizes, held to toy tiles."""
+    monkeypatch.setattr(_pa, "_MAX_STEP_TOKENS", 2 * _G_BS)
+    assert _pa.blocks_per_step(_G_BS, _G_H * _G_D * 4, _G_M) == 2
+
+
+def _stacked_pools(rng, pool_dtype):
+    """(k, v, k_scales, v_scales): `[layers, N, bs, H * D]` pools as the
+    engine holds them, float32 / bfloat16 / int8 with absmax scales."""
+    shape = (_G_LAYERS, _G_N, _G_BS, _G_H, _G_D)
+    flat = shape[:3] + (_G_H * _G_D,)
+    kf = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    if pool_dtype == "int8":
+        kq, ks = _quant.quantize_kv_rows(kf, jnp.int8)
+        vq, vs = _quant.quantize_kv_rows(vf, jnp.int8)
+        return kq.reshape(flat), vq.reshape(flat), ks, vs
+    dt = jnp.dtype(pool_dtype)
+    return kf.reshape(flat).astype(dt), vf.reshape(flat).astype(dt), \
+        None, None
+
+
+def _tables(positions, parked=()):
+    """Distinct blocks for every table entry (so a block past a slot's
+    length is nobody's live block); a parked slot holds the trash
+    block, 0, at position 0."""
+    b = len(positions)
+    tbl = 1 + np.arange(b * _G_M, dtype=np.int32).reshape(b, _G_M)
+    assert tbl.max() < _G_N
+    for i in parked:
+        tbl[i] = 0
+    return jnp.asarray(tbl)
+
+
+def _both_forms(q, pools, tbl, positions, layer=1):
+    kp, vp, ks, vs = pools
+    ctx = jnp.asarray(positions, jnp.int32) + 1
+    kw = dict(k_scales=ks, v_scales=vs, layer=layer)
+    return (np.asarray(_pa.paged_attention_pallas(q, kp, vp, tbl, ctx, **kw)),
+            np.asarray(paged_attention_reference(q, kp, vp, tbl, ctx, **kw)))
+
+
+@_POOL_DTYPES
+@pytest.mark.parametrize("position", [5, 3, 4, 9, 7, 8, 19, 0], ids=[
+    "inside_a_block", "last_of_a_block", "first_of_a_block",
+    "inside_the_second_group", "last_of_a_group", "first_of_a_group",
+    "table_full", "parked"])
+def test_grouped_kernel_matches_reference(groups_of_two, position,
+                                          pool_dtype):
+    """The slot under test between two batch-mates of other lengths,
+    the last a parked one."""
+    rng = np.random.default_rng(100 + position)
+    positions = [11, position, 0]
+    parked = (2,) + ((1,) if position == 0 else ())
+    q = jnp.asarray(rng.normal(size=(3, _G_H, _G_D)), jnp.float32)
+    pal, ref = _both_forms(q, _stacked_pools(rng, pool_dtype),
+                           _tables(positions, parked), positions)
+    np.testing.assert_allclose(pal, ref, atol=2e-5, rtol=2e-5)
+
+
+@_POOL_DTYPES
+def test_grouped_kernel_chunk_mates_share_a_table(groups_of_two,
+                                                  pool_dtype):
+    """Four slots of one prefill chunk at consecutive positions over a
+    group's edge, one table between them, and a decode lane."""
+    rng = np.random.default_rng(7)
+    positions = [6, 7, 8, 9, 13]
+    tbl = np.array(_tables(positions))
+    tbl[:4] = tbl[0]
+    q = jnp.asarray(rng.normal(size=(5, _G_H, _G_D)), jnp.float32)
+    pal, ref = _both_forms(q, _stacked_pools(rng, pool_dtype),
+                           jnp.asarray(tbl), positions)
+    np.testing.assert_allclose(pal, ref, atol=2e-5, rtol=2e-5)
+
+
+@_POOL_DTYPES
+def test_grouped_kernel_reads_no_block_past_a_length(groups_of_two,
+                                                     pool_dtype):
+    """Every block past each slot's length poisoned (NaN rows; an int8
+    pool's scales NaN and its rows 127): the outputs are finite and
+    EQUAL, to the bit, those over the clean pool — nothing past the
+    length was used. (The reference form reads them all: NaN.)"""
+    rng = np.random.default_rng(11)
+    positions = [5, 8, 0, 19, 3]
+    tbl = _tables(positions, parked=(2,))
+    pools = _stacked_pools(rng, pool_dtype)
+    q = jnp.asarray(rng.normal(size=(5, _G_H, _G_D)), jnp.float32)
+    clean, ref = _both_forms(q, pools, tbl, positions)
+    dead = np.ones(_G_N, bool)
+    for row, pos in zip(np.asarray(tbl), positions):
+        dead[row[:pos // _G_BS + 1]] = False
+
+    def poison(pool, value):
+        return None if pool is None else \
+            pool.at[:, np.flatnonzero(dead)].set(value)
+    bad = 127 if pool_dtype == "int8" else np.nan
+    poisoned = (poison(pools[0], bad), poison(pools[1], bad),
+                poison(pools[2], np.nan), poison(pools[3], np.nan))
+    pal, ref_poisoned = _both_forms(q, poisoned, tbl, positions)
+    assert np.isnan(ref_poisoned).any()
+    assert np.isfinite(pal).all()
+    assert np.array_equal(_bits(pal), _bits(clean))
+    np.testing.assert_allclose(pal, ref, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
+def test_grouped_kernel_takes_a_traced_layer_under_scan(groups_of_two,
+                                                        pool_dtype):
+    """The looped family's call: the cache layer a scalar of the scan."""
+    rng = np.random.default_rng(13)
+    positions = [9, 2, 16]
+    tbl = _tables(positions)
+    kp, vp, _, _ = pools = _stacked_pools(rng, pool_dtype)
+    q = jnp.asarray(rng.normal(size=(3, _G_H, _G_D)), jnp.float32)
+    ctx = jnp.asarray(positions, jnp.int32) + 1
+
+    def body(carry, layer):
+        return carry, _pa.paged_attention_pallas(q, kp, vp, tbl, ctx,
+                                                 layer=layer)
+    _, outs = jax.jit(lambda: jax.lax.scan(
+        body, 0, jnp.arange(_G_LAYERS, dtype=jnp.int32)))()
+    for layer in range(_G_LAYERS):
+        _, ref = _both_forms(q, pools, tbl, positions, layer=layer)
+        np.testing.assert_allclose(np.asarray(outs[layer]), ref,
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("block_size,row_bytes,max_blocks,want", [
+    (16, 768 * 4, 64, 8),       # gpt2_124m: float32 rows of 768
+    (16, 2048 * 2, 32, 8),      # ouro_2_6b: bfloat16 rows of 2,048
+    (16, 768, 64, 8),           # an int8 pool of GPT-2's rows
+    (16, 8192 * 4, 64, 2),      # a row of 32 KB: the memory budget
+    (16, 768 * 4, 5, 4),        # a table narrower than a group
+    (64, 1024, 16, 2),          # blocks of 64 positions: the lanes
+    (512, 1024, 16, 1)], ids=[
+        "gpt2", "ouro", "int8", "wide_rows", "narrow_table",
+        "long_blocks", "block_over_the_step"])
+def test_blocks_per_step_follows_the_shapes(block_size, row_bytes,
+                                            max_blocks, want):
+    g = _pa.blocks_per_step(block_size, row_bytes, max_blocks)
+    assert g == want
+    # K and V tiles, two buffers each, inside the budget (one block a
+    # step is the floor whatever it takes)
+    assert g == 1 or 4 * g * block_size * row_bytes <= _pa._KV_VMEM_BUDGET
 
 
 # forward_paged's mixed step against forward_full, float32 on both
