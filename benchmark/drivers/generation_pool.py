@@ -14,6 +14,13 @@ chip runs, PR 26). The latency sample is the requests that END inside the
 window. Once it has closed the clients stop sending, the pool drains, and a
 sample of the finished requests, drawn from the seed with the longest in it,
 is held against the plain reference's logits.
+
+The list of requests (`pool` in the cell's file) has to outlast the clients'
+whole life, from their start in set-up to the window's close: a client that
+finds the list at its end goes home, the engine runs on with fewer lanes
+loaded, and the rate read is the list's length over the window, whatever the
+engine could do (PR 35: 1,259.85 tokens/s five times over, from an engine
+that does 2,700). Such a run raises `BenchError` and prints no result.
 """
 import threading
 import time
@@ -43,13 +50,15 @@ class Driver:
         self.lock = threading.Lock()
         self.stop = threading.Event()
         self.next_index = 0
+        self.t_clients = None    # when the clients started
+        self.dry_at = None       # when a client first found the list empty
 
     # -- set-up ---------------------------------------------------------------
     def setup(self):
         from paddle_tpu.generation import (DecoderConfig, GenerationEngine,
                                            GenerationPool)
-        cfg, wl = self.cfg, self.wl
-        self.requests = traffic.requests(wl, cfg, self.seed)
+        cfg = self.cfg
+        self._draw_requests()
         dcfg = DecoderConfig(vocab_size=cfg["vocab_size"],
                              hidden=cfg["n_embd"], layers=cfg["n_layer"],
                              heads=cfg["n_head"],
@@ -74,9 +83,22 @@ class Driver:
                        self.engine.prefill_chunk, self.engine.kv.block_size,
                        self.engine.kernel))
         self.pool = GenerationPool(self.engine)
+        self._start_clients()
+
+    def _draw_requests(self):
+        t0 = time.perf_counter()
+        self.requests = traffic.requests(self.wl, self.cfg, self.seed)
+        harness.say("%d requests drawn in %.2fs"
+                    % (len(self.requests), time.perf_counter() - t0))
+
+    def _start_clients(self):
+        """Start the closed loop and wait for its run-in: every lane occupied
+        and the first `warm_completions` requests ended."""
+        wl = self.wl
         self.threads = [threading.Thread(target=self._client, daemon=True,
                                          name="bench-client-%d" % i)
                         for i in range(wl["clients"])]
+        self.t_clients = time.monotonic()
         for t in self.threads:
             t.start()
         want = wl["warm_completions"]
@@ -90,6 +112,8 @@ class Driver:
                 raise harness.BenchError("%d of %d warm-up requests completed "
                                          "in 600 s" % (n, want))
             time.sleep(0.002)   # the window opens on the completion itself
+        harness.say("clients' run-in %.1fs: %d of %d requests ended"
+                    % (time.perf_counter() - t0, n, len(self.requests)))
 
     def _client(self):
         from paddle_tpu.generation import GenerationRequest
@@ -98,6 +122,8 @@ class Driver:
                 i = self.next_index
                 self.next_index += 1
             if i >= len(self.requests):
+                if self.dry_at is None and not self.stop.is_set():
+                    self.dry_at = time.monotonic()
                 return
             prompt, new = self.requests[i]
             rec = _Done(i)
@@ -128,8 +154,25 @@ class Driver:
                 self.done.append(rec)
 
     # -- the timed part ---------------------------------------------------------
+    def _pool_held(self):
+        """Raise where a client has gone home for want of a request: from then
+        on the run measured the list's length and not the engine."""
+        dry_at = self.dry_at
+        if dry_at is None:
+            return
+        self.release()      # let what is in flight finish, then say why
+        raise harness.BenchError(
+            "the pool ran dry: all %d requests of the cell's `pool` were sent "
+            "%.1f s after the clients' start, %.1f s before this point; the "
+            "clients no longer keep the engine loaded and the rate would be "
+            "the list's, not the engine's. Raise `pool` in the workload file "
+            "(a `benchmark` issue)" % (
+                len(self.requests), dry_at - self.t_clients,
+                time.monotonic() - dry_at))
+
     def steady(self, seconds):
         time.sleep(seconds)
+        self._pool_held()
 
     def _counters(self):
         from paddle_tpu.monitor import stat_get, timer_get
@@ -153,6 +196,7 @@ class Driver:
         t1 = time.monotonic()
         n1 = self._tokens_out()
         c1 = self._counters()
+        self._pool_held()
         self.stop.set()
         with self.lock:
             self.sample = [r for r in self.done if t0 <= r.t_end < t1]

@@ -15,8 +15,8 @@ this one stands beside it and overrides two methods:
   come from the reference module as before (here bfloat16, stacked by layer;
   the module hands out the SAME arrays when `compare` asks again, so the
   comparison holds no second 5.34 GB set).
-  The clients' start and the wait for the warm-up completions are the parent's,
-  line for line.
+  The clients' start and the wait for the warm-up completions are the parent's
+  (`_start_clients`).
 - `_counters`: adds `attended_tokens` (`STAT_generation_attended_tokens`: per
   mixed step, the sum over live slots of the positions attended), which
   `metrics/step_hbm_roofline_pct.py` turns into the KV bytes a step needs.
@@ -24,10 +24,9 @@ this one stands beside it and overrides two methods:
 Everything else (the clients, the window and its token count, the drain, the
 sample, `compare`) is inherited.
 """
-import threading
 import time
 
-from benchmark import harness, traffic
+from benchmark import harness
 from benchmark.drivers import generation_pool
 
 
@@ -36,9 +35,9 @@ class Driver(generation_pool.Driver):
         from paddle_tpu.generation import GenerationEngine, GenerationPool
         from paddle_tpu.generation.looped import LoopedDecoderConfig
         from paddle_tpu.flags import get_flag
-        cfg, wl = self.cfg, self.wl
+        cfg = self.cfg
         eng = cfg["engine"]
-        self.requests = traffic.requests(wl, cfg, self.seed)
+        self._draw_requests()
         dcfg = LoopedDecoderConfig.from_source(cfg, eng["max_context"])
         if self.engine is not None:
             # an engine handed over by calibrate.py still holds the last
@@ -65,22 +64,7 @@ class Driver(generation_pool.Driver):
                        dcfg.kv_layers, dcfg.kv_row, dcfg.max_seq_len,
                        e.kv_pool_bytes() / 1e9, e.kv_dtype, e.lookahead))
         self.pool = GenerationPool(self.engine)
-        self.threads = [threading.Thread(target=self._client, daemon=True,
-                                         name="bench-client-%d" % i)
-                        for i in range(wl["clients"])]
-        for t in self.threads:
-            t.start()
-        want = wl["warm_completions"]
-        t0 = time.perf_counter()
-        while True:
-            with self.lock:
-                n = len(self.done)
-            if n >= want:
-                break
-            if time.perf_counter() - t0 > 600:
-                raise harness.BenchError("%d of %d warm-up requests completed "
-                                         "in 600 s" % (n, want))
-            time.sleep(0.002)   # the window opens on the completion itself
+        self._start_clients()
 
     def _counters(self):
         from paddle_tpu.monitor import stat_get

@@ -257,8 +257,9 @@ def run_cell(files, cell_name, seed, seconds, trace, t_start, rehearse=False):
     cache_dir = compile_cache()
     counter = CompileCounter()
     memory = MemoryPeak()
-    say("cell %s seed %d on %s; compile cache %s"
-        % (cell_name, seed, json.dumps(info), cache_dir or "off"))
+    say("cell %s seed %d on %s; compile cache %s; %.1fs since the process's "
+        "first line" % (cell_name, seed, json.dumps(info), cache_dir or "off",
+                        time.time() - t_start))
     driver_mod = files.load_module("drivers", cfg["driver"])
     ref_mod = files.load_module("references", cfg["reference"])
     drv = driver_mod.Driver(cfg=cfg, workload=wl, seed=seed, reference=ref_mod)

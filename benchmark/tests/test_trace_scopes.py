@@ -246,13 +246,16 @@ def test_engine_host_reader_fails_where_the_step_lost_a_child(program):
 
 
 def test_the_new_entries_are_the_last_of_per_layer_and_have_their_cells():
+    """PR 27's entries, held by NAME and by their cells: later PRs append
+    entries of their own and add their cells to these."""
     spec = harness.Files().spec
-    names = [m["name"] for m in spec["per_layer"]]
-    new = sorted(TRAIN) + sorted(SERVE)
-    assert sorted(names[-len(new):]) == sorted(new)
+    by_name = {m["name"]: m for m in spec["per_layer"]}
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
     cells = {"train": "bert_base_mlm_b32_s512", "serve": "gpt2_124m_chat_c32"}
-    for m in spec["per_layer"][-len(new):]:
-        assert m["workloads"] == [cells[m["name"].partition(".")[2]]]
+    for name in sorted(TRAIN) + sorted(SERVE):
+        m = by_name[name]
+        assert cells[name.partition(".")[2]] in m["workloads"]
+        assert set(m["workloads"]) <= set(e2e[m["moves"]]["workloads"])
         assert m["unit"] in ("ms", "%") and m["better"] == "lower"
 
 
