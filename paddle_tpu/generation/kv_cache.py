@@ -37,7 +37,11 @@ GAUGE_kv_shared_blocks (blocks referenced more than once) /
 GAUGE_kv_blocks_saved (duplicate allocations sharing avoided),
 STAT_generation_blocks_allocated / _blocks_freed / _evictions;
 the PrefixCache adds GAUGE_generation_prefix_entries / _prefix_blocks
-and STAT_generation_prefix_evictions.
+and STAT_generation_prefix_evictions. The gauges are RUNNING COUNTS,
+exact at every mutation: a ledger or cache call costs what it touches,
+never a recount of the pool or of the entries. `KVCacheManager._shift`
+and the cache's one eviction helper `PrefixCache._drop_oldest` (with
+`insert`) are the only places they move.
 """
 from __future__ import annotations
 
@@ -87,6 +91,10 @@ class KVCacheManager:
         self._tables: Dict[object, List[int]] = {}
         # block -> reference count; every non-free block has an entry
         self._ref: Dict[int, int] = {}
+        # running counts over _ref, kept by _shift: blocks at a count
+        # above 1, and the sum of (count - 1) over them
+        self._shared = 0
+        self._saved = 0
         self._publish()
 
     # --- queries -------------------------------------------------------
@@ -102,12 +110,12 @@ class KVCacheManager:
     @property
     def shared_blocks(self) -> int:
         """Blocks referenced by more than one owner (tables + cache)."""
-        return sum(1 for r in self._ref.values() if r > 1)
+        return self._shared
 
     @property
     def blocks_saved(self) -> int:
         """Allocations sharing avoided: sum of (refcount - 1)."""
-        return sum(r - 1 for r in self._ref.values() if r > 1)
+        return self._saved
 
     def blocks_for_tokens(self, tokens: int) -> int:
         """ceil(tokens / block_size) — blocks needed to hold a context
@@ -157,12 +165,11 @@ class KVCacheManager:
         for b in shared_blocks:
             if self._ref.get(b, 0) < 1:
                 raise ValueError("cannot share free block %d" % b)
-        priv = [self._free.popleft() for _ in range(n_private)]
-        for b in shared_blocks:
-            self._ref[b] += 1
-        for b in priv:
-            self._ref[b] = 1
-        self._tables[seq_id] = list(shared_blocks) + priv
+        table = list(shared_blocks)
+        table.extend(self._free.popleft() for _ in range(n_private))
+        for b in table:
+            self._shift(b, 1)
+        self._tables[seq_id] = table
         if n_private:
             stat_add("STAT_generation_blocks_allocated", n_private)
         self._publish()
@@ -177,7 +184,7 @@ class KVCacheManager:
             raise BlockPoolExhausted(
                 "no free block to extend sequence %r" % (seq_id,))
         b = self._free.popleft()
-        self._ref[b] = 1
+        self._shift(b, 1)
         self._tables[seq_id].append(b)
         stat_add("STAT_generation_blocks_allocated")
         self._publish()
@@ -198,8 +205,8 @@ class KVCacheManager:
             raise BlockPoolExhausted(
                 "no free block for copy-on-write of %r" % (seq_id,))
         new = self._free.popleft()
-        self._ref[new] = 1
-        self._ref[old] -= 1
+        self._shift(new, 1)
+        self._shift(old, -1)
         blocks[index] = new
         stat_add("STAT_generation_blocks_allocated")
         self._publish()
@@ -211,7 +218,7 @@ class KVCacheManager:
             if self._ref.get(b, 0) < 1:
                 raise ValueError("cannot reference free block %d" % b)
         for b in blocks:
-            self._ref[b] += 1
+            self._shift(b, 1)
         self._publish()
 
     def decref(self, blocks: Sequence[int]) -> int:
@@ -219,15 +226,11 @@ class KVCacheManager:
         return to the free list. Returns the number recycled."""
         released = 0
         for b in blocks:
-            r = self._ref.get(b, 0)
-            if r < 1:
+            if self._ref.get(b, 0) < 1:
                 raise ValueError("refcount underflow on block %d" % b)
-            if r == 1:
-                del self._ref[b]
+            if not self._shift(b, -1):
                 self._free.append(b)
                 released += 1
-            else:
-                self._ref[b] = r - 1
         if released:
             stat_add("STAT_generation_blocks_freed", released)
         self._publish()
@@ -259,11 +262,31 @@ class KVCacheManager:
 
     # --- internals -----------------------------------------------------
 
+    def _shift(self, block: int, step: int) -> int:
+        """The ONE place a reference count rises or falls (`step` is
+        +1 or -1), so the running counts behind `shared_blocks` and
+        `blocks_saved` move with it: 1 -> 2 makes a shared block and
+        2 -> 1 unmakes it, every step above 1 moves `blocks_saved` by
+        one. A count that reaches 0 leaves `_ref`. Returns the new
+        count."""
+        r = self._ref.get(block, 0)
+        n = r + step
+        if n:
+            self._ref[block] = n
+        else:
+            del self._ref[block]
+        top = max(r, n)
+        if top > 1:
+            self._saved += step
+            if top == 2:
+                self._shared += step
+        return n
+
     def _publish(self) -> None:
         gauge_set("GAUGE_generation_blocks_free", len(self._free))
         gauge_set("GAUGE_generation_blocks_used", self.used_blocks)
-        gauge_set("GAUGE_kv_shared_blocks", self.shared_blocks)
-        gauge_set("GAUGE_kv_blocks_saved", self.blocks_saved)
+        gauge_set("GAUGE_kv_shared_blocks", self._shared)
+        gauge_set("GAUGE_kv_blocks_saved", self._saved)
 
 
 class _PrefixEntry:
@@ -306,6 +329,9 @@ class PrefixCache:
         self.kv = kv
         self.chunk = max(1, int(chunk))
         self._entries: "OrderedDict[str, _PrefixEntry]" = OrderedDict()
+        # block -> number of entries that hold it (a running count,
+        # moved by insert and _drop_oldest: held_blocks is its length)
+        self._held: Dict[int, int] = {}
         self._publish()
 
     # --- hashing -------------------------------------------------------
@@ -337,10 +363,7 @@ class PrefixCache:
     @property
     def held_blocks(self) -> int:
         """Distinct blocks the cache holds references on."""
-        blocks = set()
-        for e in self._entries.values():
-            blocks.update(e.blocks)
-        return len(blocks)
+        return len(self._held)
 
     def match(self, prompt: Sequence[int]
               ) -> Optional[Tuple[int, List[int]]]:
@@ -376,7 +399,11 @@ class PrefixCache:
             self._entries.move_to_end(key)
             return
         self.kv.incref(blocks)
-        self._entries[key] = _PrefixEntry(key, int(tokens), list(blocks))
+        e = _PrefixEntry(key, int(tokens), list(blocks))
+        self._entries[key] = e
+        held = self._held
+        for b in e.blocks:
+            held[b] = held.get(b, 0) + 1
         self._publish()
 
     # --- eviction ------------------------------------------------------
@@ -389,8 +416,7 @@ class PrefixCache:
         survive via the sequence's own references. Returns True when
         the pool now has the headroom."""
         while self.kv.free_blocks < n_free and self._entries:
-            _, e = self._entries.popitem(last=False)
-            self.kv.decref(e.blocks)
+            self._drop_oldest()
             stat_add("STAT_generation_prefix_evictions")
         self._publish()
         return self.kv.free_blocks >= n_free
@@ -399,12 +425,23 @@ class PrefixCache:
         """Drop every entry (engine reset after a batch-level fault:
         a possibly poisoned cache must not survive the restart)."""
         while self._entries:
-            _, e = self._entries.popitem(last=False)
-            self.kv.decref(e.blocks)
+            self._drop_oldest()
         self._publish()
 
     # --- internals -----------------------------------------------------
 
+    def _drop_oldest(self) -> None:
+        """Forget the least-recently-used entry: its blocks leave the
+        held count and its references go back to the ledger."""
+        _, e = self._entries.popitem(last=False)
+        held = self._held
+        for b in e.blocks:
+            if held[b] > 1:
+                held[b] -= 1
+            else:
+                del held[b]
+        self.kv.decref(e.blocks)
+
     def _publish(self) -> None:
         gauge_set("GAUGE_generation_prefix_entries", len(self._entries))
-        gauge_set("GAUGE_generation_prefix_blocks", self.held_blocks)
+        gauge_set("GAUGE_generation_prefix_blocks", len(self._held))

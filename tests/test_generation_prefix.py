@@ -13,7 +13,15 @@ Plus the refcount ledger (idempotent free extended to shared blocks),
 COW divergence under concurrent sequences, LRU eviction + preemption
 replay under an armed generation.kv_alloc failpoint, and the two new
 failpoint sites' fallbacks (prefix_lookup -> cold prefill with an
-unpoisoned cache, draft_step -> plain decode)."""
+unpoisoned cache, draft_step -> plain decode).
+
+PR 37: the ledger's gauges are running counts. A recount oracle holds
+them to the formulas they replaced after every call of a random walk,
+a counted (not timed) test holds one mutation's cost independent of
+the pool's size, and an engine run holds them through prefix hits,
+copy-on-write and preemption."""
+import sys
+
 import numpy as np
 import pytest
 
@@ -23,6 +31,7 @@ from paddle_tpu.generation import (BlockPoolExhausted, DecoderConfig,
                                    GenerationEngine, GenerationRequest,
                                    KVCacheManager, SamplingParams,
                                    TRASH_BLOCK, init_params)
+from paddle_tpu.generation.kv_cache import PrefixCache
 from paddle_tpu.monitor import gauge_get, stat_get
 
 CFG = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=4,
@@ -345,3 +354,240 @@ def test_spec_requires_chunked_mode_and_validates_draft(params):
         _engine(params, spec_tokens=2, draft="model")  # no draft_cfg
     with pytest.raises(ValueError):
         _engine(params, spec_tokens=2, draft="banana")
+
+
+# ---------------------------------------------------------------------------
+# PR 37: the ledger's gauges are running counts — a recount oracle, a
+# count of what one mutation costs as the pool grows, an engine run
+# ---------------------------------------------------------------------------
+
+def _recount(kv, cache):
+    """The formulas the ledger ran on every mutation until PR 37: a
+    pass over every reference count and every entry's block list."""
+    refs = list(kv._ref.values())
+    held = set()
+    for e in cache._entries.values():
+        held.update(e.blocks)
+    return (sum(1 for r in refs if r > 1),
+            sum(r - 1 for r in refs if r > 1), len(held))
+
+
+def _assert_counts_exact(kv, cache, what):
+    """The three properties and the six gauges equal a recount."""
+    want = _recount(kv, cache)
+    assert (kv.shared_blocks, kv.blocks_saved,
+            cache.held_blocks) == want, what
+    assert (gauge_get("GAUGE_kv_shared_blocks"),
+            gauge_get("GAUGE_kv_blocks_saved"),
+            gauge_get("GAUGE_generation_prefix_blocks")) == want, what
+    assert gauge_get("GAUGE_generation_blocks_free") == len(kv._free), what
+    assert gauge_get("GAUGE_generation_blocks_used") == kv.used_blocks, what
+    assert gauge_get("GAUGE_generation_prefix_entries") == len(
+        cache._entries), what
+    assert all(r >= 1 for r in kv._ref.values()), what
+    assert len(kv._ref) == kv.used_blocks, what
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_ledger_counts_equal_a_recount_after_every_call(seed):
+    """A few thousand random ledger and cache calls over a pool small
+    enough to run dry, the allocation failpoint raised on the way:
+    after EVERY call the three properties and the six gauges equal a
+    recount from `_ref` and the entries."""
+    rng = np.random.RandomState(1000 + seed)
+    kv = KVCacheManager(num_blocks=int(rng.choice([12, 24, 40])),
+                        block_size=4)
+    cache = PrefixCache(kv, chunk=8)
+    # few distinct prompts over two stems, so chains are shared,
+    # matched and re-inserted
+    stems = [list(rng.randint(0, 64, 16)) for _ in range(2)]
+    prompts = [stems[i % 2][:int(rng.choice([8, 16]))]
+               + list(rng.randint(0, 64, int(rng.randint(0, 14))))
+               for i in range(6)]
+    live, extra, ids = {}, [], iter(range(10 ** 6))
+    raised = {"exhausted": 0, "fault": 0, "private": 0}
+
+    def pick():
+        return list(live)[rng.randint(len(live))] if live else None
+
+    def attach():
+        prompt = prompts[rng.randint(len(prompts))]
+        hit = cache.match(prompt) if rng.rand() < 0.7 else None
+        shared = hit[1] if hit else []
+        if not shared and live and rng.rand() < 0.3:
+            shared = kv.owned(pick())[:rng.randint(1, 3)]
+        need = max(0, kv.blocks_for_tokens(len(prompt)) - len(shared))
+        sid = next(ids)
+        spec = ("generation.kv_alloc=raise@once"
+                if rng.rand() < 0.1 else "")
+        try:
+            with failpoints.armed(spec):
+                kv.attach(sid, shared, need)
+        except BlockPoolExhausted:
+            raised["exhausted"] += 1
+        except InjectedFault:
+            raised["fault"] += 1
+        else:
+            live[sid] = prompt
+
+    def extend():
+        try:
+            kv.extend(pick())
+        except BlockPoolExhausted:
+            raised["exhausted"] += 1
+
+    def cow():
+        sid = pick()
+        try:
+            kv.cow(sid, rng.randint(len(kv.owned(sid))))
+        except BlockPoolExhausted:
+            raised["exhausted"] += 1
+        except ValueError:
+            raised["private"] += 1
+
+    def incref():
+        blocks = kv.owned(pick())[:rng.randint(1, 4)]
+        kv.incref(blocks)
+        extra.append(blocks)
+
+    def decref():
+        if extra:
+            kv.decref(extra.pop(rng.randint(len(extra))))
+
+    def free():
+        # now and then an id that is gone: a no-op, not an underflow
+        sid = pick() if rng.rand() < 0.9 else -1
+        (kv.evict if rng.rand() < 0.3 else kv.free)(sid)
+        live.pop(sid, None)
+
+    def insert():
+        sid = pick()
+        owned = kv.owned(sid)
+        for tokens, key in cache.keys_for(live[sid]):
+            n = kv.blocks_for_tokens(tokens)
+            if n > len(owned) or rng.rand() < 0.2:
+                break
+            cache.insert(key, tokens, owned[:n])
+            _assert_counts_exact(kv, cache, "insert")
+
+    def match():
+        cache.match(prompts[rng.randint(len(prompts))])
+
+    def evict_for():
+        cache.evict_for(kv.free_blocks + int(rng.randint(0, 4)))
+
+    ops = [(attach, 5, False), (extend, 3, True), (cow, 3, True),
+           (incref, 1, True), (decref, 1, False), (free, 4, False),
+           (insert, 5, True), (match, 1, False), (evict_for, 2, False),
+           (cache.clear, 0.1, False)]
+    weights = np.array([w for _, w, _ in ops], float)
+    _assert_counts_exact(kv, cache, "fresh")
+    for _ in range(2500):
+        op, _, needs_live = ops[rng.choice(len(ops), p=weights
+                                           / weights.sum())]
+        if needs_live and not live:
+            continue
+        op()
+        _assert_counts_exact(kv, cache, op.__name__)
+    # the walk reached what it claims to cover
+    assert raised["exhausted"] and raised["fault"] and raised["private"]
+    for sid in list(live):
+        kv.free(sid)
+    for blocks in extra:
+        kv.decref(blocks)
+    cache.clear()
+    _assert_counts_exact(kv, cache, "drained")
+    assert kv.used_blocks == 0 and cache.held_blocks == 0
+
+
+def _full_cache(num_blocks):
+    """A ledger whose prefix cache holds the whole pool but three
+    blocks: retired requests of 4 blocks of 16 tokens, an entry at
+    every 8-token boundary, and one live sequence whose first two
+    blocks a second table shares."""
+    kv = KVCacheManager(num_blocks=num_blocks, block_size=16)
+    cache = PrefixCache(kv, chunk=8)
+    live = kv.alloc("live", 4)
+    kv.attach("twin", live[:2], 0)
+    sid = 0
+    while kv.free_blocks >= 4:
+        owned = kv.alloc(sid, 4)
+        for i in range(1, 9):
+            cache.insert("%d/%d" % (sid, i), 8 * i, owned[:(i + 1) // 2])
+        kv.free(sid)
+        sid += 1
+    assert kv.free_blocks == 3 and cache.held_blocks == 4 * sid
+    assert cache.held_blocks >= num_blocks - 12
+    return kv, cache, live
+
+
+_MUTATIONS = {
+    "extend": lambda kv, cache, live: kv.extend("live"),
+    "cow": lambda kv, cache, live: kv.cow("twin", 1),
+    "insert": lambda kv, cache, live: cache.insert(
+        "live/4", 32, live[:2]),
+    "evict_for": lambda kv, cache, live: cache.evict_for(
+        kv.free_blocks + 1),
+    "free": lambda kv, cache, live: kv.free("live"),
+}
+
+
+def _calls_of(mutation, num_blocks):
+    """Python calls, generator resumptions and C calls
+    (`sys.setprofile`) one mutation makes on a full cache."""
+    kv, cache, live = _full_cache(num_blocks)
+    calls = [0]
+
+    def count(frame, event, arg):
+        if event in ("call", "c_call"):
+            calls[0] += 1
+
+    sys.setprofile(count)
+    try:
+        _MUTATIONS[mutation](kv, cache, live)
+    finally:
+        sys.setprofile(None)
+    _assert_counts_exact(kv, cache, mutation)
+    return calls[0]
+
+
+@pytest.mark.parametrize("mutation", sorted(_MUTATIONS))
+def test_a_ledger_mutation_costs_what_it_touches_not_the_pool(mutation):
+    """COUNTED, not timed: one mutation of a full cache makes the same
+    number of calls at 64 blocks and at 4,096. With the gauges
+    recounted on every mutation the count grew with the pool (a
+    generator resumed per reference count, a `set.update` per
+    entry)."""
+    small, large = (_calls_of(mutation, n) for n in (64, 4096))
+    assert small == large, (mutation, small, large)
+
+
+def test_engine_gauges_equal_a_recount_through_hits_cow_and_preemption(
+        params):
+    """The engine's own traffic over a pool too small for it: prefix
+    hits, copy-on-write (chunk 6 on blocks of 4 puts the cached
+    boundary mid-block), LRU eviction and preemption. After every step
+    and at the end the gauges equal the recount."""
+    reqs = [GenerationRequest(
+        prompt=PREFIX[:6] + [30 + i, 31 + i, 32 + i] * 3,
+        max_new_tokens=8, sampling=SamplingParams(), request_id=i)
+        for i in range(10)]
+    eng = _engine(params, prefill_chunk=6, num_blocks=14)
+    before = {k: stat_get(k) for k in (
+        "STAT_generation_prefix_hits", "STAT_generation_prefix_cow_copies",
+        "STAT_generation_prefix_evictions", "STAT_generation_evictions")}
+    for r in reqs:
+        eng.submit(r)
+    done = 0
+    while not eng.idle:
+        done += len(eng.step())
+        _assert_counts_exact(eng.kv, eng.prefix_cache, "step")
+    assert done == len(reqs)
+    for k, v in before.items():
+        assert stat_get(k) > v, k
+    assert not eng.kv._tables
+    assert eng.kv.used_blocks == eng.prefix_cache.held_blocks
+    _assert_counts_exact(eng.kv, eng.prefix_cache, "drained")
+    eng.prefix_cache.clear()
+    _assert_counts_exact(eng.kv, eng.prefix_cache, "cleared")
+    assert eng.kv.used_blocks == 0
