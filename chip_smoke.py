@@ -15,6 +15,8 @@ on ONE TPU v5e chip and in ONE process:
             published widths, eight greedy requests, checked against the
             engine's own oracle (NaiveGenerator), then the same requests
             under the Pallas paged-attention kernel form
+            (and the same for the looped family and the expert family at
+            their benchmark configurations' widths, bfloat16)
 
 `--chips 4` runs the mesh path instead (dp2 x mp2 TrainStep against the same
 model on one of the four devices) and no other phase.
@@ -50,6 +52,19 @@ REAL = dict(
                 num_attention_heads=16, num_key_value_heads=16,
                 head_dim=128, intermediate_size=5632, total_ut_steps=4,
                 max_seq_len=1024),
+    # the expert family at the widths of the benchmark's `k_exaone_236b`:
+    # the dense layer, a window layer and a full layer, 8 of the 128
+    # experts held, 64 heads over 8 key-value heads; bfloat16. Its
+    # prompts outgrow the window of 128 and stay under a context cap at
+    # which the oracle's full-context attention over 64 heads fits
+    expert=dict(vocab_size=19200, hidden_size=6144, num_hidden_layers=3,
+                num_attention_heads=64, num_key_value_heads=8,
+                head_dim=128, intermediate_size=18432,
+                moe_intermediate_size=2048, num_experts=128,
+                num_experts_per_tok=8, sliding_windows=(128, 128, 0),
+                dense_layers=1, experts_first=0, experts_held=8,
+                max_seq_len=256),
+    expert_prompts=(32, 192),
     engine=dict(num_blocks=1024, decode_width=8),
     prompts=(64, 512), new_tokens=32,
     mesh_bert=dict(num_hidden_layers=2, hidden_dropout_prob=0.0,
@@ -66,6 +81,13 @@ TINY = dict(
     looped=dict(vocab_size=128, hidden_size=64, num_hidden_layers=2,
                 num_attention_heads=4, num_key_value_heads=4, head_dim=24,
                 intermediate_size=96, total_ut_steps=2, max_seq_len=64),
+    expert=dict(vocab_size=128, hidden_size=64, num_hidden_layers=3,
+                num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+                intermediate_size=96, moe_intermediate_size=32,
+                num_experts=16, num_experts_per_tok=4,
+                sliding_windows=(8, 8, 0), dense_layers=1,
+                experts_first=4, experts_held=8, max_seq_len=64),
+    expert_prompts=(4, 24),
     engine=dict(num_blocks=64, decode_width=8),
     prompts=(4, 24), new_tokens=6,
     mesh_bert=dict(vocab_size=1000, hidden_size=128, num_hidden_layers=2,
@@ -330,9 +352,12 @@ def _explain_divergence(oracle_logits, max_len, prompt, ref, got, what):
 
 
 def phase_generate(sizes, counter, family="gpt"):
-    """`family`: "gpt" (float32, generation/model.py) or "looped"
-    (bfloat16 weights and KV pool, generation/looped.py); the engine, the
-    pool, both kernel forms and the oracle are the same code."""
+    """`family`: "gpt" (float32, generation/model.py), "looped"
+    (bfloat16 weights and KV pool, generation/looped.py) or "expert"
+    (bfloat16: routed experts of which a share is held, a window layer
+    and a full one, grouped key-value heads; generation/moe_window.py);
+    the engine, the pool, both kernel forms and the oracle are the same
+    code."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.generation import (DecoderConfig, GenerationEngine,
@@ -344,12 +369,17 @@ def phase_generate(sizes, counter, family="gpt"):
     if family == "gpt":
         cfg = DecoderConfig(**sizes["decoder"])
         params = init_params(cfg, seed=0)
-    else:
+    elif family == "looped":
         cfg = looped.LoopedDecoderConfig(**sizes["looped"])
         params = looped.init_params(cfg, seed=0, dtype=jnp.bfloat16)
         engine_kw["kv_dtype"] = "bf16"
+    else:
+        from paddle_tpu.generation import moe_window
+        cfg = moe_window.ExpertDecoderConfig(**sizes["expert"])
+        params = moe_window.init_params(cfg, seed=0, dtype=jnp.bfloat16)
+        engine_kw["kv_dtype"] = "bf16"
     rng = np.random.default_rng(0)
-    lo, hi = sizes["prompts"]
+    lo, hi = sizes["expert_prompts" if family == "expert" else "prompts"]
     new = sizes["new_tokens"]
     prompts = [rng.integers(0, cfg.vocab_size, int(n)).tolist()
                for n in np.linspace(lo, hi, 8)]
@@ -521,7 +551,9 @@ def main(argv=None):
                   ("static", lambda: phase_static(sizes, counter)),
                   ("generate", lambda: phase_generate(sizes, counter)),
                   ("generate_looped",
-                   lambda: phase_generate(sizes, counter, "looped"))]
+                   lambda: phase_generate(sizes, counter, "looped")),
+                  ("generate_expert",
+                   lambda: phase_generate(sizes, counter, "expert"))]
     for name, run in phases:
         t1 = time.perf_counter()
         run()

@@ -31,6 +31,11 @@ block (`model.DecoderConfig`) and the looped decoder
 (`looped.LoopedDecoderConfig`: a stack run several times a token, a
 cache layer for every pass) are served by the same programs below;
 nothing here names a family (docs/generation.md, "Model families").
+A family may report numbers beside a step's tokens (`cfg.step_stats_len`
+int32 after the sample rows of the step's one result, handed to
+`cfg.record_step_stats` at the fetch: the expert family's routing
+counts, `generation/moe_window.py`) and give a window a cache layer
+(`cfg.kv_windows`: what the attended counters count by).
 `kv_dtype="bf16"` keeps a plain bfloat16 pool; weights are served in
 the dtype they arrive in.
 
@@ -422,8 +427,14 @@ class GenerationEngine:
         # plan, chunk plan, time of dispatch); None when none is out
         self._inflight = None
         self._t_collected = 0.0
+        # what a family's step reports beside its tokens (int32
+        # numbers after the sample rows of the step's one result, so
+        # that they cost no fetch of their own; `cfg.record_step_stats`
+        # reads them on the host): 0 for a family that reports nothing
+        self._stats_len = int(getattr(cfg, "step_stats_len", 0))
         # what a step feeds on where the host gives every token itself
-        self._no_prev = jnp.zeros((self.sample_width,), jnp.int32)
+        self._no_prev = jnp.zeros((self.sample_width + self._stats_len,),
+                                  jnp.int32)
         # per-request error sink: the scheduler points this at the
         # request's future; the bare engine re-raises
         self.on_request_error = None
@@ -580,6 +591,7 @@ class GenerationEngine:
             t = self.token_budget
             sw = self.sample_width
             quant_kv = self.k_scales is not None
+            n_stats = self._stats_len
 
             def raw(params, *rest):
                 pools, (prev, ints, floats) = rest[:-3], rest[-3:]
@@ -596,17 +608,26 @@ class GenerationEngine:
                 temps, tps = floats[:sw], floats[sw:]
                 scales = dict(k_scale_pools=pools[2],
                               v_scale_pools=pools[3]) if quant_kv else {}
+                tables = tables.reshape(t, m)
+                if n_stats:
+                    # the slots that carry a token: an idle slot's
+                    # table is the trash block throughout
+                    scales["live"] = tables[:, 0] != TRASH_BLOCK
                 out = cfg.forward_paged(
-                    params, pools[0], pools[1], tables.reshape(t, m),
+                    params, pools[0], pools[1], tables,
                     positions, tokens, **scales)
                 with jax.named_scope("sampler"):
                     nxt = sample_tokens(out[0][sample_slots], temps,
                                         tks, tps, seeds, steps)
+                if n_stats:
+                    nxt = jnp.concatenate(
+                        [nxt, out[-1].reshape(n_stats).astype(nxt.dtype)])
+                    out = out[:-1]
                 return (nxt,) + tuple(out[1:])
             avals = (jax.tree.map(_sds, self.params),) + tuple(
                 _sds(getattr(self, n))
                 for n in self._program_pools(kind)) + (
-                jax.ShapeDtypeStruct((sw,), jnp.int32),
+                jax.ShapeDtypeStruct((sw + n_stats,), jnp.int32),
                 jax.ShapeDtypeStruct((t * m + 3 * t + 4 * sw,), jnp.int32),
                 jax.ShapeDtypeStruct((2 * sw,), jnp.float32))
         elif kind in ("cow", "draft_cow"):
@@ -1016,10 +1037,19 @@ class GenerationEngine:
             with _tm.span("pt/engine/dispatch", track="generation"):
                 nxt = self._run("mixed", self._no_prev, *packed)
             with _tm.span("pt/engine/fetch", track="generation"):
-                nxt = np.asarray(nxt)
+                nxt = self._fetched(nxt)
         dt_us = (time.perf_counter() - t0) * 1e6
         self._emit_mixed(nxt, dt_us, decode_plan, chunk_plan, finished)
         return finished
+
+    def _fetched(self, nxt) -> np.ndarray:
+        """A step's result on the host: its sample rows, after what
+        the family's step reported beside them went to the family's
+        counters (`_stats_len`)."""
+        nxt = np.asarray(nxt)
+        if self._stats_len:
+            self.cfg.record_step_stats(nxt[self.sample_width:])
+        return nxt
 
     def _riders(self, decode_plan, chunk_plan) -> Optional[str]:
         """The trace ids of the requests that ride a step."""
@@ -1178,14 +1208,12 @@ class GenerationEngine:
                 stat_add("STAT_generation_sampler_filter_steps")
             # positions the live slots attend over this step: each sees
             # the cache up to and with its own token
-            stat_add("STAT_generation_attended_tokens",
-                     int(positions[:slot].sum()) + slot)
             # ... and the pool blocks those contexts span: what the
             # Pallas kernel copies (the reference form gathers every
             # slot's whole table, token_budget x max_blocks_per_seq)
-            stat_add("STAT_generation_attended_blocks",
-                     int((positions[:slot] // self.kv.block_size + 1)
-                         .sum()))
+            attended, blocks = self._attended(positions[:slot])
+            stat_add("STAT_generation_attended_tokens", attended)
+            stat_add("STAT_generation_attended_blocks", blocks)
             if self.k_scales is not None:
                 # this step's fresh K/V rows quantize inside the compiled
                 # call — the failpoint models a fault in that stage, and it
@@ -1200,6 +1228,24 @@ class GenerationEngine:
             return (_pack_mixed(tables, positions, tokens, feed_rows,
                                 sample_slots, temps, tks, tps, seeds,
                                 steps), decode_plan, chunk_plan)
+
+    def _attended(self, positions) -> tuple:
+        """(positions attended, pool blocks they span) by slots at
+        `positions`, each with its own token. In a family whose cache
+        layers all see the whole context that is ONE layer's count
+        (every layer attends the same); where the family gives a
+        window a cache layer (`cfg.kv_windows`, 0: none) it is the SUM
+        over its layers of what each really attends, a window layer at
+        most its window a slot."""
+        bs = self.kv.block_size
+        windows = getattr(self.cfg, "kv_windows", None) or (0,)
+        attended = blocks = 0
+        for w in sorted(set(windows)):
+            first = np.maximum(positions - w + 1, 0) if w else 0
+            n = windows.count(w)
+            attended += n * int((positions - first + 1).sum())
+            blocks += n * int((positions // bs - first // bs + 1).sum())
+        return attended, blocks
 
     def _emit_mixed(self, nxt, dt_us, decode_plan, chunk_plan,
                     finished: List[GenerationResult]) -> None:
@@ -1338,7 +1384,7 @@ class GenerationEngine:
         self._inflight = None
         finished: List[GenerationResult] = []
         with _tm.span("pt/engine/fetch", track="generation"):
-            nxt = np.asarray(nxt)
+            nxt = self._fetched(nxt)
         with _tm.span("pt/engine/emit", track="generation"):
             now = time.perf_counter()
             # the step's time is the time it held the pipeline: from

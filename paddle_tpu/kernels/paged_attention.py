@@ -140,7 +140,8 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
                                      q_lens, ctx_lens,
                                      sm_scale: Optional[float] = None,
                                      k_scales=None, v_scales=None,
-                                     layer: Optional[int] = None):
+                                     layer: Optional[int] = None,
+                                     window=None):
     """Ragged gather-from-block-table attention in plain XLA.
 
     q `[B, Cq, H, D]`: row b holds `q_lens[b]` real queries at absolute
@@ -178,7 +179,12 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
     products and the same reductions, head for head, and no
     `[.., H * D] -> [.., H, D]` reshape of the view, which on the TPU
     is a relayout of the whole gathered array (twice over, K and V of
-    every layer: 28 of 39 ms a step, PERF.md PR 28)."""
+    every layer: 28 of 39 ms a step, PERF.md PR 28).
+
+    WINDOW and GROUPED HEADS: see `paged_attention`. `window` masks
+    (no gather is saved here: this form's cost follows the table);
+    a STACKED pool's row of fewer heads than q has is read head
+    `h // rep` (a single layer's `[N, bs, H, D]` pool holds q's heads)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     b, cq, h, d = q.shape
@@ -187,8 +193,11 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
     pos = jnp.arange(m * bs, dtype=jnp.int32)
     qi = jnp.arange(cq, dtype=jnp.int32)
     # [B, Cq, L]: pool position visible to query j of row b
-    visible = pos[None, None, :] <= \
-        (ctx_lens[:, None] + qi[None, :])[:, :, None]
+    qpos = (ctx_lens[:, None] + qi[None, :])[:, :, None]
+    visible = pos[None, None, :] <= qpos
+    if window is not None:
+        w = jnp.asarray(window, jnp.int32)
+        visible &= (w <= 0) | (pos[None, None, :] > qpos - w)
     live = (qi[None, :] < q_lens[:, None])[:, :, None]
     mask = (visible & live)[:, None, :, :]            # [B, 1, Cq, L]
     qt = jnp.transpose(q, (0, 2, 1, 3))               # [B, H, Cq, D]
@@ -218,31 +227,35 @@ def _attend_stacked(qt, k_pool, v_pool, k_scales, v_scales, layer,
                     block_tables, mask, sm_scale):
     """The reference attention over layer `layer` of the engine's
     stacked flat pools, one head at a time (see
-    ragged_paged_attention_reference, STACKED POOLS): `[B, H, Cq, D]`."""
+    ragged_paged_attention_reference, STACKED POOLS): `[B, H, Cq, D]`.
+    A row of fewer heads than q has (grouped key-value heads) is read
+    a key-value head at a time, by the `rep` query heads that share
+    it; with `rep` 1 that is a head at a time, as it was."""
     b, h, _, d = qt.shape
 
     def view(pool):                                   # [B, L, width]
         return pool[layer, block_tables].reshape(b, mask.shape[-1], -1)
     kf, vf = view(k_pool), view(v_pool)
+    rep = h * d // kf.shape[-1]     # query heads a key-value head
     if k_scales is not None:
         inv = _inv_grid(k_pool.dtype)
         ksc, vsc = view(k_scales) * inv, view(v_scales) * inv
     outs = []
-    for i in range(h):
+    for i in range(h // rep):
         k = kf[:, None, :, i * d:(i + 1) * d]         # [B, 1, L, D]
         v = vf[:, None, :, i * d:(i + 1) * d]
         if k_scales is not None:
             k = k.astype(jnp.float32) * ksc[:, None, :, i, None]
             v = v.astype(jnp.float32) * vsc[:, None, :, i, None]
-        outs.append(attend_reference(qt[:, i:i + 1], k, v, mask,
-                                     sm_scale))
+        outs.append(attend_reference(qt[:, i * rep:(i + 1) * rep], k, v,
+                                     mask, sm_scale))
     return jnp.concatenate(outs, axis=1)
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
                               sm_scale: Optional[float] = None,
                               k_scales=None, v_scales=None,
-                              layer: Optional[int] = None):
+                              layer: Optional[int] = None, window=None):
     """Single-token decode attention: the Cq == 1 specialization of the
     ragged path. ctx_lens here counts VISIBLE keys (position + 1), so
     the ragged call gets `ctx_lens - 1` keys-before-the-query and a
@@ -253,7 +266,7 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
     out = ragged_paged_attention_reference(
         q[:, None], k_pool, v_pool, block_tables,
         jnp.ones_like(ctx), ctx - 1, sm_scale,
-        k_scales=k_scales, v_scales=v_scales, layer=layer)
+        k_scales=k_scales, v_scales=v_scales, layer=layer, window=window)
     return out[:, 0]
 
 
@@ -321,8 +334,9 @@ def _dot_pieces(lhs3, rhs_pieces, contract_rhs):
     return total
 
 
-def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
-                   *refs, block_size, sm_scale, group, heads, quant):
+def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, *refs,
+                   block_size, sm_scale, group, heads, quant, cq,
+                   windowed=False, rep=1):
     """Grid (B,): one grid step a slot, and inside it a loop over the
     slot's LIVE groups of G table entries — entries below `ceil((ctx +
     q_len) / block_size)` — so that nothing is run, and nothing
@@ -340,7 +354,24 @@ def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
     whose diagonal blocks are the heads' outputs — no split of a tile
     into heads, which on the TPU is a relayout of it. Products are
     float32 (_pieces); the running max, sum and accumulator are
-    float32 scratch carried across the slot's groups."""
+    float32 scratch carried across the slot's groups.
+
+    `windowed`: a fifth prefetched scalar, the layer's window (0:
+    none). Query j of the slot sees the keys `ctx + j - window <
+    position <= ctx + j`, so the slot's loop STARTS at the group that
+    holds `ctx - window + 1` (the first key its first query sees):
+    the groups wholly before it are neither copied nor multiplied,
+    and the mask cuts inside the first. With window 0 the loop starts
+    at group 0.
+
+    `rep` > 1, grouped key-value heads: the pool's row holds `heads`
+    key-value heads and q comes as `[Cq * Hp, D]`, a query head a row.
+    Row h of the block-diagonal query lies over the lanes of key-value
+    head `h // rep`, so `rep` rows share a diagonal block; the output
+    is each row's own block, `[Cq * Hp, D]`."""
+    if windowed:
+        win_ref, *refs = refs
+    q_ref, *refs = refs
     if quant:
         k_hbm, v_hbm, ks_ref, vs_ref, *refs = refs
     else:
@@ -352,18 +383,28 @@ def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
     nb = pl.num_programs(0)
     g_, bs = group, block_size
     max_blocks = tables_ref.shape[1]
-    cq, width = q_ref.shape[1], q_ref.shape[2]
+    width = kbuf.shape[-1]
     d = width // heads
     rows = acc_ref.shape[0]                   # Cq * Hp
     hp = rows // cq
     t = g_ * bs
     lyr = layer_ref[0]
+    win = win_ref[0] if windowed else None
 
     def live_blocks(bi):
         """Table entries slot `bi` attends over: up to its chunk's last
         visible key, at least one (a parked slot's trash block)."""
         n = (lens_ref[bi] + qlens_ref[bi] + bs - 1) // bs
         return jnp.clip(n, 1, max_blocks)
+
+    def first_group(bi):
+        """The first group slot `bi` attends over: 0, or under a
+        window the group of the first key its first query sees."""
+        if not windowed:
+            return 0
+        bi = jnp.minimum(bi, nb - 1)    # asked of the slot after the last
+        lo = jnp.where(win > 0, jnp.maximum(lens_ref[bi] - win + 1, 0), 0)
+        return lo // t
 
     def copies(bi, gi, buf_i, go):
         """Start (or wait for) the copies of group `gi` of slot `bi`
@@ -387,24 +428,34 @@ def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
         for _, buf in streams:
             buf[...] = jnp.zeros_like(buf)
         slot_ref[0] = 0
-        copies(0, 0, 0, "start")
+        copies(0, first_group(0), 0, "start")
 
-    rowi = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
-    diag = (lane >= rowi * d) & (lane < (rowi + 1) * d)  # head h's lanes
+    if rep == 1:
+        rowi = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
+        diag = (lane >= rowi * d) & (lane < (rowi + 1) * d)  # head h's
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
-    q = q_ref[0].astype(jnp.float32) * sm_scale          # [Cq, H * D]
-    qb = jnp.concatenate(
-        [jnp.where(diag, q[j:j + 1], 0.0) for j in range(cq)], axis=0)
+    if rep == 1:
+        q = q_ref[0].astype(jnp.float32) * sm_scale      # [Cq, H * D]
+        qb = jnp.concatenate(
+            [jnp.where(diag, q[j:j + 1], 0.0) for j in range(cq)], axis=0)
+    else:
+        # [Cq * Hp, D], a query head a row -> its key-value head's lanes
+        q = q_ref[0].astype(jnp.float32) * sm_scale
+        kvh = (jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0) % hp) \
+            // rep
+        qb = jnp.concatenate([jnp.where(kvh == g, q, 0.0)
+                              for g in range(heads)], axis=1)
     for i, piece in enumerate(_pieces(qb)):
         q3_ref[i * rows:(i + 1) * rows] = piece
 
     ctx = lens_ref[b]
     qlen = qlens_ref[b]
     n_groups = (live_blocks(b) + g_ - 1) // g_
+    g0 = first_group(b)
 
     def tile(buf, buf_i):
         """The group's rows `[T, H * D]`, exact as bfloat16 pieces."""
@@ -419,7 +470,8 @@ def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
     def attend(gi, buf_i):
         more = gi + 1 < n_groups
         nbi = jnp.where(more, b, b + 1)
-        ngi = jnp.where(more, gi + 1, 0)
+        ngi = jnp.where(more, gi + 1,
+                        first_group(b + 1))
 
         @pl.when(nbi < nb)
         def _prefetch():
@@ -431,6 +483,10 @@ def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
         pos = gi * t + jax.lax.broadcasted_iota(jnp.int32, (hp, t), 1)
         mask = jnp.concatenate(
             [(pos <= ctx + j) & (j < qlen) for j in range(cq)], axis=0)
+        if windowed:
+            mask &= jnp.concatenate(
+                [(win <= 0) | (pos > ctx + j - win) for j in range(cq)],
+                axis=0)
         s = jnp.where(mask, s, NEG_INF)                  # [rows, T]
         m_prev = m_ref[...]                              # [rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -447,14 +503,20 @@ def _ragged_kernel(tables_ref, qlens_ref, lens_ref, layer_ref, q_ref,
         acc_ref[...] = acc_ref[...] * alpha + pv
         return 1 - buf_i
 
-    slot_ref[0] = jax.lax.fori_loop(0, n_groups, attend, slot_ref[0])
+    slot_ref[0] = jax.lax.fori_loop(g0, n_groups, attend, slot_ref[0])
 
     l = l_ref[...]
     o = acc_ref[...] / jnp.where(l <= 0.0, 1.0, l)       # [rows, H*D]
-    o_ref[0] = jnp.concatenate(
-        [jnp.sum(jnp.where(diag, o[j * hp:(j + 1) * hp], 0.0),
-                 axis=0, keepdims=True) for j in range(cq)],
-        axis=0).astype(o_ref.dtype)
+    if rep == 1:
+        o_ref[0] = jnp.concatenate(
+            [jnp.sum(jnp.where(diag, o[j * hp:(j + 1) * hp], 0.0),
+                     axis=0, keepdims=True) for j in range(cq)],
+            axis=0).astype(o_ref.dtype)
+    else:
+        out = jnp.zeros((rows, d), jnp.float32)
+        for g in range(heads):
+            out = out + jnp.where(kvh == g, o[:, g * d:(g + 1) * d], 0.0)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
@@ -462,7 +524,8 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                   sm_scale: Optional[float] = None,
                                   interpret: Optional[bool] = None,
                                   k_scales=None, v_scales=None,
-                                  layer: Optional[int] = None):
+                                  layer: Optional[int] = None,
+                                  window=None):
     """The blocked ragged kernel (_ragged_kernel): a grid step a slot,
     scoring the slot's whole Cq-wide chunk against its live blocks, G
     at a time, so prefill chunks and decode singles share one
@@ -484,7 +547,10 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
     H, D]` pool (tests) is viewed as one. `layer` is a scalar-prefetch
     operand like the tables, so it may be a TRACED scalar: the looped
     family's layer loop (generation/looped.py) reads cache slot `pass
-    * layers + layer` from inside a `lax.scan`."""
+    * layers + layer` from inside a `lax.scan`. `window` (None: the
+    program without one) is a fifth such scalar, so a layer loop may
+    scan it beside `layer`; a pool row narrower than q's heads is
+    grouped key-value heads (_ragged_kernel, `windowed` and `rep`)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
@@ -492,24 +558,37 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
     b, cq, h, d = q.shape
     quant = k_scales is not None
     if layer is None:
-        n, bs = k_pool.shape[:2]
-        k_pool = k_pool.reshape(1, n, bs, h * d)
-        v_pool = v_pool.reshape(1, n, bs, h * d)
+        n, bs, hkv = k_pool.shape[:3]
+        k_pool = k_pool.reshape(1, n, bs, hkv * d)
+        v_pool = v_pool.reshape(1, n, bs, hkv * d)
         if quant:
-            k_scales = k_scales.reshape(1, n, bs, h)
-            v_scales = v_scales.reshape(1, n, bs, h)
+            k_scales = k_scales.reshape(1, n, bs, hkv)
+            v_scales = v_scales.reshape(1, n, bs, hkv)
         layer = 0
     bs, width = k_pool.shape[2:]
+    hkv = width // d
+    rep = h // hkv                 # query heads a key-value head
+    if quant and rep > 1:
+        raise NotImplementedError(
+            "a quantized pool under grouped key-value heads: no family "
+            "serves one (scale rows are a key-value head's, scores a "
+            "query head's)")
     m = block_tables.shape[1]
     g = blocks_per_step(bs, width * k_pool.dtype.itemsize, m)
     hp = -(-h // 16) * 16          # a bfloat16 tile's sublanes
     rows = cq * hp
     groups = -(-m // g)
-    idx = lambda bi, tbl, qls, lens, lyr: (bi, 0, 0)  # noqa: E731
-    row_spec = pl.BlockSpec((1, cq, width), idx)
+    idx = lambda bi, *_: (bi, 0, 0)  # noqa: E731
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    if rep == 1:
+        row_spec = pl.BlockSpec((1, cq, width), idx)
+        q_in = q.reshape(b, cq, width)
+    else:       # a query head a row, padded to the tile's sublanes
+        row_spec = pl.BlockSpec((1, rows, d), idx)
+        q_in = jnp.pad(q, ((0, 0), (0, 0), (0, hp - h), (0, 0))
+                       ).reshape(b, rows, d)
     in_specs = [row_spec, hbm, hbm]
-    operands = [q.reshape(b, cq, width), k_pool, v_pool]
+    operands = [q_in, k_pool, v_pool]
     if quant:
         inv = _inv_grid(k_pool.dtype)
 
@@ -522,7 +601,7 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                  (0, 2, 1, 3))
         in_specs += [pl.BlockSpec(
             (1, groups, hp, g * bs),
-            lambda bi, tbl, qls, lens, lyr: (bi, 0, 0, 0))] * 2
+            lambda bi, *_: (bi, 0, 0, 0))] * 2
         operands += [scale_rows(k_scales), scale_rows(v_scales)]
     scratch = [
         pltpu.VMEM((2, g, bs, width), k_pool.dtype),
@@ -536,9 +615,16 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
     ]
     kern = functools.partial(
         _ragged_kernel, block_size=bs, sm_scale=sm_scale, group=g,
-        heads=h, quant=quant)
+        heads=hkv, quant=quant, cq=cq, windowed=window is not None,
+        rep=rep)
+    scalars = [block_tables.astype(jnp.int32), q_lens.astype(jnp.int32),
+               ctx_lens.astype(jnp.int32),
+               jnp.asarray(layer, jnp.int32).reshape(1)]
+    if window is not None:
+        scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # block_tables, q_lens, ctx_lens, layer
+        # block_tables, q_lens, ctx_lens, layer (and the window)
+        num_scalar_prefetch=len(scalars),
         grid=(b,),
         in_specs=in_specs,
         out_specs=row_spec,
@@ -547,16 +633,16 @@ def ragged_paged_attention_pallas(q, k_pool, v_pool, block_tables,
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, cq, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         # the copies of a slot's first group start in the slot before
         # it: the grid runs in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(block_tables.astype(jnp.int32), q_lens.astype(jnp.int32),
-      ctx_lens.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+    )(*scalars, *operands)
+    if rep > 1:
+        return out.reshape(b, cq, hp, d)[:, :, :h]
     return out.reshape(b, cq, h, d)
 
 
@@ -564,7 +650,7 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens,
                            sm_scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
                            k_scales=None, v_scales=None,
-                           layer: Optional[int] = None):
+                           layer: Optional[int] = None, window=None):
     """Single-token decode kernel: Cq == 1 delegation to the ragged
     kernel (same visible-count ctx_lens convention as the reference
     specialization above)."""
@@ -572,7 +658,7 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens,
     out = ragged_paged_attention_pallas(
         q[:, None], k_pool, v_pool, block_tables,
         jnp.ones_like(ctx), ctx - 1, sm_scale, interpret,
-        k_scales=k_scales, v_scales=v_scales, layer=layer)
+        k_scales=k_scales, v_scales=v_scales, layer=layer, window=window)
     return out[:, 0]
 
 
@@ -627,7 +713,7 @@ def resolved_form() -> str:
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                     sm_scale: Optional[float] = None,
                     k_scales=None, v_scales=None,
-                    layer: Optional[int] = None):
+                    layer: Optional[int] = None, window=None):
     """Decode-step attention over the paged KV pool, in the form
     resolved_form() answers: "pallas", the blocked kernel, on a TPU
     (interpret mode where a test pins it elsewhere); "reference", the
@@ -639,7 +725,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     (an int or a traced scalar) says the pools are the stacked
     `[layers, N, bs, ...]` arrays and picks the layer to read, in both
     forms without a slice. Pools may be float32, bfloat16 (read as
-    they are and upcast where they are multiplied) or quantized."""
+    they are and upcast where they are multiplied) or quantized.
+
+    `window` (an int or a traced scalar, as `layer`; 0: none) keeps a
+    query at position i to the keys `i - window < j <= i`. The
+    reference form masks; the Pallas form starts a slot's loop at the
+    first group that holds a visible key, so a window layer costs
+    what the window spans, not the context. With `window=None` both
+    forms are the program they were before there was one.
+
+    GROUPED key-value heads: where the pool's row holds fewer heads
+    than q (`H * D` a multiple of the row), query head h reads
+    key-value head `h // rep`."""
     mode = resolved_form()
     # ONE device-trace name for the gather and the attention over it,
     # in either form: a kernel change is read by the same metric
@@ -648,17 +745,19 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
             return paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                           ctx_lens, sm_scale,
                                           k_scales=k_scales,
-                                          v_scales=v_scales, layer=layer)
+                                          v_scales=v_scales, layer=layer,
+                                          window=window)
         return paged_attention_reference(q, k_pool, v_pool, block_tables,
                                          ctx_lens, sm_scale,
                                          k_scales=k_scales,
-                                         v_scales=v_scales, layer=layer)
+                                         v_scales=v_scales, layer=layer,
+                                         window=window)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
                            ctx_lens, sm_scale: Optional[float] = None,
                            k_scales=None, v_scales=None,
-                           layer: Optional[int] = None):
+                           layer: Optional[int] = None, window=None):
     """Mixed prefill+decode attention over the paged KV pool: q
     `[B, Cq, H, D]` with per-row true query length (1 = decode, chunk
     width = prefill). Routed like the decode entry (resolved_form:
@@ -670,7 +769,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
             return ragged_paged_attention_pallas(
                 q, k_pool, v_pool, block_tables, q_lens, ctx_lens,
                 sm_scale, k_scales=k_scales, v_scales=v_scales,
-                layer=layer)
+                layer=layer, window=window)
         return ragged_paged_attention_reference(
             q, k_pool, v_pool, block_tables, q_lens, ctx_lens, sm_scale,
-            k_scales=k_scales, v_scales=v_scales, layer=layer)
+            k_scales=k_scales, v_scales=v_scales, layer=layer,
+            window=window)

@@ -285,6 +285,31 @@ def _module_text(compiled) -> str:
         return compiled.as_text()
 
 
+# A kernel call the COMPILER put in place of a jax operation names
+# itself and loses the operation's path (`jax.lax.ragged_dot` on a TPU
+# becomes `%ragged-dot-none = custom-call(...)` with
+# `op_name="ragged-dot-none"`: the grouped expert products, a third of
+# the expert family's step, read as unscoped on the chip, PR 38). Such
+# a call lies where its operands were made.
+_HLO_PATHLESS_CALL = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([^\s=]+)\s=\scustom-call\(([^)]*)\).*?'
+    r'\bop_name="([^"/]*)"', re.M)
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def _adopt_pathless_calls(text: str, table: Dict[str, str]) -> None:
+    """Give each custom call whose `op_name` is no path the path of
+    the first of its operands that has one, its own name last
+    (`.../moe/moe_experts/ragged-dot-none`)."""
+    for m in _HLO_PATHLESS_CALL.finditer(text):
+        for operand in _HLO_OPERAND.findall(m.group(2)):
+            path = table.get(operand, "")
+            if "/" in path:
+                table[m.group(1)] = "%s/%s" % (path.rsplit("/", 1)[0],
+                                               m.group(3))
+                break
+
+
 def note_device_program(compiled) -> None:
     """Remember which scope path each instruction of one compiled
     program (a `jax.stages.Compiled`) lies under. An observation, never
@@ -294,6 +319,7 @@ def note_device_program(compiled) -> None:
         name = _HLO_MODULE.match(text).group(1)
         table = {m.group(1): m.group(2)
                  for m in _HLO_OP_NAME.finditer(text)}
+        _adopt_pathless_calls(text, table)
     except Exception:
         return
     with _DEVICE_LOCK:

@@ -396,3 +396,113 @@ def test_looped_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < 15.75 * 2 ** 30
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+# The expert family (generation/moe_window.py) at the sizes of the
+# benchmark's cell `k_exaone_236b_reason_c128`: 7.42 GB of bfloat16
+# weights, a bfloat16 pool of 5 cache layers of rows 8 x 128 under 64
+# query heads, 10,240 blocks of 16, 136 slots a step, the cache layer
+# AND the window traced scalars from inside the layer loop.
+_EXPERT_POOL = _sds((5, 10240, 16, 1024), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("window", [None, 128], ids=["full", "window"])
+def test_grouped_heads_and_a_traced_window_compile_for_v5e(one_chip,
+                                                           window):
+    """The kernel alone at the cell's geometry: a key-value row of 8
+    heads under 64 query heads, G = 8 blocks a loop step (2 MiB of
+    tiles), the window a fifth prefetched scalar; the pools are read
+    where they lie."""
+    from paddle_tpu.kernels import paged_attention as pa
+    assert pa.blocks_per_step(16, 2048, 80) == 8
+
+    def attend(q, kp, vp, tables, ctx, layer, win):
+        return pa.paged_attention_pallas(
+            q, kp, vp, tables, ctx, interpret=False, layer=layer,
+            window=None if window is None else win)
+    txt = _compile(attend, one_chip, _sds((136, 64, 128), jnp.float32),
+                   _EXPERT_POOL, _EXPERT_POOL, _sds((136, 80), jnp.int32),
+                   _sds((136,), jnp.int32), _sds((), jnp.int32),
+                   _sds((), jnp.int32))
+    assert txt.count("tpu_custom_call") == 1
+    assert not re.search(r"= bf16\[5,10240,16,1024\]\S* (?!parameter)", txt)
+
+
+@pytest.mark.parametrize("form", ["reference", "pallas"])
+def test_expert_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch,
+                                                  form):
+    """The cell's whole step (the dense layer and four sparse layers at
+    the published widths, 16 of 128 experts held, bfloat16 weights and
+    pools, the routing counts, the sampler) as the engine jits it: the
+    sparse layers ONE loop body, the grouped expert products two kernel
+    calls over the experts' leaves WHERE THEY LIE (a layer's slice of
+    them, 805 MB and 403 MB, is never copied out: temporaries of 23 MB,
+    stated below), each pool aliased to its output and never copied,
+    weights + pools + temporaries inside the chip's memory."""
+    import json
+    import os
+    from paddle_tpu.generation import moe_window, sample_tokens
+    from paddle_tpu.kernels import paged_attention as pa
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    src = json.load(open(os.path.join(root, "benchmark", "configs",
+                                      "k_exaone_236b.json")))
+    eng, held = src["engine"], src["experts_held"]
+    cfg = moe_window.ExpertDecoderConfig.from_source(
+        src, eng["max_context"], (held["first"], held["count"]))
+    params = {k: _sds(s, jnp.bfloat16)
+              for k, (s, _) in moe_window.leaf_shapes(cfg).items()}
+    assert _EXPERT_POOL.shape == (cfg.kv_layers,
+                                  eng["kv_pool_tokens"] // 16, 16,
+                                  cfg.kv_row)
+    t, m, sw = eng["decode_width"] + 8, eng["max_context"] // 16, \
+        eng["decode_width"]
+
+    def mixed(params, kp, vp, tables, positions, tokens, slots, temps,
+              tks, tps, seeds, steps):
+        logits, kp, vp, loads = cfg.forward_paged(
+            params, kp, vp, tables, positions, tokens,
+            live=tables[:, 0] != 0)
+        with jax.named_scope("sampler"):
+            nxt = sample_tokens(logits[slots], temps, tks, tps, seeds,
+                                steps)
+        return jnp.concatenate([nxt, loads.reshape(-1)]), kp, vp
+    i32, f32 = jnp.int32, jnp.float32
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, _EXPERT_POOL, _EXPERT_POOL, _sds((t, m), i32),
+         _sds((t,), i32), _sds((t,), i32), _sds((sw,), i32),
+         _sds((sw,), f32), _sds((sw,), i32), _sds((sw,), f32),
+         _sds((sw,), i32), _sds((sw,), i32)))
+    with pa.kernel_form(form):
+        compiled = jax.jit(mixed, donate_argnums=(1, 2)).lower(
+            *args).compile()
+    txt = compiled.as_text()
+    # the four sparse layers are one loop (the one dense layer's loop of
+    # a single trip is inlined by the compiler)
+    assert txt.count(" while(") == 1
+    # ONE conditional, the sampler's batch-level branch (this family
+    # sorts outside it too: the router's choice and the pairs by expert)
+    assert txt.count(" conditional(") == 1
+    # the two grouped products and their metadata, and in the Pallas
+    # form the attention of the dense layer and of the loop's body
+    assert txt.count("tpu_custom_call") == (5 if form == "pallas" else 3)
+    assert txt.count("ragged-dot-none") >= 2
+    head = txt.splitlines()[0]
+    alias = head[head.index("input_output_alias"):]
+    alias = alias[:alias.index("}, entry_computation_layout")]
+    assert len(re.findall(r"\(\d+, \{\}", alias)) == 2, alias
+    made = re.findall(r"= bf16\[5,10240,16,1024\]\S* ([\w\-]+)\(", txt)
+    assert set(made) <= {"parameter", "get-tuple-element", "fusion",
+                         "scatter", "dynamic-update-slice"}, set(made)
+    # nothing of a layer's experts' size is made: they are read in place
+    assert not re.search(r"= bf16\[16,(6144,4096|2048,6144)\]\S* "
+                         r"(?!bitcast|parameter|get-tuple-element)", txt)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 5 * 10240 * 16 * 1024 * 2
+    weights = 2 * sum(math.prod(s.shape) for s in params.values())
+    assert abs(weights / 1e9 - 7.42) < 0.01
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    if form == "pallas":
+        assert mem.temp_size_in_bytes < 64 * 2 ** 20

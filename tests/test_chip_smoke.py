@@ -24,7 +24,7 @@ def _smoke(*args):
 
 @pytest.mark.parametrize("args,phases", [
     (("--tiny",), ["device", "train", "static", "generate",
-                  "generate_looped"]),
+                  "generate_looped", "generate_expert"]),
     (("--tiny", "--chips", "4"), ["device", "mesh"]),
 ], ids=["one_chip_phases", "mesh_phase_only"])
 def test_tiny_rehearsal_runs_its_phases_and_claims_nothing(args, phases):

@@ -28,8 +28,8 @@ ENGINE_CHILDREN = ("pt/engine/admit", "pt/engine/plan", "pt/engine/dispatch",
 TRAIN_CHILDREN = ("pt/trainstep/stage", "pt/trainstep/dispatch")
 
 
-def _engine():
-    cfg = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=2,
+def _engine(hidden=32, layers=2):
+    cfg = DecoderConfig(vocab_size=64, hidden=hidden, layers=layers, heads=2,
                         max_seq_len=64)
     eng = GenerationEngine(cfg, init_params(cfg, 0), decode_width=4,
                            num_blocks=32)
@@ -73,7 +73,10 @@ def traced(tmp_path_factory):
     FLAGS_telemetry off, read back as the benchmark reads its traces."""
     from benchmark import trace_reduce
     assert not telemetry.enabled()
-    eng = _engine()
+    # wide enough that the device's step outlasts the host's: `fetch`, the
+    # wait for XLA:CPU, is then most of a step on an idle machine as under
+    # the suite's load (test_the_children_cover_the_engine_step)
+    eng = _engine(hidden=512, layers=6)
     step, batch = _train_step()
     step(*batch)                          # build and compile outside
     profiler.reset_profiler()
@@ -143,7 +146,15 @@ def test_the_children_cover_the_engine_step(traced, taken_out):
     for XLA:CPU, is 95 % of it. With that child taken out (the planted
     fault: a span that went missing) the rest must NOT cover; the
     small children's shares shrink under load, so no limit on the sum
-    tells one of THEM missing."""
+    tells one of THEM missing.
+
+    PR 38: the engine traced is wide enough (hidden 512, 6 layers) that
+    the device's step, 10 ms on XLA:CPU, outlasts the host's on an idle
+    machine too. With the toy of hidden 32 a step alone lasted 0.3-0.9
+    ms, 20-120 us of it between the children, and the share read 94.4 %;
+    it had passed only where a pause of 28 ms (the collector's, by its
+    size) fell inside the first traced `fetch`, which a change to what
+    the compile allocates moved out of the trace."""
     line = next(l for l in traced["lines"]
                 if any(e[0] == "pt/engine/step" for e in l))
     steps = covered = 0
@@ -378,6 +389,36 @@ def test_the_table_is_bounded_and_never_raises():
     newest = "jit_probe_%d" % (telemetry._DEVICE_PROGRAMS + 3)
     assert newest in table and "jit_probe_0" not in table
     assert any(p.endswith("/mlp/mul") for p in table[newest].values())
+
+
+def test_a_kernel_call_the_compiler_named_itself_adopts_its_operands_path():
+    """What the TPU's compiler makes of `jax.lax.ragged_dot` (lines of
+    the expert family's step compiled for a v5e, PR 38): a custom call
+    whose `op_name` is its own name. It lies where the first of its
+    operands with a path was made; a custom call that HAS a path keeps
+    it, and one with no operand to tell stays as it is."""
+    text = """HloModule jit_generation_mixed_abc, is_scheduled=true
+  %fusion.208 = fusion(%x), kind=kLoop, metadata={op_name="jit(mixed)/while/body/closed_call/moe/moe_experts/gather" stack_frame_id=137}
+  %dynamic_update_slice.18 = fusion(%y), kind=kLoop, metadata={op_name="jit(mixed)/while/body/closed_call/moe/moe_router/dynamic_update_slice"}
+  %ragged-dot-metadata = custom-call(%dynamic_update_slice.18), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %get-tuple-element.797 = get-tuple-element(%ragged-dot-metadata), index=3
+  %ragged-dot-none = custom-call(%get-tuple-element.797, /*index=5*/%fusion.208, %bitcast.234), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %paged_attention.13 = custom-call(%a, %fusion.208), custom_call_target="tpu_custom_call", metadata={op_name="jit(mixed)/while/body/closed_call/paged_attention/pallas_call"}
+  %lonely = custom-call(%get-tuple-element.797), custom_call_target="X", metadata={op_name="lonely"}
+"""
+    table = {m.group(1): m.group(2)
+             for m in telemetry._HLO_OP_NAME.finditer(text)}
+    assert table["ragged-dot-none"] == "ragged-dot-none"
+    telemetry._adopt_pathless_calls(text, table)
+    assert table["ragged-dot-none"] == \
+        "jit(mixed)/while/body/closed_call/moe/moe_experts/ragged-dot-none"
+    assert table["ragged-dot-metadata"] == \
+        "jit(mixed)/while/body/closed_call/moe/moe_router/ragged-dot-metadata"
+    assert table["paged_attention.13"].endswith("paged_attention/pallas_call")
+    assert table["lonely"] == "lonely"
+    from benchmark import trace_scopes
+    assert trace_scopes.scopes_of(table["ragged-dot-none"])[0] == \
+        ["moe", "moe_experts"]
 
 
 def test_a_cached_entry_is_named_by_tag_and_fingerprint(tmp_path):
