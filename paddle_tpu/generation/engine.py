@@ -51,18 +51,19 @@ request's token stream what it is without them:
 
 - PREFIX CACHE (FLAGS_generation_prefix_cache):
   admission asks the PrefixCache (kv_cache.py) for the longest cached
-  chunk chain matching the new prompt and attaches those immutable
-  blocks read-only (refcounted) — prefill starts at the first uncached
-  chunk, so a shared-prefix fleet pays prefill once and TTFT collapses
-  to ~one chunk. As a prompt streams in, every completed chunk
-  boundary is published back to the cache. K/V at a position
+  chain of whole blocks matching the new prompt and attaches those
+  immutable blocks read-only (refcounted) — prefill starts at the first
+  uncached block, so a shared-prefix fleet pays prefill once and TTFT
+  collapses to ~one chunk. As a prompt streams in, every completed
+  block boundary is published back to the cache (a partial block never
+  is: nothing shared is written again). K/V at a position
   is a function of the tokens at or before it, so a cached block holds
   what a cold recompute would write — tests/test_generation_prefix.py
-  holds hit streams to cold streams. Any write into
-  a still-shared block (divergence after the common prefix, or a
-  producer growing past a published partial block) goes through
-  COPY-ON-WRITE first: the ledger swaps in a private block and a
-  one-block compiled copy clones the pool rows.
+  holds hit streams to cold streams. The one write into a still-shared
+  block — the re-run of the last token of a fully cached prompt whose
+  length is a block multiple — goes through COPY-ON-WRITE first: the
+  ledger swaps in a private block and a one-block compiled copy clones
+  the pool rows.
 
 - SPECULATIVE DECODING (FLAGS_generation_spec_tokens = k > 0): a cheap
   drafter — "ngram" prompt-lookup (host-side, default) or a small
@@ -377,11 +378,10 @@ class GenerationEngine:
         # model's config is known (it makes whichever are missing)
         self.k_pools = self.v_pools = None
         self.k_scales = self.v_scales = None
-        # cross-request prefix cache (the chunk is the hash unit)
+        # cross-request prefix cache (the pool's block is the unit)
         pc_on = bool(prefix_cache if prefix_cache is not None
                      else get_flag("FLAGS_generation_prefix_cache"))
-        self.prefix_cache = (PrefixCache(self.kv, self.prefill_chunk)
-                             if pc_on else None)
+        self.prefix_cache = PrefixCache(self.kv) if pc_on else None
         # drafter for speculative decoding: "ngram" is a host-side
         # prompt-lookup (zero device cost); "model" runs a small draft
         # decoder over its OWN paged pools indexed by the same tables
@@ -941,7 +941,7 @@ class GenerationEngine:
 
     def _admit_chunked(self, seq: _Seq, lane: int) -> bool:
         """Park `seq` in `lane` for chunked prefill: walk the prefix
-        cache for the longest cached chunk chain, attach those shared
+        cache for the longest cached block chain, attach those shared
         blocks plus private blocks for the rest of the prompt + the
         first decode token all-or-nothing (a half-provisioned prompt
         would stall mid-prefill holding blocks), then let the mixed
@@ -1001,8 +1001,7 @@ class GenerationEngine:
                 stat_add("STAT_generation_prefix_hits")
                 stat_add("STAT_generation_prefix_hit_tokens",
                          cached_use)
-                tr.event("prefix_hit_chunks", tokens=cached_use,
-                         chunks=cached_use // self.prefill_chunk,
+                tr.event("prefix_hit", tokens=cached_use,
                          blocks=len(shared))
             else:
                 stat_add("STAT_generation_prefix_misses")
@@ -1572,10 +1571,11 @@ class GenerationEngine:
         return {ln: d for ln, d in out.items() if d}
 
     def _publish_prefix(self, seq: _Seq) -> None:
-        """Offer every newly completed chunk boundary of `seq`'s prompt
+        """Offer every newly completed block boundary of `seq`'s prompt
         to the prefix cache (the cache increfs the covering blocks).
-        The producer's own NEXT write into a just-published partial
-        block will COW first, so the published version stays frozen."""
+        The boundaries are whole blocks, so the producer's next write
+        lands in a private block and the published ones stay frozen
+        with no copy."""
         pc = self.prefix_cache
         if pc is None or seq.pkeys is None:
             return

@@ -290,9 +290,8 @@ class KVCacheManager:
 
 
 class _PrefixEntry:
-    """One cached chunk-aligned prefix: `tokens` prompt tokens whose
-    K/V lives in `blocks` (the last block may be partial — a consumer
-    that writes into it copy-on-writes first)."""
+    """One cached prefix of whole blocks: `tokens` prompt tokens (a
+    multiple of the block size) whose K/V fills `blocks`."""
 
     __slots__ = ("key", "tokens", "blocks")
 
@@ -305,14 +304,23 @@ class _PrefixEntry:
 class PrefixCache:
     """Cross-request prefix reuse over the paged pool (PR 14).
 
-    Prompts are hashed CHUNK-ALIGNED — `FLAGS_generation_prefill_chunk`
-    is the unit, matching how the mixed step streams them in — with a
-    RUNNING hash over the token ids, so only identical prefixes ever
-    collide: key_i = sha256(tokens[0 : i * chunk]), computed
-    incrementally. An entry per boundary (plus one for the full
-    prompt) references the blocks covering that many tokens; admission
-    walks the chain upward and stops at the first uncached boundary,
-    so the new request starts prefill at the first uncached chunk.
+    Prompts are hashed at WHOLE-BLOCK boundaries — the pool's
+    `block_size` is the unit, whatever the prefill chunk — with a
+    RUNNING hash over the token ids, so only identical prefixes
+    ever collide: key_i = sha256(tokens[0 : i * block_size]), computed
+    incrementally. An entry per boundary references the blocks
+    covering that many tokens; a prompt's partial last block is never
+    published. Admission walks the chain upward and stops at the first
+    uncached boundary, so the new request starts prefill at the first
+    uncached block.
+
+    Whole blocks only, so no entry ever holds a block that anyone will
+    write again: a producer's next chunk or first decode token lands in
+    a block of its own, and copy-on-write is reached only when a
+    consumer re-runs the last token of a fully cached prompt whose
+    length is a block multiple. The trade: a hit is rounded down to a
+    block (at most block_size - 1 more prompt tokens prefilled), as in
+    vLLM's automatic prefix caching.
 
     Entries hold real refcounts on their blocks (KVCacheManager), so a
     producing sequence may retire — or be preempted — while its prefix
@@ -325,9 +333,8 @@ class PrefixCache:
     through the engine's copy-on-write step first.
     """
 
-    def __init__(self, kv: KVCacheManager, chunk: int):
+    def __init__(self, kv: KVCacheManager):
         self.kv = kv
-        self.chunk = max(1, int(chunk))
         self._entries: "OrderedDict[str, _PrefixEntry]" = OrderedDict()
         # block -> number of entries that hold it (a running count,
         # moved by insert and _drop_oldest: held_blocks is its length)
@@ -337,20 +344,15 @@ class PrefixCache:
     # --- hashing -------------------------------------------------------
 
     def keys_for(self, prompt: Sequence[int]) -> List[Tuple[int, str]]:
-        """[(boundary_tokens, key)] for every chunk boundary of the
-        prompt, ending with the full prompt length. The running hash
+        """[(boundary_tokens, key)] for every whole-block boundary of
+        the prompt; a partial last block has none. The running hash
         makes key_i a pure function of tokens[:boundary_i]."""
-        n = len(prompt)
+        bs = self.kv.block_size
         toks = np.asarray(prompt, np.int64)
         h = hashlib.sha256()
         out: List[Tuple[int, str]] = []
-        prev = 0
-        bounds = list(range(self.chunk, n + 1, self.chunk))
-        if not bounds or bounds[-1] != n:
-            bounds.append(n)
-        for b in bounds:
-            h.update(toks[prev:b].tobytes())
-            prev = b
+        for b in range(bs, len(toks) + 1, bs):
+            h.update(toks[b - bs:b].tobytes())
             out.append((b, h.hexdigest()))
         return out
 
@@ -367,7 +369,7 @@ class PrefixCache:
 
     def match(self, prompt: Sequence[int]
               ) -> Optional[Tuple[int, List[int]]]:
-        """Longest cached chunk chain covering a prefix of `prompt`:
+        """Longest cached block chain covering a prefix of `prompt`:
         returns (cached_tokens, blocks) or None. Walks the chain
         upward, touching every hit (LRU order stays chain-monotone),
         and stops at the first miss — insertion always publishes

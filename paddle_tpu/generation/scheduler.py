@@ -387,8 +387,7 @@ class GenerationPool:
             # the fresh ledger has no refcounts for the old entries —
             # survivors would be dangling. Rebuilding re-publishes the
             # prefix gauges at zero.
-            eng.prefix_cache = type(eng.prefix_cache)(
-                eng.kv, eng.prefill_chunk)
+            eng.prefix_cache = type(eng.prefix_cache)(eng.kv)
         eng._restore_pools()
         eng._lane_seq = [None] * eng.decode_width
         eng._tables[:] = 0

@@ -19,7 +19,11 @@ PR 37: the ledger's gauges are running counts. A recount oracle holds
 them to the formulas they replaced after every call of a random walk,
 a counted (not timed) test holds one mutation's cost independent of
 the pool's size, and an engine run holds them through prefix hits,
-copy-on-write and preemption."""
+copy-on-write and preemption.
+
+PR 39: the cache publishes and matches whole blocks only, so a run
+that shares nothing copies nothing; copy-on-write is reached through
+an exact duplicate of a prompt whose length is a block multiple."""
 import sys
 
 import numpy as np
@@ -58,7 +62,7 @@ def _engine(params, **kw):
     return GenerationEngine(CFG, params, **kw)
 
 
-# a 16-token prefix = two full chunks of 8; suffixes diverge after it
+# a 16-token prefix = four whole blocks of 4; suffixes diverge after it
 PREFIX = [7, 3, 11, 2, 9, 14, 5, 8, 21, 4, 13, 6, 17, 10, 1, 12]
 
 
@@ -168,13 +172,14 @@ def test_shared_prefix_streams_bitwise_identical_to_cold(params):
 
 
 def test_cow_divergence_under_concurrent_sequences(params):
-    """chunk 6 on block_size 4 puts the cached boundary MID-block:
-    every consumer's first write lands in a still-shared block and
-    must copy-on-write, while the producer keeps decoding — streams
-    stay bitwise-identical to a no-sharing run."""
-    shared6 = PREFIX[:6]
+    """Exact duplicates of an 8-token prompt on blocks of 4 (chunk 6,
+    no divisor of the block): a consumer admitted after the producer
+    published hits both blocks and re-runs the last prompt token INTO
+    the last shared block, so it must copy-on-write while earlier
+    lanes keep decoding — streams stay bitwise-identical to a
+    no-sharing run."""
     reqs = [GenerationRequest(
-        prompt=shared6 + [30 + i, 31 + i, 32 + i], max_new_tokens=5,
+        prompt=PREFIX[:8], max_new_tokens=3 + 2 * (i % 3),
         sampling=SamplingParams(temperature=0.85, seed=i),
         request_id=i) for i in range(6)]
     want = _streams(
@@ -187,6 +192,54 @@ def test_cow_divergence_under_concurrent_sequences(params):
     # divergence never corrupted the ledger: nothing still tabled
     assert not eng.kv._tables
     assert eng.kv.used_blocks == eng.prefix_cache.held_blocks
+
+
+@pytest.mark.parametrize("chunk,block", [(6, 4), (8, 16), (24, 16)])
+def test_keys_are_whole_blocks_only(params, chunk, block):
+    """PR 39: the cache's unit is the pool's block, whatever the chunk.
+    `keys_for` cuts at block multiples only (no partial tail), each
+    key a pure function of the tokens before its boundary; a served
+    prompt of no block multiple leaves only whole-block entries."""
+    eng = _engine(params, prefill_chunk=chunk, block_size=block,
+                  num_blocks=16, decode_width=2)
+    pc = eng.prefix_cache
+    prompt = list(range(1, 41))
+    for n in (3, block, block + 1, 2 * block - 1, 2 * block, 40):
+        keys = pc.keys_for(prompt[:n])
+        assert [b for b, _ in keys] == list(range(block, n + 1, block))
+        for b, key in keys:
+            assert pc.keys_for(prompt[:b])[-1] == (b, key)
+    n = 2 * block + 3
+    eng.generate([GenerationRequest(prompt=prompt[:n], max_new_tokens=3)])
+    got = sorted(e.tokens for e in pc._entries.values())
+    assert got == list(range(block, n + 1, block))
+
+
+def test_unshared_prompts_never_copy_and_a_repeat_hits_whole_blocks(
+        params):
+    """The benchmark's serve geometry in small (chunk 8, blocks of
+    16): unshared prompts of lengths that are no block multiple run
+    with NO copy-on-write (until PR 39 each left a mid-block entry its
+    producer then copied); a repeated one still hits, a block multiple
+    of tokens, and serves its cold stream."""
+    rng = np.random.RandomState(39)
+    reqs = [GenerationRequest(
+        prompt=list(rng.randint(0, CFG.vocab_size, n)), max_new_tokens=6,
+        request_id=i) for i, n in enumerate((17, 21, 25, 30, 19, 23))]
+    want = _streams(_engine(params, block_size=16, prefix_cache=False),
+                    [GenerationRequest(**r.__dict__) for r in reqs])
+    eng = _engine(params, block_size=16)
+    c0 = stat_get("STAT_generation_prefix_cow_copies")
+    h0 = stat_get("STAT_generation_prefix_hits")
+    t0 = stat_get("STAT_generation_prefix_hit_tokens")
+    assert _streams(eng, reqs) == want
+    assert stat_get("STAT_generation_prefix_hits") == h0
+    assert all(e.tokens % 16 == 0 for e in eng.prefix_cache._entries.values())
+    again = GenerationRequest(**reqs[1].__dict__)
+    assert _streams(eng, [again]) == {1: want[1]}
+    assert stat_get("STAT_generation_prefix_hits") == h0 + 1
+    assert stat_get("STAT_generation_prefix_hit_tokens") - t0 == 16
+    assert stat_get("STAT_generation_prefix_cow_copies") == c0
 
 
 def test_lru_eviction_and_preemption_replay_under_kv_alloc_fault(
@@ -397,7 +450,7 @@ def test_ledger_counts_equal_a_recount_after_every_call(seed):
     rng = np.random.RandomState(1000 + seed)
     kv = KVCacheManager(num_blocks=int(rng.choice([12, 24, 40])),
                         block_size=4)
-    cache = PrefixCache(kv, chunk=8)
+    cache = PrefixCache(kv)
     # few distinct prompts over two stems, so chains are shared,
     # matched and re-inserted
     stems = [list(rng.randint(0, 64, 16)) for _ in range(2)]
@@ -503,17 +556,17 @@ def test_ledger_counts_equal_a_recount_after_every_call(seed):
 def _full_cache(num_blocks):
     """A ledger whose prefix cache holds the whole pool but three
     blocks: retired requests of 4 blocks of 16 tokens, an entry at
-    every 8-token boundary, and one live sequence whose first two
+    every block boundary, and one live sequence whose first two
     blocks a second table shares."""
     kv = KVCacheManager(num_blocks=num_blocks, block_size=16)
-    cache = PrefixCache(kv, chunk=8)
+    cache = PrefixCache(kv)
     live = kv.alloc("live", 4)
     kv.attach("twin", live[:2], 0)
     sid = 0
     while kv.free_blocks >= 4:
         owned = kv.alloc(sid, 4)
-        for i in range(1, 9):
-            cache.insert("%d/%d" % (sid, i), 8 * i, owned[:(i + 1) // 2])
+        for i in range(1, 5):
+            cache.insert("%d/%d" % (sid, i), 16 * i, owned[:i])
         kv.free(sid)
         sid += 1
     assert kv.free_blocks == 3 and cache.held_blocks == 4 * sid
@@ -565,14 +618,16 @@ def test_a_ledger_mutation_costs_what_it_touches_not_the_pool(mutation):
 def test_engine_gauges_equal_a_recount_through_hits_cow_and_preemption(
         params):
     """The engine's own traffic over a pool too small for it: prefix
-    hits, copy-on-write (chunk 6 on blocks of 4 puts the cached
-    boundary mid-block), LRU eviction and preemption. After every step
-    and at the end the gauges equal the recount."""
+    hits, copy-on-write (every other prompt an exact duplicate of
+    8 tokens on blocks of 4, whose re-run last token lands in a shared
+    block), LRU eviction and preemption. After every step and at the
+    end the gauges equal the recount."""
     reqs = [GenerationRequest(
-        prompt=PREFIX[:6] + [30 + i, 31 + i, 32 + i] * 3,
+        prompt=PREFIX[:8] + ([30 + i, 31 + i, 32 + i] * 2 if i % 2
+                             else []),
         max_new_tokens=8, sampling=SamplingParams(), request_id=i)
         for i in range(10)]
-    eng = _engine(params, prefill_chunk=6, num_blocks=14)
+    eng = _engine(params, prefill_chunk=6, num_blocks=18)
     before = {k: stat_get(k) for k in (
         "STAT_generation_prefix_hits", "STAT_generation_prefix_cow_copies",
         "STAT_generation_prefix_evictions", "STAT_generation_evictions")}

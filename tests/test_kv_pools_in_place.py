@@ -61,7 +61,10 @@ def _held(eng):
 
 def _reqs():
     """Greedy and sampled lanes over one shared 16-token prefix, so the
-    prefix cache publishes, attaches and the `cow` program runs."""
+    prefix cache publishes whole blocks, and last the prefix alone: an
+    exact duplicate of four cached blocks, admitted once a lane frees,
+    whose re-run last token lands in a shared block, so `cow` (and
+    `draft_cow`) run on the live pools."""
     prefix = [7, 3, 11, 2, 9, 14, 5, 8, 21, 4, 13, 6, 17, 10, 1, 12]
     sps = [SamplingParams(),
            SamplingParams(temperature=0.8, seed=101),
@@ -69,7 +72,10 @@ def _reqs():
            SamplingParams(temperature=0.7, top_p=0.9, seed=303)]
     return [GenerationRequest(prompt=prefix + [40 + i, 41 + i],
                               max_new_tokens=6, sampling=sp, request_id=i)
-            for i, sp in enumerate(sps)]
+            for i, sp in enumerate(sps)] + [GenerationRequest(
+                prompt=list(prefix), max_new_tokens=6,
+                sampling=SamplingParams(temperature=0.8, seed=404),
+                request_id=len(sps))]
 
 
 _INSTR = re.compile(
@@ -142,7 +148,7 @@ def test_pool_programs_update_in_place(tmp_path, params, cached, kv):
                        else eng.params)
     # a step leaves every array the engine held before it dead
     reqs = _reqs()
-    for r in reqs:
+    for r in reqs[:-1]:
         eng.submit(r)
     before = _held(eng)
     out = {r.request_id: r.tokens for r in eng.step()}
@@ -151,13 +157,18 @@ def test_pool_programs_update_in_place(tmp_path, params, cached, kv):
             continue            # the drafter runs only beside a decode
         assert old.is_deleted(), name
         assert not getattr(eng, name).is_deleted(), name
+    # the duplicate, after the first step has published: it hits and
+    # copies a shared block inside the steps checked below
+    eng.submit(reqs[-1])
     cow0 = stat_get("STAT_generation_compile")
+    copies0 = stat_get("STAT_generation_prefix_cow_copies")
     while not eng.idle:
         before = _held(eng)
         for r in eng.step():
             out[r.request_id] = r.tokens
     assert all(a.is_deleted() for a in before.values())
     assert stat_get("STAT_generation_compile") == cow0
+    assert stat_get("STAT_generation_prefix_cow_copies") > copies0
     # the streams are still the oracle's (int8 KV: the plain int8
     # engine's, which speculation reproduces bitwise)
     if kv == "fp32":

@@ -219,10 +219,12 @@ def test_composes_with_prefix_cache_cow_and_spec_decode(params):
     speculative decoding) over a QUANTIZED pool: greedy streams match
     the fp32 engine running the same stack, COW clones carry the scale
     rows, and the prefix hits really happened."""
-    shared = [3] * 8                       # shared prefix, 2 chunks
+    shared = [3] * 8                       # shared prefix, 2 blocks
     def reqs():
+        # the third, admitted once a lane frees, repeats the 8 cached
+        # tokens exactly: its re-run last token copies the last block
         return [GenerationRequest(request_id=i,
-                                  prompt=shared + [i + 1] * 2,
+                                  prompt=shared + [i + 1] * 2 * (i < 2),
                                   max_new_tokens=8,
                                   sampling=SamplingParams(seed=i))
                 for i in range(3)]
