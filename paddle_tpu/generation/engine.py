@@ -93,7 +93,7 @@ _tokens / _prefills / _evictions / _compile / _errors,
 STAT_generation_prefix_{hits,misses,hit_tokens,cow_copies} /
 _spec_{proposed,accepted} / _draft_faults,
 GAUGE_generation_active_seqs (+ kv_cache block + prefix gauges),
-TIMER_generation_mixed_step_us / _decode_step_us / _prefix_admit_us.
+TIMER_generation_mixed_step_us / _prefix_admit_us.
 
 Request tracing (tracing.py, docs/observability.md): every request
 carries a RequestTrace (opened by GenerationPool.submit, or by
@@ -166,8 +166,8 @@ class _Seq:
     """Host-side state of one in-flight sequence."""
 
     __slots__ = ("req", "ctx", "generated", "lane", "admit_order",
-                 "evictions", "t_last_token", "prefilled",
-                 "admit_failures", "pkeys", "published", "pending")
+                 "evictions", "prefilled", "admit_failures", "pkeys",
+                 "published", "pending")
 
     def __init__(self, req: GenerationRequest, admit_order: int):
         self.req = req
@@ -176,7 +176,6 @@ class _Seq:
         self.lane = -1
         self.admit_order = admit_order
         self.evictions = 0
-        self.t_last_token = time.perf_counter()
         self.prefilled = 0         # prompt tokens already in the pool
         self.admit_failures = 0    # consecutive transient re-admit fails
         self.pkeys = None          # [(boundary, hash)] — PrefixCache keys
@@ -1044,7 +1043,13 @@ class GenerationEngine:
     def _fetched(self, nxt) -> np.ndarray:
         """A step's result on the host: its sample rows, after what
         the family's step reported beside them went to the family's
-        counters (`_stats_len`)."""
+        counters (`_stats_len`). The wait for the device lies under
+        `pt/device/wait`; what is left of the caller's `fetch` is the
+        copy to the host, asked for before the wait so that it follows
+        the step on the device as `np.asarray` alone would have it."""
+        nxt.copy_to_host_async()
+        with _tm.span("pt/device/wait", track="generation"):
+            nxt.block_until_ready()
         nxt = np.asarray(nxt)
         if self._stats_len:
             self.cfg.record_step_stats(nxt[self.sample_width:])
@@ -1254,10 +1259,7 @@ class GenerationEngine:
         rpl = 1 + self.spec_tokens          # sampler rows per lane
         with _tm.span("pt/engine/emit", track="generation"):
             timer_observe("TIMER_generation_mixed_step_us", dt_us)
-            # the mixed step IS the decode step of this engine — keep the
-            # historic SLO timer (and its bench regression gate) alive
-            timer_observe("TIMER_generation_decode_step_us", dt_us)
-            now = time.perf_counter()
+            emitted = 0
             for ln, seq, row0, d in decode_plan:
                 s = len(d)
                 if s:
@@ -1272,10 +1274,7 @@ class GenerationEngine:
                     seq.ctx += 1
                     seq.generated.append(tok)
                     seq.req.trace.token()
-                    timer_observe("TIMER_generation_inter_token_us",
-                                  (now - seq.t_last_token) * 1e6)
-                    seq.t_last_token = now
-                    stat_add("STAT_generation_tokens")
+                    emitted += 1
                     done = self._finish_reason(seq)
                     if done is not None:
                         finished.append(self._retire(ln, done))
@@ -1301,11 +1300,11 @@ class GenerationEngine:
                     # TTFT lands at the TRUE first sampled token (first
                     # token() call only; replays re-observe TPOT)
                     seq.req.trace.token()
-                    seq.t_last_token = now
-                    stat_add("STAT_generation_tokens")
+                    emitted += 1
                     done = self._finish_reason(seq)
                     if done is not None:
                         finished.append(self._retire(ln, done))
+            stat_add("STAT_generation_tokens", emitted)
             gauge_set("GAUGE_generation_active_seqs", self.active_count)
 
     # --- lookahead: one step on the device while the host plans ---------
@@ -1349,7 +1348,8 @@ class GenerationEngine:
         if self._inflight is not None:
             finished.extend(self._collect())
         if plan is not None:
-            self._advance(decode_plan, chunk_plan)
+            with _tm.span("pt/engine/advance", track="generation"):
+                self._advance(decode_plan, chunk_plan)
             self._inflight = (nxt, decode_plan, chunk_plan, t_dispatch)
         return finished
 
@@ -1392,7 +1392,7 @@ class GenerationEngine:
             dt_us = (now - max(t_dispatch, self._t_collected)) * 1e6
             self._t_collected = now
             timer_observe("TIMER_generation_mixed_step_us", dt_us)
-            timer_observe("TIMER_generation_decode_step_us", dt_us)
+            emitted = 0
             rows = [(ln, seq) for ln, seq, _r, _d in decode_plan] + [
                 (ln, seq) for ln, seq, start, take in chunk_plan
                 if start + take == len(seq.req.prompt)]
@@ -1402,14 +1402,11 @@ class GenerationEngine:
                 seq.generated.append(int(nxt[ln]))
                 seq.pending -= 1
                 seq.req.trace.token()
-                if len(seq.generated) > 1:
-                    timer_observe("TIMER_generation_inter_token_us",
-                                  (now - seq.t_last_token) * 1e6)
-                seq.t_last_token = now
-                stat_add("STAT_generation_tokens")
+                emitted += 1
                 done = self._finish_reason(seq)
                 if done is not None:
                     finished.append(self._retire(ln, done))
+            stat_add("STAT_generation_tokens", emitted)
             gauge_set("GAUGE_generation_active_seqs", self.active_count)
         return finished
 

@@ -68,10 +68,9 @@ The generation engine (docs/generation.md) exposes:
   STAT_generation_blocks_allocated / _blocks_freed (KV ledger churn);
 - GAUGE_generation_blocks_free / _blocks_used (pool occupancy),
   _active_seqs, _queue_depth;
-- always-on TIMER_generation_mixed_step_us / _decode_step_us /
-  _inter_token_us histograms (tokens/s and p95 inter-token latency are
-  the generation SLO; bench.py's generation block gates on the
-  decode-step p95 via tools/stat_diff.py).
+- the always-on TIMER_generation_mixed_step_us histogram (bench.py's
+  generation block gates on its p95 via tools/stat_diff.py); per-token
+  latency is the request trace's TIMER_generation_tpot_us (tracing.py).
 
 The mesh-native SPMD runtime (paddle_tpu/mesh/, docs/spmd.md)
 exposes (always-on, like the serving timers):

@@ -23,16 +23,20 @@ from paddle_tpu.generation import (DecoderConfig, GenerationEngine,
 from paddle_tpu.generation.model import init_params
 from paddle_tpu.models import bert
 
+# a step of the lookahead loop: its six children, and inside `fetch` the
+# host's wait for the device (`pt/device/`)
 ENGINE_CHILDREN = ("pt/engine/admit", "pt/engine/plan", "pt/engine/dispatch",
-                   "pt/engine/fetch", "pt/engine/emit")
+                   "pt/engine/fetch", "pt/engine/advance", "pt/engine/emit")
+WAIT = "pt/device/wait"
+STEP_SPANS = ("pt/engine/step",) + ENGINE_CHILDREN + (WAIT,)
 TRAIN_CHILDREN = ("pt/trainstep/stage", "pt/trainstep/dispatch")
 
 
-def _engine(hidden=32, layers=2):
+def _engine(hidden=32, layers=2, **kw):
     cfg = DecoderConfig(vocab_size=64, hidden=hidden, layers=layers, heads=2,
                         max_seq_len=64)
     eng = GenerationEngine(cfg, init_params(cfg, 0), decode_width=4,
-                           num_blocks=32)
+                           num_blocks=32, **kw)
     eng.warmup()
     return eng
 
@@ -118,10 +122,13 @@ def _children(line, parent):
     return out
 
 
-def test_engine_step_lands_in_the_trace_with_its_five_children(traced):
-    line = next(l for l in traced["lines"]
+def _engine_line(traced):
+    return next(l for l in traced["lines"]
                 if any(e[0] == "pt/engine/step" for e in l))
-    steps = _children(line, "pt/engine/step")
+
+
+def test_engine_step_lands_in_the_trace_with_its_six_children(traced):
+    steps = _children(_engine_line(traced), "pt/engine/step")
     assert len(steps) == traced["engine_steps"]
     # a step with every phase: the engine runs one step ahead of the
     # host, so its first call dispatches and has nothing to fetch yet
@@ -129,8 +136,24 @@ def test_engine_step_lands_in_the_trace_with_its_five_children(traced):
             if "pt/engine/dispatch" in k and "pt/engine/fetch" in k]
     assert len(full) >= 3
     for kids in full:
-        assert sorted(kids) == sorted(ENGINE_CHILDREN)
+        # the children and, one level down, the device runtime's two
+        assert sorted(kids) == sorted(STEP_SPANS[1:])
         assert all(len(v) == 1 for v in kids.values())
+
+
+def test_the_wait_and_the_advance_lie_inside_fetch_and_the_step(traced):
+    """`pt/device/wait` inside every `pt/engine/fetch`, `pt/engine/advance`
+    inside a step of the lookahead loop: the benchmark's
+    `engine_fetch_wait_ms` and idle split read them so."""
+    line = _engine_line(traced)
+    found = _children(line, "pt/engine/fetch")
+    assert found
+    assert all(len(kids.get(WAIT, ())) == 1 for _, kids in found)
+    assert sum(e[0] == WAIT for e in line) == len(found)
+    advances = [k for _, k in _children(line, "pt/engine/step")
+                if "pt/engine/advance" in k]
+    assert len(advances) >= 3
+    assert sum(e[0] == "pt/engine/advance" for e in line) == len(advances)
 
 
 @pytest.mark.parametrize("taken_out", [(), ("pt/engine/fetch",)],
@@ -155,15 +178,15 @@ def test_the_children_cover_the_engine_step(traced, taken_out):
     it had passed only where a pause of 28 ms (the collector's, by its
     size) fell inside the first traced `fetch`, which a change to what
     the compile allocates moved out of the trace."""
-    line = next(l for l in traced["lines"]
-                if any(e[0] == "pt/engine/step" for e in l))
     steps = covered = 0
-    for (s, d), kids in _children(line, "pt/engine/step"):
+    for (s, d), kids in _children(_engine_line(traced), "pt/engine/step"):
         if "pt/engine/dispatch" not in kids or \
                 "pt/engine/fetch" not in kids:
             continue
         steps += d
-        covered += sum(cd for n, v in kids.items() if n not in taken_out
+        # the step's own children: `pt/device/` lies inside two of them
+        covered += sum(cd for n, v in kids.items()
+                       if n in ENGINE_CHILDREN and n not in taken_out
                        for _, cd in v)
     assert steps > 0
     assert (covered / steps >= 0.95) == (not taken_out), covered / steps
@@ -297,8 +320,72 @@ def test_span_budget_of_an_engine_step(monkeypatch):
     assert steps >= 3 and calls
     assert len(calls) <= 20 * steps
     per_step = len(calls) / steps
-    assert per_step <= 6, (per_step, sorted(set(calls)))
-    assert all(n.startswith("pt/engine/") for n in calls)
+    assert per_step <= 8, (per_step, sorted(set(calls)))
+    assert set(calls) == set(STEP_SPANS)
+
+
+def _transfers_in(span, tmp_path, drive):
+    """[how many of the runtime's transfer events lie inside it] for each
+    `span` of a profiler session around `drive()`, taken as the
+    benchmark's Tracer takes it (`benchmark/engine_trace.py`)."""
+    from benchmark import engine_trace, trace_reduce
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        drive()
+    finally:
+        jax.profiler.stop_trace()
+    evs = engine_trace.engine_events(trace_reduce.from_xplane(
+        trace_reduce.find_xplane(str(tmp_path))))
+    moves = [(s, e) for n, s, e in evs if n in engine_trace.TRANSFERS]
+    return [sum(s <= a and b <= e for a, b in moves)
+            for n, s, e in evs if n == span]
+
+
+@pytest.mark.parametrize("fault", [False, True],
+                         ids=["as_built", "put_before_dispatch"])
+def test_a_steps_upload_lies_under_dispatch(monkeypatch, tmp_path, fault):
+    """The compiled call takes the step's packed host arrays, and the
+    runtime's own `DevicePut` of them lies inside every
+    `pt/engine/dispatch`: `engine_upload_ms` and `engine_launch_ms` split
+    the dispatch by it. With the arrays put on the device before the
+    dispatch (the planted fault) no dispatch holds a transfer, and the
+    split would read the whole dispatch as the launch."""
+    from paddle_tpu.generation import engine as engine_mod
+    eng = _engine()
+    if fault:
+        real = engine_mod._pack_mixed
+        monkeypatch.setattr(engine_mod, "_pack_mixed", lambda *a: tuple(
+            jax.device_put(x) for x in real(*a)))
+    got = _transfers_in("pt/engine/dispatch", tmp_path, lambda: _drive(eng))
+    assert len(got) >= 3
+    assert all(got) == (not fault) and any(got) == (not fault), got
+
+
+def test_a_copy_on_writes_upload_lies_under_plan(tmp_path):
+    """`_copy_block`'s two block ids go to the device inside
+    `pt/engine/plan`, where `_provision` runs it, as the runtime's own
+    transfers: `engine_upload_ms` counts them beside the step's. Exact
+    duplicates of a prompt of two whole blocks, so a consumer re-runs its
+    last prompt token into a shared block."""
+    cfg = DecoderConfig(vocab_size=64, hidden=32, layers=2, heads=2,
+                        max_seq_len=64)
+    eng = GenerationEngine(cfg, init_params(cfg, 0), decode_width=4,
+                           num_blocks=64, block_size=4, prefill_chunk=6)
+    eng.warmup()
+
+    def drive():
+        for i in range(6):
+            eng.submit(GenerationRequest(prompt=[7, 3, 11, 2, 9, 14, 5, 8],
+                                         max_new_tokens=3 + 2 * (i % 3)))
+        while not eng.idle:
+            eng.step()
+    c0 = monitor.stat_get("STAT_generation_prefix_cow_copies")
+    got = _transfers_in("pt/engine/plan", tmp_path, drive)
+    copies = monitor.stat_get("STAT_generation_prefix_cow_copies") - c0
+    assert copies > 0 and sum(got) == 2 * copies, (copies, got)
 
 
 def test_span_budget_of_a_pool_round(monkeypatch):
@@ -317,7 +404,7 @@ def test_span_budget_of_a_pool_round(monkeypatch):
     steps = calls.count("pt/engine/step")
     assert steps >= 1
     assert {"pt/pool/wait", "pt/pool/admit", "pt/pool/deliver"} <= set(calls)
-    # the pool's three and the engine's six, a round
+    # the pool's three and the engine's eight, a round
     assert len(calls) <= 20 * steps
 
 

@@ -152,9 +152,12 @@ def test_exemplar_ring_bound_and_eviction():
     ids = []
     for i in range(6):
         tr = tracing.begin("serving")
-        time.sleep(0.004 * (i + 1))  # strictly increasing totals,
-        tr.finish()                  # spaced 4ms apart so scheduler
-        ids.append(tr.trace_id)      # jitter cannot reorder them
+        # strictly increasing totals, stamped: no sleep, so no scheduler
+        # of a loaded machine can reorder them (finish() keeps a `done`
+        # stage it finds)
+        tr.stages.append(("done", tr.t0 + 0.004 * (i + 1)))
+        tr.finish()
+        ids.append(tr.trace_id)
     kept = [r["trace_id"] for r in tracing.exemplars()]
     assert len(kept) == 3
     # the fastest traces were evicted, the slowest kept
