@@ -15,10 +15,10 @@ Layouts: q, k, v are [B, H, S, D]; bias is additive, broadcastable to
 [B, H, Sq, Sk] (dims of size 1 are broadcast in-kernel via BlockSpec
 index maps). Returns [B, H, Sq, D].
 
-The backward pass saves only out + logsumexp and recomputes score tiles
-(two Pallas kernels: one gridded over q-blocks for dQ, one over k-blocks
-for dK/dV) — the same memory/FLOPs trade the reference gets from
-recompute checkpointing (backward.py:145).
+The backward pass saves only out + logsumexp and recomputes each score
+tile ONCE (one Pallas kernel gridded over k-blocks: dK/dV per k-block,
+dQ accumulated in VMEM across the key axis) — the same memory/FLOPs
+trade the reference gets from recompute checkpointing (backward.py:145).
 """
 from __future__ import annotations
 
@@ -62,8 +62,8 @@ def dropout_paths_taken():
 
 def _drop_keep_tile(seed_ref, qi, ki, shape, keep_prob):
     """In-kernel attention-probs dropout tile: seed the per-core PRNG
-    from (base_seed, b, h, q_tile, k_tile) so every kernel (forward, dQ,
-    dK/dV) regenerates the IDENTICAL keep pattern for a tile without any
+    from (base_seed, b, h, q_tile, k_tile) so both kernels (forward and
+    backward) regenerate the IDENTICAL keep pattern for a tile without any
     [B,H,Sq,Sk] mask in HBM — the hardware-PRNG analog of the rbg8
     trick in ops/nn dropout. Returns keep/keep_prob (0 or 1/keep_prob),
     ready to multiply into the probs.
@@ -72,7 +72,7 @@ def _drop_keep_tile(seed_ref, qi, ki, shape, keep_prob):
     5-word call fails to compile on hardware), so the four tile
     coordinates are hash-combined into one word with distinct odd
     multipliers (xxhash/fxhash-style; int32 wraparound is the intended
-    mixing). Determinism across the three kernels only needs equal
+    mixing). Determinism across the two kernels only needs equal
     tuples -> equal seeds, which a pure function of the tuple gives."""
     ident = (pl.program_id(0) * jnp.int32(-1640531535)   # 0x9E3779B1
              + pl.program_id(1) * jnp.int32(-2048144777)  # 0x85EBCA77
@@ -283,63 +283,19 @@ def _fwd(q, k, v, bias, drop_mask, drop_seed, causal, sm_scale, block_q,
 
 
 # ---------------------------------------------------------------------------
-# backward kernels
+# backward kernel
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, drop_ref, seed_ref,
-                   do_ref, lse_ref, delta_ref, dq_ref, *, sm_scale, causal,
-                   block_k, sk, sq_total, keep_prob):
-    bq, d = q_ref.shape[2], q_ref.shape[3]
-    qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, :, 0]
-    delta = delta_ref[0, 0, :, 0]
-    nk = jnp.minimum(pl.cdiv((qi + 1) * bq + (sk - sq_total), block_k),
-                     sk // block_k) if causal else sk // block_k
-
-    def body(ki, dq):
-        k_blk = k_ref[0, 0, pl.ds(ki * block_k, block_k), :] \
-            .astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.ds(ki * block_k, block_k), :] \
-            .astype(jnp.float32)
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if bias_ref is not None:
-            b = bias_ref[0, 0, :, pl.ds(ki * block_k, block_k)] \
-                .astype(jnp.float32)
-            s = s + jnp.broadcast_to(b, s.shape)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0) \
-                + qi * bq + (sk - sq_total)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1) \
-                + ki * block_k
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if drop_ref is not None:
-            # d/ds of sum_k (m/keep) p_k v_k with lse fixed by the full
-            # (undropped) softmax: ds = p * (m/keep * dp - delta)
-            dm = drop_ref[0, 0, :, pl.ds(ki * block_k, block_k)] \
-                .astype(jnp.float32)
-            dp = dp * dm * (1.0 / keep_prob)
-        elif seed_ref is not None:
-            dp = dp * _drop_keep_tile(seed_ref, qi, ki, (bq, block_k),
-                                      keep_prob)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        return dq + jax.lax.dot_general(ds, k_blk, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    dq = jax.lax.fori_loop(0, nk, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, drop_ref, seed_ref,
-                    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, sm_scale,
-                    causal, block_q, sq, sk_total, keep_prob):
+def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, drop_ref, seed_ref, do_ref,
+                lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
+                sm_scale, causal, block_q, sq, sk_total, keep_prob):
+    # one k-block ki a grid step; dK/dV in registers over the q-blocks,
+    # dQ of all sq rows in the f32 VMEM scratch dq_acc across the key
+    # axis, written out on its last block. Each score tile, its softmax
+    # and its dropout pattern are made once and serve all three grads.
     bk, d = k_ref.shape[2], k_ref.shape[3]
     ki = pl.program_id(2)
+    nk = sk_total // bk
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     nq = sq // block_q
@@ -347,20 +303,22 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, drop_ref, seed_ref,
     q_start = jnp.maximum(ki * bk - (sk_total - sq), 0) // block_q \
         if causal else 0
 
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
     def body(qi, carry):
         dk, dv = carry
-        q_blk = q_ref[0, 0, pl.ds(qi * block_q, block_q), :] \
-            .astype(jnp.float32)
-        do_blk = do_ref[0, 0, pl.ds(qi * block_q, block_q), :] \
-            .astype(jnp.float32)
-        lse_blk = lse_ref[0, 0, pl.ds(qi * block_q, block_q), 0]
-        delta_blk = delta_ref[0, 0, pl.ds(qi * block_q, block_q), 0]
+        rows_q = pl.ds(qi * block_q, block_q)
+        q_blk = q_ref[0, 0, rows_q, :].astype(jnp.float32)
+        do_blk = do_ref[0, 0, rows_q, :].astype(jnp.float32)
+        lse_blk = lse_ref[0, 0, rows_q, 0]
+        delta_blk = delta_ref[0, 0, rows_q, 0]
         s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
         if bias_ref is not None:
-            b = bias_ref[0, 0, pl.ds(qi * block_q, block_q) if
-                         bias_ref.shape[2] != 1 else slice(None), :] \
-                .astype(jnp.float32)
+            b = bias_ref[0, 0, rows_q if bias_ref.shape[2] != 1
+                         else slice(None), :].astype(jnp.float32)
             s = s + jnp.broadcast_to(b, s.shape)
         if causal:
             rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0) \
@@ -370,14 +328,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, drop_ref, seed_ref,
             s = jnp.where(rows >= cols, s, NEG_INF)
         p = jnp.exp(s - lse_blk[:, None])  # [block_q, bk]
         if drop_ref is not None:
-            dm = drop_ref[0, 0, pl.ds(qi * block_q, block_q), :] \
-                .astype(jnp.float32) * (1.0 / keep_prob)
+            dm = drop_ref[0, 0, rows_q, :].astype(jnp.float32) \
+                * (1.0 / keep_prob)
             p_drop = p * dm
         elif seed_ref is not None:
-            # NOTE tile coords: this kernel's (qi, ki) are (loop index,
-            # grid index) — the same absolute (q-tile, k-tile) pair the
-            # forward used, and block_q/bk here equal the forward's
-            # (blk_q, blk_k), so the regenerated pattern is identical
+            # (qi, ki) here are (loop index, grid index): the same
+            # absolute (q-tile, k-tile) pair the forward used, and
+            # block_q/bk equal the forward's (blk_q, blk_k), so the
+            # regenerated pattern is identical
             dm = _drop_keep_tile(seed_ref, qi, ki, (block_q, bk),
                                  keep_prob)
             p_drop = p * dm
@@ -389,11 +347,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, drop_ref, seed_ref,
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do_blk, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        if drop_ref is not None or seed_ref is not None:
+        if dm is not None:
+            # d/ds of sum_k (m/keep) p_k v_k with lse fixed by the full
+            # (undropped) softmax: ds = p * (m/keep * dp - delta)
             dp = dp * dm
         ds = p * (dp - delta_blk[:, None]) * sm_scale
         dk_new = dk + jax.lax.dot_general(
             ds, q_blk, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_acc[rows_q, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
@@ -401,6 +364,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, drop_ref, seed_ref,
     dk, dv = jax.lax.fori_loop(q_start, nq, body, (z, z))
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _bwd(causal, sm_scale, block_q, block_k, interpret, keep_prob,
@@ -413,98 +380,57 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, keep_prob,
     blk_k = min(block_k, sk)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
-    qspec = pl.BlockSpec((1, 1, blk_q, d), lambda b, h, i: (b, h, i, 0))
     qfull = pl.BlockSpec((1, 1, sq, d), lambda b, h, i: (b, h, 0, 0))
-    kfull = pl.BlockSpec((1, 1, sk, d), lambda b, h, i: (b, h, 0, 0))
     kspec = pl.BlockSpec((1, 1, blk_k, d), lambda b, h, i: (b, h, i, 0))
-    lse_blk = pl.BlockSpec((1, 1, blk_q, 1), lambda b, h, i: (b, h, i, 0))
     lse_full = pl.BlockSpec((1, 1, sq, 1), lambda b, h, i: (b, h, 0, 0))
-    lse4 = lse[..., None]
-    delta4 = delta[..., None]
 
-    # ---- dQ: grid over q blocks
-    in_specs = [qspec, kfull, kfull, qspec, lse_blk, lse_blk]
-    args = [q, k, v, do, lse4, delta4]
-    if drop_seed is not None:
-        in_specs.insert(3, pl.BlockSpec((1, 1), lambda b, h, i: (0, 0)))
-        args.insert(3, drop_seed)
-    if drop_mask is not None:
-        in_specs.insert(3, pl.BlockSpec((1, 1, blk_q, sk),
-                                        lambda b, h, i: (b, h, i, 0)))
-        args.insert(3, drop_mask)
-    if bias is not None:
-        in_specs.insert(3, _bias_spec(bias, batch, heads, blk_q, sk))
-        args.insert(3, bias)
-
-    def dq_kern(*refs):
-        refs = list(refs)
-        q_r, k_r, v_r = refs[:3]
-        rest = refs[3:]
-        b_r = rest.pop(0) if bias is not None else None
-        dm_r = rest.pop(0) if drop_mask is not None else None
-        s_r = rest.pop(0) if drop_seed is not None else None
-        do_r, lse_r, dl_r, dq_r = rest
-        _bwd_dq_kernel(q_r, k_r, v_r, b_r, dm_r, s_r, do_r, lse_r, dl_r,
-                       dq_r, sm_scale=sm_scale, causal=causal,
-                       block_k=blk_k, sk=sk, sq_total=sq,
-                       keep_prob=keep_prob)
-
-    with jax.named_scope("flash_attention"):
-        dq = pl.pallas_call(
-            dq_kern,
-            grid=(batch, heads, sq // blk_q),
-            in_specs=in_specs,
-            out_specs=qspec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            interpret=interpret,
-            name="flash_attention_bwd_dq",
-        )(*args)
-
-    # ---- dK/dV: grid over k blocks
-    in_specs2 = [qfull, kspec, kspec, qfull, lse_full, lse_full]
-    args2 = [q, k, v, do, lse4, delta4]
-    if drop_seed is not None:
-        in_specs2.insert(3, pl.BlockSpec((1, 1), lambda b, h, i: (0, 0)))
-        args2.insert(3, drop_seed)
-    if drop_mask is not None:
-        in_specs2.insert(3, pl.BlockSpec((1, 1, sq, blk_k),
-                                         lambda b, h, i: (b, h, 0, i)))
-        args2.insert(3, drop_mask)
+    # grid over k blocks; q, dO and the rows whole, dQ's block constant
+    # along the key axis (so that axis is "arbitrary")
+    in_specs = [qfull, kspec, kspec]
+    args = [q, k, v]
     if bias is not None:
         bshape = bias.shape
 
         def bidx(b, h, i):
             return (b if bshape[0] != 1 else 0, h if bshape[1] != 1 else 0,
                     0, i)
-        bspec2 = pl.BlockSpec(
-            (1, 1, bshape[2] if bshape[2] != 1 else 1, blk_k), bidx)
-        in_specs2.insert(3, bspec2)
-        args2.insert(3, bias)
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bshape[2] if bshape[2] != 1 else 1, blk_k), bidx))
+        args.append(bias)
+    if drop_mask is not None:
+        in_specs.append(pl.BlockSpec((1, 1, sq, blk_k),
+                                     lambda b, h, i: (b, h, 0, i)))
+        args.append(drop_mask)
+    if drop_seed is not None:
+        in_specs.append(pl.BlockSpec((1, 1), lambda b, h, i: (0, 0)))
+        args.append(drop_seed)
+    in_specs += [qfull, lse_full, lse_full]
+    args += [do, lse[..., None], delta[..., None]]
 
-    def dkv_kern(*refs):
-        refs = list(refs)
-        q_r, k_r, v_r = refs[:3]
-        rest = refs[3:]
+    def kern(q_r, k_r, v_r, *rest):
+        rest = list(rest)
         b_r = rest.pop(0) if bias is not None else None
         dm_r = rest.pop(0) if drop_mask is not None else None
         s_r = rest.pop(0) if drop_seed is not None else None
-        do_r, lse_r, dl_r, dk_r, dv_r = rest
-        _bwd_dkv_kernel(q_r, k_r, v_r, b_r, dm_r, s_r, do_r, lse_r, dl_r,
-                        dk_r, dv_r,
-                        sm_scale=sm_scale, causal=causal, block_q=blk_q,
-                        sq=sq, sk_total=sk, keep_prob=keep_prob)
+        _bwd_kernel(q_r, k_r, v_r, b_r, dm_r, s_r, *rest,
+                    sm_scale=sm_scale, causal=causal, block_q=blk_q,
+                    sq=sq, sk_total=sk, keep_prob=keep_prob)
 
     with jax.named_scope("flash_attention"):
-        dk, dv = pl.pallas_call(
-            dkv_kern,
+        dq, dk, dv = pl.pallas_call(
+            kern,
             grid=(batch, heads, sk // blk_k),
-            in_specs=in_specs2,
-            out_specs=[kspec, kspec],
-            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+            in_specs=in_specs,
+            out_specs=[qfull, kspec, kspec],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
                        jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
-            name="flash_attention_bwd_dkv",
-        )(*args2)
+            name="flash_attention_bwd",
+        )(*args)
 
     dbias = None
     if bias is not None and not bias_grad:

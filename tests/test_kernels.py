@@ -28,38 +28,55 @@ def test_flash_attention_forward(causal):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_flash_attention_causal_cross_length():
+# Blocks for the grad tests: one tile a (b, h), and several q- and
+# k-tiles, so the backward's dQ is summed over key blocks in its scratch
+# and its causal skip starts past the first q-block
+ONE_TILE = (512, 512)
+
+
+@pytest.mark.parametrize("blocks", [ONE_TILE, (64, 128)],
+                         ids=["one_tile", "tiles_2x2"])
+def test_flash_attention_causal_cross_length(blocks):
     # sq != sk: bottom-right-aligned causal mask must match the reference
     b, h, sq, sk, d = 1, 2, 128, 256, 64
+    bq, bk = blocks
     q = _rand((b, h, sq, d), 0)
     k, v = _rand((b, h, sk, d), 1), _rand((b, h, sk, d), 2)
-    out = flash_attention(q, k, v, causal=True)
+    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
     ref = attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    g_f = jax.grad(lambda q, k, v: flash_attention(q, k, v, causal=True)
-                   .sum(), argnums=(0, 1, 2))(q, k, v)
+    g_f = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=bq, block_k=bk)
+        .sum(), argnums=(0, 1, 2))(q, k, v)
     g_r = jax.grad(lambda q, k, v: attention_reference(q, k, v, causal=True)
                    .sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_f, g_r):
         np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-4)
 
 
-def test_flash_attention_causal_sq_gt_sk():
+@pytest.mark.parametrize("sq,sk,blocks", [(256, 128, ONE_TILE),
+                                          (512, 256, (128, 128))],
+                         ids=["one_tile", "tiles_4x2"])
+def test_flash_attention_causal_sq_gt_sk(sq, sk, blocks):
     # sq > sk: leading q-rows see ZERO keys (bottom-right alignment);
     # their output is 0 and — the ADVICE r1 regression — their backward
-    # must not blow up through exp(s - lse) with lse ~ -1e30
-    b, h, sq, sk, d = 1, 2, 256, 128, 64
+    # must not blow up through exp(s - lse) with lse ~ -1e30. In the
+    # tiled case the first two q-blocks are empty and the backward's
+    # causal skip starts past them
+    b, h, d = 1, 2, 64
+    bq, bk = blocks
     q = _rand((b, h, sq, d), 0)
     k, v = _rand((b, h, sk, d), 1), _rand((b, h, sk, d), 2)
-    out = flash_attention(q, k, v, causal=True)
+    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
     ref = attention_reference(q, k, v, causal=True)
     # empty rows output exactly 0 in both paths
     np.testing.assert_allclose(out[:, :, :sq - sk], 0.0, atol=1e-6)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    g_f = jax.grad(lambda q, k, v: flash_attention(q, k, v, causal=True)
-                   .sum(), argnums=(0, 1, 2))(q, k, v)
+    g_f = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=bq, block_k=bk)
+        .sum(), argnums=(0, 1, 2))(q, k, v)
     g_r = jax.grad(lambda q, k, v: attention_reference(q, k, v, causal=True)
                    .sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_f, g_r):
@@ -90,14 +107,18 @@ def test_flash_attention_bias_broadcast():
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("blocks", [ONE_TILE, (128, 128)],
+                         ids=["one_tile", "tiles_2x2"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_grads(causal):
+def test_flash_attention_grads(causal, blocks):
     b, h, s, d = 1, 2, 256, 64
+    bq, bk = blocks
     q, k, v = _rand((b, h, s, d), 0), _rand((b, h, s, d), 1), \
         _rand((b, h, s, d), 2)
 
     def f_flash(q, k, v):
-        return (flash_attention(q, k, v, causal=causal) *
+        return (flash_attention(q, k, v, causal=causal, block_q=bq,
+                                block_k=bk) *
                 _rand((b, h, s, d), 9)).sum()
 
     def f_ref(q, k, v):
@@ -110,14 +131,21 @@ def test_flash_attention_grads(causal):
         np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-4)
 
 
-def test_flash_attention_bias_grad():
-    b, h, s, d = 1, 2, 128, 64
+@pytest.mark.parametrize("s,blocks,bias_q", [
+    (128, ONE_TILE, 1), (256, (128, 128), 1), (256, (128, 128), 256)],
+    ids=["one_tile", "tiles_2x2", "tiles_2x2_per_query_bias"])
+def test_flash_attention_bias_grad(s, blocks, bias_q):
+    # bias_needs_grad=True: the kernel's dQ/dK/dV beside the dbias
+    # recompute in XLA
+    b, h, d = 1, 2, 64
+    bq, bk = blocks
     q, k, v = _rand((b, h, s, d), 0), _rand((b, h, s, d), 1), \
         _rand((b, h, s, d), 2)
-    bias = _rand((b, 1, 1, s), 3)
+    bias = _rand((b, 1, bias_q, s), 3)
 
     def f_flash(q, k, v, bias):
-        return (flash_attention(q, k, v, bias=bias)).sum()
+        return (flash_attention(q, k, v, bias=bias, block_q=bq,
+                                block_k=bk)).sum()
 
     def f_ref(q, k, v, bias):
         return (attention_reference(q, k, v, bias=bias)).sum()
@@ -204,9 +232,12 @@ def test_flash_attention_dropout_matches_masked_oracle():
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_flash_attention_dropout_grads():
+@pytest.mark.parametrize("blocks", [ONE_TILE, (128, 128)],
+                         ids=["one_tile", "tiles_2x2"])
+def test_flash_attention_dropout_grads(blocks):
     from paddle_tpu.kernels.flash_attention import dropout_keep_mask
     b, h, s, d = 1, 2, 256, 64
+    bq, bk = blocks
     q, k, v = _rand((b, h, s, d), 6), _rand((b, h, s, d), 7), \
         _rand((b, h, s, d), 8)
     rate = 0.2
@@ -215,7 +246,8 @@ def test_flash_attention_dropout_grads():
 
     def f_flash(q, k, v):
         return (flash_attention(q, k, v, dropout_rate=rate,
-                                dropout_rng=rng) * w).sum()
+                                dropout_rng=rng, block_q=bq,
+                                block_k=bk) * w).sum()
 
     keep = dropout_keep_mask(rng, rate, (b, h, s, s), q.dtype)
 
@@ -269,14 +301,17 @@ def test_flash_inkernel_dropout_tpu():
     mod.check_inkernel_dropout_parity()
 
 
-def test_flash_bias_needs_grad_false_matches_reference():
+@pytest.mark.parametrize("S,block", [(256, 128), (1024, 512)],
+                         ids=["tiles_2x2", "s1024_tiles_2x2"])
+def test_flash_bias_needs_grad_false_matches_reference(S, block):
     """bias_needs_grad=False must not change q/k/v grads (the dbias
     recompute is skipped, its cotangent is zeros) — the padding-mask
-    contract that makes in-kernel dropout eligible with a bias."""
+    contract that makes in-kernel dropout eligible with a bias. The
+    second case is the tiling of scripts/inkernel_parity.py."""
     from paddle_tpu.kernels.flash_attention import (attention_reference,
                                                     flash_attention)
     rng = np.random.RandomState(2)
-    B, H, S, D = 1, 2, 256, 64
+    B, H, D = 1, 2, 64
     q = jnp.asarray(rng.randn(B, H, S, D) * 0.1, jnp.float32)
     k = jnp.asarray(rng.randn(B, H, S, D) * 0.1, jnp.float32)
     v = jnp.asarray(rng.randn(B, H, S, D) * 0.1, jnp.float32)
@@ -286,7 +321,7 @@ def test_flash_bias_needs_grad_false_matches_reference():
 
     def loss_flash(q, k, v, b):
         return jnp.sum(flash_attention(q, k, v, bias=b, sm_scale=0.125,
-                                       block_q=128, block_k=128,
+                                       block_q=block, block_k=block,
                                        bias_needs_grad=False) ** 2)
 
     def loss_ref(q, k, v, b):
