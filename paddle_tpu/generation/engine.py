@@ -262,6 +262,16 @@ class GenerationEngine:
                 "kv_dtype='fp8' needs float8_e4m3fn in this jax "
                 "build/backend (quant.supports_fp8()) — use 'int8'")
         self.kv_dtype = kvq
+        # the pools the family's step takes, in its argument order: a
+        # K and a V pool, or what the family declares (the latent
+        # family's one pool of rows, generation/mla_moe.py)
+        self._kv_names = tuple(getattr(cfg, "kv_pools",
+                                       ("k_pools", "v_pools")))
+        if kvq not in _PLAIN_KV and self._kv_names != ("k_pools",
+                                                       "v_pools"):
+            raise ValueError("kv_dtype %r: a quantized pool needs a K and "
+                             "a V pool, %s declares %s" % (
+                                 kvq, type(cfg).__name__, self._kv_names))
         if self.quant_mode != "off" and not _quant.is_quantized(
                 self.params):
             # fp32 params are converted in-process (tests/bench
@@ -376,6 +386,8 @@ class GenerationEngine:
         # device pools: made below by _restore_pools, once the draft
         # model's config is known (it makes whichever are missing)
         self.k_pools = self.v_pools = None
+        for name in self._kv_names:
+            setattr(self, name, None)
         self.k_scales = self.v_scales = None
         # cross-request prefix cache (the pool's block is the unit)
         pc_on = bool(prefix_cache if prefix_cache is not None
@@ -450,7 +462,10 @@ class GenerationEngine:
         down, from what the model family's config says of it
         (`kv_layers`, `kv_row`, `kv_heads`; a looped family holds
         passes x layers of cache, a head need not be hidden / heads).
-        A pool is `[kv_layers, N, block_size, kv_heads * head_dim]`:
+        The family's step takes a K and a V pool, or the pools it
+        declares (`cfg.kv_pools`: the latent family's ONE pool of rows
+        whose first columns are the values). A pool is `[kv_layers, N,
+        block_size, kv_row]`, a row `kv_heads * head_dim` wide:
         the heads' two axes are kept FLAT, because the TPU's
         default layout of a `[..., heads, 64]` array makes the block
         axis N the minor one, and then neither the step's scatter nor
@@ -461,7 +476,7 @@ class GenerationEngine:
         shape = (cfg.kv_layers, nb, bs, cfg.kv_row)
         if self.kv_dtype in _PLAIN_KV:
             dt = _PLAIN_KV[self.kv_dtype]
-            specs = {"k_pools": (shape, dt, 0), "v_pools": (shape, dt, 0)}
+            specs = {n: (shape, dt, 0) for n in self._kv_names}
         else:
             # quantized pool + per-token-per-head fp32 absmax scale
             # pool (quant.quantize_kv_rows). Scales init to ONE so a
@@ -501,10 +516,8 @@ class GenerationEngine:
         """Total device bytes of the K/V block pools, scale pools
         included — the fixed budget the capacity bench holds constant
         across dtypes."""
-        b = self.k_pools.nbytes + self.v_pools.nbytes
-        if self.k_scales is not None:
-            b += self.k_scales.nbytes + self.v_scales.nbytes
-        return int(b)
+        return int(sum(getattr(self, n).nbytes
+                       for n in self._program_pools("mixed")))
 
     def kv_bytes_per_seq(self) -> int:
         """Pool bytes one max-length sequence occupies (payload +
@@ -591,6 +604,7 @@ class GenerationEngine:
             sw = self.sample_width
             quant_kv = self.k_scales is not None
             n_stats = self._stats_len
+            n_kv = len(self._kv_names)
 
             def raw(params, *rest):
                 pools, (prev, ints, floats) = rest[:-3], rest[-3:]
@@ -605,15 +619,16 @@ class GenerationEngine:
                                    prev[jnp.maximum(feed_rows, 0)],
                                    tokens)
                 temps, tps = floats[:sw], floats[sw:]
-                scales = dict(k_scale_pools=pools[2],
-                              v_scale_pools=pools[3]) if quant_kv else {}
+                scales = dict(k_scale_pools=pools[n_kv],
+                              v_scale_pools=pools[n_kv + 1]) \
+                    if quant_kv else {}
                 tables = tables.reshape(t, m)
                 if n_stats:
                     # the slots that carry a token: an idle slot's
                     # table is the trash block throughout
                     scales["live"] = tables[:, 0] != TRASH_BLOCK
                 out = cfg.forward_paged(
-                    params, pools[0], pools[1], tables,
+                    params, *pools[:n_kv], tables,
                     positions, tokens, **scales)
                 with jax.named_scope("sampler"):
                     nxt = sample_tokens(out[0][sample_slots], temps,
@@ -682,8 +697,8 @@ class GenerationEngine:
         if kind.startswith("draft"):
             return ("dk_pools", "dv_pools")
         if self.k_scales is not None:
-            return ("k_pools", "v_pools", "k_scales", "v_scales")
-        return ("k_pools", "v_pools")
+            return self._kv_names + ("k_scales", "v_scales")
+        return self._kv_names
 
     def _pool_argnums(self, kind: str) -> tuple:
         """Positions of those pools among the program's arguments
@@ -1155,6 +1170,7 @@ class GenerationEngine:
             seeds = np.zeros((sw,), np.int32)
             steps = np.zeros((sw,), np.int32)
             slot = 0
+            ends = []                   # each planned lane's last slot
             # (lane, seq, first sampler row, drafts riding this step)
             decode_plan = []
             for ln in decode_lanes:
@@ -1182,6 +1198,7 @@ class GenerationEngine:
                     # samples exactly what plain decode would at that index
                     steps[row0 + j] = base + j
                     slot += 1
+                ends.append(slot - 1)
                 decode_plan.append((ln, seq, row0, d))
             for ln, seq, start, take in chunk_plan:
                 if seq.prefilled:
@@ -1194,6 +1211,7 @@ class GenerationEngine:
                     positions[slot] = start + j
                     tokens[slot] = seq.req.prompt[start + j]
                     slot += 1
+                ends.append(slot - 1)
                 # only the chunk's LAST slot's sample matters (step 0, the
                 # first generated token) and only when the chunk completes
                 # the prompt — otherwise discarded on the host
@@ -1215,9 +1233,12 @@ class GenerationEngine:
             # ... and the pool blocks those contexts span: what the
             # Pallas kernel copies (the reference form gathers every
             # slot's whole table, token_budget x max_blocks_per_seq)
-            attended, blocks = self._attended(positions[:slot])
+            # ... and the rows each LANE's slots see together, each once:
+            # what any kernel must read at the least
+            attended, blocks, rows = self._attended(positions[:slot], ends)
             stat_add("STAT_generation_attended_tokens", attended)
             stat_add("STAT_generation_attended_blocks", blocks)
+            stat_add("STAT_generation_context_rows", rows)
             if self.k_scales is not None:
                 # this step's fresh K/V rows quantize inside the compiled
                 # call — the failpoint models a fault in that stage, and it
@@ -1233,23 +1254,33 @@ class GenerationEngine:
                                 sample_slots, temps, tks, tps, seeds,
                                 steps), decode_plan, chunk_plan)
 
-    def _attended(self, positions) -> tuple:
-        """(positions attended, pool blocks they span) by slots at
-        `positions`, each with its own token. In a family whose cache
-        layers all see the whole context that is ONE layer's count
-        (every layer attends the same); where the family gives a
-        window a cache layer (`cfg.kv_windows`, 0: none) it is the SUM
-        over its layers of what each really attends, a window layer at
-        most its window a slot."""
+    def _attended(self, positions, ends) -> tuple:
+        """(positions attended, pool blocks they span, distinct rows) by
+        slots at `positions`, each with its own token; `ends` holds each
+        lane's last slot, its slots consecutive positions from the slot
+        after the lane before. Distinct rows count a lane's context ONCE
+        however many of its slots see it (a prefill chunk's): what a
+        kernel must read at the least. In a family whose cache layers
+        all see the whole context each is ONE layer's count (every layer
+        attends the same); where the family gives a window a cache layer
+        (`cfg.kv_windows`, 0: none) it is the SUM over its layers of
+        what each really attends, a window layer at most its window a
+        slot."""
+        if not ends:
+            return 0, 0, 0
         bs = self.kv.block_size
         windows = getattr(self.cfg, "kv_windows", None) or (0,)
-        attended = blocks = 0
+        last = positions[ends]
+        lead = positions[[0] + [e + 1 for e in ends[:-1]]]
+        attended = blocks = rows = 0
         for w in sorted(set(windows)):
             first = np.maximum(positions - w + 1, 0) if w else 0
+            seen = np.maximum(lead - w + 1, 0) if w else 0
             n = windows.count(w)
             attended += n * int((positions - first + 1).sum())
             blocks += n * int((positions // bs - first // bs + 1).sum())
-        return attended, blocks
+            rows += n * int((last - seen + 1).sum())
+        return attended, blocks, rows
 
     def _emit_mixed(self, nxt, dt_us, decode_plan, chunk_plan,
                     finished: List[GenerationResult]) -> None:

@@ -293,9 +293,15 @@ def init_params(cfg: ExpertDecoderConfig, seed: int = 0,
     N(0, 0.02) embedding, N(0, 1/sqrt(fan_in)) matrices, unit gains, a
     choice bias N(0, 0.01): small beside the scores' spread and not
     zero, so that leaving it out changes which experts run."""
+    return draw_params(leaf_shapes(cfg), seed, dtype)
+
+
+def draw_params(shapes: dict, seed: int, dtype) -> dict:
+    """`init_params`' draw over `shapes`, name -> (shape, fan_in | None
+    for a unit gain | "bias")."""
     rng = np.random.default_rng(seed)
     p = {}
-    for name, (shape, fan_in) in leaf_shapes(cfg).items():
+    for name, (shape, fan_in) in shapes.items():
         if name == "tok_emb":
             w = rng.normal(0.0, 0.02, shape)
         elif fan_in == "bias":
@@ -423,17 +429,20 @@ def _after_attention(cfg: ExpertDecoderConfig, w, x, o, live):
     return x + _rms(m, w["ln_ffn"], eps), load
 
 
-def _stacks(cfg: ExpertDecoderConfig, params, layer, carry):
+def _stacks(cfg, params, layer, carry,
+            leaves=(DENSE_LEAVES, SPARSE_LEAVES)):
     """`carry, y = layer(carry, layer's weights, cache slot, window)`
     over the leading dense layers, then over the sparse ones: a
     `lax.scan` each, the slot and the window scanned beside the
-    weights. -> (carry, [what each scan emitted, stacked by layer])."""
-    windows = np.asarray(cfg.sliding_windows, np.int32)
+    weights; `leaves` names the two kinds' stacked leaves (the latent
+    family's, generation/mla_moe.py, are not these). -> (carry, [what
+    each scan emitted, stacked by layer])."""
+    windows = np.asarray(cfg.kv_windows, np.int32)
     nd = cfg.dense_layers
     emitted = []
     for lo, hi, names, prefix in (
-            (0, nd, DENSE_LEAVES, DENSE_PREFIX),
-            (nd, cfg.num_hidden_layers, SPARSE_LEAVES, "")):
+            (0, nd, leaves[0], DENSE_PREFIX),
+            (nd, cfg.num_hidden_layers, leaves[1], "")):
         if hi == lo:
             continue
         stack = {n: params[prefix + n] for n in names}

@@ -511,3 +511,92 @@ def test_expert_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch,
         < 15.75 * 2 ** 30
     if form == "pallas":
         assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+# The latent family (generation/mla_moe.py) at the sizes of the benchmark's
+# cell `kimi_k2_6_agent_c96`: 6.99 GB of bfloat16 weights, ONE bfloat16 pool of
+# 5 cache layers of latent rows (512 + 64, in 640 lanes), 61,440 blocks of 16,
+# 352 slots a step, tables of 640 entries, the cache layer a traced scalar.
+_LATENT_POOL = _sds((5, 61440, 16, 640), jnp.bfloat16)
+
+
+def test_latent_kernel_compiles_at_the_cells_geometry_for_v5e(one_chip):
+    """The kernel alone: 64 heads of a slot against one row, G = 32 blocks
+    a loop step (1.3 MB of rows, two buffers); the pool is read where it
+    lies."""
+    from paddle_tpu.kernels import latent_attention as la
+    assert la.blocks_per_step(16, 1280, 640) == 32
+
+    def attend(q, pool, tables, ctx, layer):
+        return la.latent_attention_pallas(q, pool, tables, ctx, 0.1, layer,
+                                          512, interpret=False)
+    txt = _compile(attend, one_chip, _sds((352, 64, 640), jnp.float32),
+                   _LATENT_POOL, _sds((352, 640), jnp.int32),
+                   _sds((352,), jnp.int32), _sds((), jnp.int32))
+    assert txt.count("tpu_custom_call") == 1
+    assert not re.search(r"= bf16\[5,61440,16,640\]\S* (?!parameter)", txt)
+
+
+def test_latent_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch):
+    """The cell's whole step in the Pallas form (the dense layer and four
+    sparse layers at the published widths, 12 of 384 experts held, the
+    routing counts, the sampler) as the engine jits it: the sparse layers
+    ONE loop body, the pool aliased to its output and never copied,
+    weights + pool + temporaries inside the chip's memory."""
+    import json
+    import os
+    from paddle_tpu.generation import mla_moe, sample_tokens
+    from paddle_tpu.kernels import paged_attention as pa
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    src = json.load(open(os.path.join(root, "benchmark", "configs",
+                                      "kimi_k2_6.json")))
+    eng, held = src["engine"], src["experts_held"]
+    cfg = mla_moe.LatentDecoderConfig.from_source(
+        src, eng["max_context"], (held["first"], held["count"]))
+    params = {k: _sds(s, jnp.bfloat16)
+              for k, (s, _) in mla_moe.leaf_shapes(cfg).items()}
+    assert _LATENT_POOL.shape == (cfg.kv_layers,
+                                  eng["kv_pool_tokens"] // 16, 16,
+                                  cfg.kv_row)
+    t, m, sw = eng["token_budget"], eng["max_context"] // 16, \
+        eng["decode_width"]
+
+    def mixed(params, pool, tables, positions, tokens, slots, temps, tks,
+              tps, seeds, steps):
+        logits, pool, loads = cfg.forward_paged(
+            params, pool, tables, positions, tokens,
+            live=tables[:, 0] != 0)
+        with jax.named_scope("sampler"):
+            nxt = sample_tokens(logits[slots], temps, tks, tps, seeds,
+                                steps)
+        return jnp.concatenate([nxt, loads.reshape(-1)]), pool
+    i32, f32 = jnp.int32, jnp.float32
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, _LATENT_POOL, _sds((t, m), i32), _sds((t,), i32),
+         _sds((t,), i32), _sds((sw,), i32), _sds((sw,), f32),
+         _sds((sw,), i32), _sds((sw,), f32), _sds((sw,), i32),
+         _sds((sw,), i32)))
+    with pa.kernel_form("pallas"):
+        compiled = jax.jit(mixed, donate_argnums=(1,)).lower(
+            *args).compile()
+    txt = compiled.as_text()
+    assert txt.count(" while(") == 1
+    # the latent kernel of the dense layer and of the loop's body, the two
+    # grouped products and their metadata
+    assert txt.count("tpu_custom_call") == 5
+    head = txt.splitlines()[0]
+    alias = head[head.index("input_output_alias"):]
+    alias = alias[:alias.index("}, entry_computation_layout")]
+    assert len(re.findall(r"\(\d+, \{\}", alias)) == 1, alias
+    made = re.findall(r"= bf16\[5,61440,16,640\]\S* ([\w\-]+)\(", txt)
+    assert set(made) <= {"parameter", "get-tuple-element", "fusion",
+                         "scatter", "dynamic-update-slice"}, set(made)
+    mem = compiled.memory_analysis()
+    weights = 2 * sum(math.prod(s.shape) for s in params.values())
+    assert weights == 2 * 3_496_763_904
+    assert mem.alias_size_in_bytes >= 5 * 61440 * 16 * 640 * 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20

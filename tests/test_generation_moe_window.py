@@ -488,6 +488,24 @@ def test_the_counters_against_a_hand_count():
     assert got[4] == loads.max(axis=1).sum()
 
 
+def test_context_rows_count_a_lanes_context_once_a_layer():
+    """`STAT_generation_context_rows`: the rows a lane's slots see together,
+    once each, summed over the layers (windows 8, 8, 8, none). The chunk of
+    positions 0..10 sees all 11 rows in every layer; the decode slot at 11
+    sees 12 in the full layer and its window of 8 in the others."""
+    cfg = _cfg(_source())
+    eng = _engine(cfg, mw.init_params(cfg, seed=4), prefix_cache=False,
+                  prefill_chunk=16, lookahead=0)
+    eng.submit(GenerationRequest(prompt=list(range(20, 31)),
+                                 max_new_tokens=3))
+    r0 = stat_get("STAT_generation_context_rows")
+    eng.step()
+    assert stat_get("STAT_generation_context_rows") - r0 == 4 * 11
+    eng.step()
+    assert stat_get("STAT_generation_context_rows") - r0 == 4 * 11 + 12 \
+        + 3 * 8
+
+
 def _loads_of(cfg, params, toks):
     """[sparse layers, experts held]: the held experts' loads over the
     tokens of `toks`, from `forward_paged` one token a step."""
