@@ -57,8 +57,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.latent_attention import latent_attention
+from ..kernels import latent_attention as _la
 from ..kernels.paged_attention import attend_reference
+from ..monitor import stat_add
 from . import moe_window as _mw
 from .looped import _mm, _rms
 
@@ -216,9 +217,21 @@ class LatentDecoderConfig:
                     **{k: getattr(self, k)
                        for k in self.__dataclass_fields__})
 
-    # --- what a step reports beside its tokens: moe_window's -------------
-    step_stats_len = _mw.ExpertDecoderConfig.step_stats_len
-    record_step_stats = _mw.ExpertDecoderConfig.record_step_stats
+    # --- what a step reports beside its tokens ---------------------------
+    @property
+    def step_stats_len(self) -> int:
+        """int32 numbers `forward_paged(..., live=...)` returns last: the
+        held experts' loads as `moe_window`'s, then the latent kernel's
+        tiles of live slots and the live slots in a tile of more than
+        one."""
+        return self.sparse_layers * self.experts_held + 2
+
+    def record_step_stats(self, stats) -> None:
+        """The host's half: the routing counts as the expert family
+        records them, and the kernel's tiles (docs/observability.md)."""
+        _mw.ExpertDecoderConfig.record_step_stats(self, stats[:-2])
+        stat_add("STAT_generation_latent_tiles", int(stats[-2]))
+        stat_add("STAT_generation_latent_shared_slots", int(stats[-1]))
 
     # --- the seam the engine calls -------------------------------------
     def forward_full(self, params, tokens, lengths, attn_lanes: int = 0):
@@ -416,14 +429,31 @@ def forward_full(cfg: LatentDecoderConfig, params: dict, tokens, lengths,
     return last, rows, rows[..., :cfg.kv_lora_rank]
 
 
+def _tile_stats(cfg: LatentDecoderConfig, pool, block_tables, visible,
+                live):
+    """`[2]` int32: the latent kernel's tiles whose slots carry a token,
+    and the live slots attended in a tile of more than one, by the
+    kernel's own rule (`latent_tiles` at the Q its geometry gives)."""
+    b, m = block_tables.shape
+    tile = _la.slots_per_tile(cfg.num_attention_heads, pool.shape[2],
+                              pool.shape[-1] * pool.dtype.itemsize, m,
+                              cfg.kv_lora_rank, b)
+    first, count = _la.latent_tiles(block_tables, visible, tile)
+    lead = live[jnp.minimum(first, b - 1)] & (count > 0)
+    return jnp.stack([jnp.sum(lead, dtype=jnp.int32),
+                      jnp.sum(jnp.where(lead & (count > 1), count, 0),
+                              dtype=jnp.int32)])
+
+
 def forward_paged(cfg: LatentDecoderConfig, params: dict, latent_pools,
                   block_tables, ctx_lens, tokens, live=None):
     """The engine's mixed step in the ABSORBED form: tokens `[B]` (each
     slot's token at position ctx_lens), the pool `[kv_layers, N, bs,
     kv_row]` -> (logits `[B, vocab]`, the pool with this step's rows
     written) and, where `live` `[B]` bool says which slots carry a
-    token, last the held experts' loads `[sparse layers, experts_held]`
-    int32 over those slots."""
+    token, last `step_stats_len` int32: the held experts' loads
+    `[sparse layers, experts_held]` over those slots, flat, then
+    `_tile_stats`."""
     scope = jax.named_scope
     b = tokens.shape[0]
     bs = latent_pools.shape[2]
@@ -455,9 +485,9 @@ def forward_paged(cfg: LatentDecoderConfig, params: dict, latent_pools,
                 [q_lat, q_rope, jnp.zeros(q_rope.shape[:-1] + (
                     cfg.kv_row - kvl - q_rope.shape[-1],), jnp.float32)],
                 axis=-1)                                   # [B, H, R]
-        ctx = latent_attention(q, pool, block_tables, ctx_lens + 1,
-                               sm_scale=cfg.softmax_scale, layer=slot,
-                               value_width=kvl)            # [B, H, kvl]
+        ctx = _la.latent_attention(q, pool, block_tables, ctx_lens + 1,
+                                   sm_scale=cfg.softmax_scale, layer=slot,
+                                   value_width=kvl)        # [B, H, kvl]
         with scope("latent_out"):
             o = jnp.einsum("bhc,chd->bhd", ctx.astype(w_uv.dtype), w_uv,
                            preferred_element_type=jnp.float32)
@@ -473,4 +503,6 @@ def forward_paged(cfg: LatentDecoderConfig, params: dict, latent_pools,
         return logits, pool
     loads = emitted[-1] if cfg.sparse_layers else \
         jnp.zeros((0, cfg.experts_held), jnp.int32)
-    return logits, pool, loads
+    return logits, pool, jnp.concatenate([
+        loads.reshape(-1),
+        _tile_stats(cfg, latent_pools, block_tables, ctx_lens + 1, live)])

@@ -522,10 +522,11 @@ _LATENT_POOL = _sds((5, 61440, 16, 640), jnp.bfloat16)
 
 def test_latent_kernel_compiles_at_the_cells_geometry_for_v5e(one_chip):
     """The kernel alone: 64 heads of a slot against one row, G = 32 blocks
-    a loop step (1.3 MB of rows, two buffers); the pool is read where it
-    lies."""
+    a loop step (1.3 MB of rows, two buffers), tiles of Q = 8 slots (512
+    query rows); the pool is read where it lies."""
     from paddle_tpu.kernels import latent_attention as la
     assert la.blocks_per_step(16, 1280, 640) == 32
+    assert la.slots_per_tile(64, 16, 1280, 640, 512, 352) == 8
 
     def attend(q, pool, tables, ctx, layer):
         return la.latent_attention_pallas(q, pool, tables, ctx, 0.1, layer,
@@ -540,9 +541,10 @@ def test_latent_kernel_compiles_at_the_cells_geometry_for_v5e(one_chip):
 def test_latent_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch):
     """The cell's whole step in the Pallas form (the dense layer and four
     sparse layers at the published widths, 12 of 384 experts held, the
-    routing counts, the sampler) as the engine jits it: the sparse layers
-    ONE loop body, the pool aliased to its output and never copied,
-    weights + pool + temporaries inside the chip's memory."""
+    routing counts and the kernel's tile counts, the sampler) as the
+    engine jits it: the sparse layers ONE loop body, the pool aliased to
+    its output and never copied, weights + pool + temporaries inside the
+    chip's memory."""
     import json
     import os
     from paddle_tpu.generation import mla_moe, sample_tokens
@@ -564,13 +566,14 @@ def test_latent_mixed_step_compiles_whole_for_v5e(one_chip, monkeypatch):
 
     def mixed(params, pool, tables, positions, tokens, slots, temps, tks,
               tps, seeds, steps):
-        logits, pool, loads = cfg.forward_paged(
+        logits, pool, stats = cfg.forward_paged(
             params, pool, tables, positions, tokens,
             live=tables[:, 0] != 0)
         with jax.named_scope("sampler"):
             nxt = sample_tokens(logits[slots], temps, tks, tps, seeds,
                                 steps)
-        return jnp.concatenate([nxt, loads.reshape(-1)]), pool
+        assert stats.shape == (cfg.step_stats_len,)
+        return jnp.concatenate([nxt, stats]), pool
     i32, f32 = jnp.int32, jnp.float32
     args = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),

@@ -115,8 +115,54 @@ def _latent_case(bs=4, entries=48, slots=10, r=24, heads=4, seed=0):
     return q, pool, tables.astype(np.int32)
 
 
+def _tiled_case(case):
+    """Sixteen slots of 128 heads: at most 512 query rows make Q = 4.
+    Tables are each slot's own, a run's slots given one lane's."""
+    q, pool, tables = _latent_case(slots=16, heads=128)
+    visible = [37] * 16
+
+    def lane(first, n, at):
+        tables[first:first + n] = tables[first]
+        visible[first:first + n] = range(at, at + n)
+    if case == "chunk_runs":
+        # three decode slots, a run of 7 from slot 3, one of 5 from the
+        # first position, a decode slot
+        visible[:3] = [37, 150, 9]
+        lane(3, 7, 60)
+        lane(10, 5, 1)
+        visible[15] = 192
+    elif case == "block_edge_run":
+        # runs that start on a block's and a group's first position
+        lane(0, 9, 129)
+        lane(9, 5, 5)
+        visible[14:] = [4, 128]
+    elif case == "shared_prefix":
+        # a prefix-cache hit: lane B's table shares lane A's first two
+        # blocks; A's chunk ends at 10 positions where B's starts at 11
+        lane(0, 5, 6)
+        lane(5, 6, 11)
+        tables[5:11, :2] = tables[0, :2]
+        lane(11, 5, 100)
+    elif case == "parked_between_runs":
+        lane(0, 5, 20)
+        lane(7, 6, 33)
+        tables[[5, 6, 13, 15]] = 0
+        for i in (5, 6, 13, 15):
+            visible[i] = 1
+    else:                               # "run_of_one"
+        # one lane's slots that are not one position apart, a chunk's
+        # last token beside decode slots
+        tables[1] = tables[0]
+        visible[:3] = [20, 22, 23]
+        lane(4, 1, 9)
+    return q, pool, tables, visible
+
+
 @FORMS
-@pytest.mark.parametrize("case", ["edges", "idle", "prefill_lane"])
+@pytest.mark.parametrize("case", ["edges", "idle", "prefill_lane",
+                                  "chunk_runs", "block_edge_run",
+                                  "shared_prefix", "parked_between_runs",
+                                  "run_of_one"])
 def test_latent_kernel_against_a_dense_loop(form, case):
     """Blocks of 4 and 48 table entries, so the Pallas form's loop step
     is 32 blocks = 128 positions. `edges`: a context of one, ends at a
@@ -124,23 +170,31 @@ def test_latent_kernel_against_a_dense_loop(form, case):
     129), two groups crossed (190). `idle`: parked slots on the trash
     block (block 0, one visible position) between live ones. A
     `prefill_lane`: six slots share one lane's table at consecutive
-    positions, as the mixed step gives a chunk's tokens."""
-    q, pool, tables = _latent_case()
-    if case == "edges":
-        visible = [1, 4, 5, 127, 128, 129, 130, 160, 190, 192]
-    elif case == "idle":
-        tables[1::2] = 0
-        visible = [1, 1, 33, 1, 130, 1, 7, 1, 192, 1]
+    positions, as the mixed step gives a chunk's tokens. The rest
+    (`_tiled_case`) at 128 heads, where a tile holds 4 slots: runs
+    longer than a tile and not a multiple of it, from an odd slot and
+    from a block's edge, two lanes that share leading blocks at
+    consecutive positions, parked slots between runs, runs of one."""
+    if case in ("edges", "idle", "prefill_lane"):
+        q, pool, tables = _latent_case()
+        if case == "edges":
+            visible = [1, 4, 5, 127, 128, 129, 130, 160, 190, 192]
+        elif case == "idle":
+            tables[1::2] = 0
+            visible = [1, 1, 33, 1, 130, 1, 7, 1, 192, 1]
+        else:
+            tables[:6] = tables[0]
+            visible = [125, 126, 127, 128, 129, 130, 3, 64, 65, 1]
     else:
-        tables[:6] = tables[0]
-        visible = [125, 126, 127, 128, 129, 130, 3, 64, 65, 1]
+        assert la.slots_per_tile(128, 4, 24 * 4, 48, 16, 16) == 4
+        q, pool, tables, visible = _tiled_case(case)
     visible = jnp.asarray(visible, jnp.int32)
     with pa.kernel_form(form):
         got = jax.jit(lambda lyr: la.latent_attention(
             q, pool, jnp.asarray(tables), visible, sm_scale=0.3, layer=lyr,
             value_width=16))(jnp.int32(2))
     want = _dense_latent(q, pool, tables, visible, 2, 0.3, 16)
-    assert got.shape == (10, 4, 16)
+    assert got.shape == q.shape[:2] + (16,)
     assert np.abs(np.asarray(got) - want).max() <= 2e-6
 
 
@@ -150,6 +204,29 @@ def test_the_kernels_step_follows_its_fast_memory():
     assert la.blocks_per_step(16, 1280, 640) == 32
     assert la.blocks_per_step(16, 1280, 20) == 16
     assert la.blocks_per_step(4, 96, 48) == 32
+    # a tile: 8 slots of 64 heads at the published widths (512 query
+    # rows); 4 where rows twice as wide leave the scores less room; 4 at
+    # 128 heads; never more than the step's slots
+    assert la.slots_per_tile(64, 16, 1280, 640, 512, 352) == 8
+    assert la.slots_per_tile(64, 16, 5120, 640, 1024, 352) == 4
+    assert la.slots_per_tile(128, 4, 512, 48, 16, 16) == 4
+    assert la.slots_per_tile(4, 4, 96, 48, 16, 10) == 8
+
+
+def test_the_tiles_follow_each_lanes_runs():
+    """Lane A's table, lane B's (A's first two blocks, then its own), the
+    trash table. A slot continues a run where the table row is the
+    same and it sees one position more; runs cut into tiles of 2."""
+    a, b_, trash = [1, 2, 3], [1, 2, 4], [0, 0, 0]
+    tables = jnp.asarray([a, b_, b_, b_, b_, b_, trash, trash, a, a],
+                         jnp.int32)
+    visible = jnp.asarray([5, 6, 7, 8, 9, 10, 1, 1, 7, 8], jnp.int32)
+    first, count = jax.jit(la.latent_tiles, static_argnums=2)(
+        tables, visible, 2)
+    assert np.asarray(first).tolist() == [0, 1, 3, 5, 6, 7, 8, 10, 10, 10]
+    assert np.asarray(count).tolist() == [1, 2, 2, 1, 1, 1, 2, 0, 0, 0]
+    first, count = la.latent_tiles(tables, visible, 8)
+    assert np.asarray(count).tolist() == [1, 5, 1, 1, 2, 0, 0, 0, 0, 0]
 
 
 def test_absorbed_equals_expanded_attention_on_the_same_rows():
@@ -576,6 +653,35 @@ def test_the_counters_against_a_hand_count():
     assert got[3] == 4 * 11
 
 
+def test_the_tile_counters_against_a_hand_count():
+    """128 heads make the kernel's tile 4 slots. Two prompts of 3 and 2
+    tokens, then their two decode slots beside a chunk of 11 prompt
+    tokens: d + ceil(k / 4) tiles of live slots, the chunk's k slots
+    attended in tiles of more than one (parked slots are neither)."""
+    cfg = _cfg(_source(num_attention_heads=128))
+    eng = _engine(cfg, ml.init_params(cfg, seed=4), prefix_cache=False,
+                  prefill_chunk=16, lookahead=0)
+    assert la.slots_per_tile(
+        128, eng.kv.block_size,
+        cfg.kv_row * eng.latent_pools.dtype.itemsize,
+        eng.max_blocks_per_seq, cfg.kv_lora_rank, eng.token_budget) == 4
+    assert cfg.step_stats_len == 3 * 8 + 2
+    names = ("STAT_generation_latent_tiles",
+             "STAT_generation_latent_shared_slots")
+
+    def step():
+        before = [stat_get(n) for n in names]
+        eng.step()
+        return [stat_get(n) - b for n, b in zip(names, before)]
+    for prompt in (list(range(3)), list(range(10, 12))):
+        eng.submit(GenerationRequest(prompt=prompt, max_new_tokens=4))
+    assert step() == [1 + 1, 3 + 2]
+    eng.submit(GenerationRequest(prompt=list(range(20, 31)),
+                                 max_new_tokens=2))
+    assert step() == [2 + 3, 11]
+    assert step() == [3, 0]             # three decode slots, each alone
+
+
 def test_a_prefill_chunk_needs_its_lanes_rows_once():
     """The latent roofline's bytes come from the rows each LANE sees, once
     however many of its slots attend them: a chunk of 16 prompt tokens
@@ -676,7 +782,8 @@ def test_from_source_reads_the_benchmarks_file():
     # one row of 512 + 64 a position, in 640 lanes
     assert (cfg.kv_layers, cfg.kv_row, cfg.max_seq_len) == (5, 640, 10240)
     assert cfg.kv_windows == (0,) * 5
-    assert cfg.step_stats_len == 4 * 12
+    # the held experts' loads, then the latent kernel's two counts
+    assert cfg.step_stats_len == 4 * 12 + 2
     assert cfg.routed_scaling_factor == 2.827 and cfg.rope_theta == 50000
     # 3,496,763,904 parameters, 6.99 GB of bfloat16, as the configuration's
     # `deployment` counts them
